@@ -28,7 +28,7 @@ def _attach_requested() -> bool:
         return False
     return bool(os.environ.get("KUBESHARE_TPU_CHIP_PROXY_PORT")
                 or os.environ.get("KUBESHARE_TPU_POD_MANAGER_PORT")
-                or os.environ.get("TPU_VISIBLE_CHIPS"))
+                or os.environ.get("KUBESHARE_TPU_VISIBLE_CHIPS"))
 
 
 try:

@@ -55,12 +55,19 @@ POD_MANAGER_PORT = DOMAIN + "tpu_manager_port"
 # injection (pod.go:435-457). On TPU the client process must NOT grab the
 # chip (single-tenant per process); it is pointed at its pod manager and the
 # chip stays owned by the proxy.
-ENV_VISIBLE_CHIPS = "TPU_VISIBLE_CHIPS"
-# Node mesh shape ("2x4") accompanying a carved TPU_VISIBLE_CHIPS value
-# (entries "chip@x.y", doc/gang.md) so the torus-aware block check in
-# gang/carve.py can validate wrap-around carves. Absent for seed-format
-# assignments; carve-unaware consumers ignore both.
-ENV_MESH_SHAPE = "KUBESHARE_TPU_MESH"
+# The chip GRANT: global chip ids ("TPU-v5-lite-<host>-<index>"), optionally
+# carved ("chip@x.y"). It lives in the repo's own namespace because libtpu
+# parses TPU_VISIBLE_CHIPS itself (a list of local chip indices): handed a
+# chip id there, the directly attached runtime finds no device at all and
+# the pod's backend never starts. attach._pin_visible_devices translates
+# the grant into the runtime's own variables.
+ENV_VISIBLE_CHIPS = "KUBESHARE_TPU_VISIBLE_CHIPS"
+# Node mesh shape ("2x4") accompanying a carved grant (entries "chip@x.y",
+# doc/gang.md) so the torus-aware block check in gang/carve.py can
+# validate wrap-around carves. Absent for seed-format assignments;
+# carve-unaware consumers ignore both. Not KUBESHARE_TPU_MESH: that name
+# is the workload's own axis spec ("dp=2,sp=2,tp=2", parallel/runner.py).
+ENV_MESH_SHAPE = "KUBESHARE_TPU_NODE_MESH"
 ENV_POD_MANAGER_PORT = "KUBESHARE_TPU_POD_MANAGER_PORT"
 ENV_POD_NAME = "KUBESHARE_TPU_POD_NAME"
 ENV_SCHEDULER_IP = "KUBESHARE_TPU_SCHEDULER_IP"

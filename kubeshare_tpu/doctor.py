@@ -5,9 +5,13 @@ installing the next (Prometheus endpoints, the ``gpu_capacity`` metric —
 ``doc/deploy.md:137-146``); this command runs those checks in one shot:
 
 1. **chip** — can the JAX backend initialize, and how fast is a trivial
-   dispatch+host-read round trip? (Probed in a subprocess with a timeout:
-   a wedged transport hangs inside C where no Python timeout reaches.)
-2. **discovery** — do chips enumerate, with model/HBM/coords?
+   dispatch+host-read round trip? (Probed in a subprocess that gives the
+   chip back on exit — a chip belongs to one process at a time. While
+   the node's chip proxy owns the chip the probe cannot, and the check
+   says so instead of failing.)
+2. **discovery** — do chips enumerate, with model/HBM/coords? (The same
+   child-process discovery the node daemons use, or the inventory it
+   left under the scheduler dir when the proxy owns the chip.)
 3. **registry** — is the telemetry bus reachable; does ``/metrics``
    render; how many capacity/requirement records live there?
 4. **scheduler** — is the service reachable; does ``/state`` show nodes?
@@ -51,7 +55,23 @@ def _result(name: str, status: str, detail: str) -> bool:
     return status != "fail"
 
 
+def _proxy_listening() -> bool:
+    """Is a chip proxy answering on the launcher's first exec port? Then
+    it owns the chip, and no other process can open it."""
+    import socket
+    try:
+        with socket.create_connection(("127.0.0.1", C.SCHD_PORT_START),
+                                      timeout=0.5):
+            return True
+    except OSError:
+        return False
+
+
 def check_chip(timeout_s: float) -> bool:
+    if _proxy_listening():
+        return _result("chip", "skip",
+                       f"owned by the chip proxy on :{C.SCHD_PORT_START} "
+                       "(one process per chip); not probed")
     probe = ("import time; t0=time.time(); import jax; d=jax.devices(); "
              "import jax.numpy as jnp; x=float(jnp.ones(8).sum()); "
              "print(d[0].platform, d[0], round((time.time()-t0)*1000))")
@@ -61,8 +81,8 @@ def check_chip(timeout_s: float) -> bool:
                               timeout=timeout_s)
     except subprocess.TimeoutExpired:
         return _result("chip", "fail",
-                       f"backend init hung > {timeout_s:.0f}s — transport "
-                       "wedged? (retry later; develop on cpu)")
+                       f"backend init hung > {timeout_s:.0f}s — is the "
+                       "chip held by another process?")
     if proc.returncode != 0:
         tail = (proc.stderr or proc.stdout).strip().splitlines()
         return _result("chip", "fail", tail[-1] if tail else "unknown")
@@ -83,34 +103,26 @@ def check_discovery(chip_ok: bool, timeout_s: float) -> bool:
                        f"(fake) {len(chips)} chip(s); first: "
                        f"{chips[0].chip_id}")
     if not chip_ok:
-        # Live discovery initializes the backend in-process — on a wedged
-        # transport that hangs where no timeout can reach.
         return _result("discovery", "skip",
                        "chip unreachable; set KUBESHARE_TPU_FAKE_TOPOLOGY "
                        "to exercise the fake path")
-    probe = ("from kubeshare_tpu.topology.discovery import discover_chips; "
-             "cs = discover_chips('jax'); c = cs[0]; "
-             "print(len(cs), c.chip_id, c.memory >> 30, c.coords)")
+    # The daemons' own discovery: a child process that gives the chip
+    # back, or — while the chip proxy owns it — the inventory that child
+    # left in the node's scheduler dir. Never an in-process backend.
+    from .topology.discovery import node_inventory
+    state_dir = C.SCHEDULER_DIR if _proxy_listening() else None
     try:
-        proc = subprocess.run([sys.executable, "-c", probe],
-                              capture_output=True, text=True,
-                              timeout=timeout_s)
-    except subprocess.TimeoutExpired:
-        return _result("discovery", "fail", "hung — transport wedged?")
-    if proc.returncode != 0:
-        tail = (proc.stderr or proc.stdout).strip().splitlines()
-        return _result("discovery", "fail", tail[-1] if tail else "unknown")
-    # The TPU runtime may interleave banners/absl logs into stdout; the
-    # probe's own line is the last one.  Parse defensively — a report tool
-    # must never die with a traceback mid-report.
-    lines = proc.stdout.strip().splitlines()
-    try:
-        n, chip_id, gib, coords = lines[-1].split(maxsplit=3)
-    except (IndexError, ValueError):
-        return _result("discovery", "fail",
-                       f"unexpected probe output: {proc.stdout!r:.200}")
+        inv = node_inventory(state_dir=state_dir, timeout_s=timeout_s)
+    except Exception as exc:
+        return _result("discovery", "fail", f"{type(exc).__name__}: {exc}")
+    chips = inv["chips"]
+    if not chips:
+        return _result("discovery", "fail", "no chips enumerated")
+    c = chips[0]
     return _result("discovery", "ok",
-                   f"{n} chip(s); first: {chip_id} {gib}GiB coords={coords}")
+                   f"{len(chips)} chip(s) on {inv['platform']}; first: "
+                   f"{c['chip_id']} {int(c['memory']) >> 30}GiB "
+                   f"coords={c['coords']}")
 
 
 def _get(url: str, timeout_s: float) -> str:

@@ -13,7 +13,7 @@ four planes that each already existed but were never connected:
     :class:`~..autopilot.cooldown.CooldownLedger` rail so elastic,
     autopilot and rightsizer never fight over a pod;
   * **carve** — the committed chip set renders through
-    :func:`~..gang.carve.carve_env` into the new ``TPU_VISIBLE_CHIPS``
+    :func:`~..gang.carve.carve_env` into the new ``KUBESHARE_TPU_VISIBLE_CHIPS``
     layout the training processes rebuild their NamedSharding mesh
     from (``elastic/restate.py`` re-shards the live state);
   * **journal** — a plan→pause→restate→flip→resume state machine in
@@ -307,7 +307,7 @@ class ElasticOrchestrator:
         the dispatcher lock (the ``resize_request`` in-place mutation
         idiom). Raises :class:`_FlipError` with everything rolled back
         when the cluster changed under the pause. Returns the new
-        ``TPU_VISIBLE_CHIPS`` layout."""
+        ``KUBESHARE_TPU_VISIBLE_CHIPS`` layout."""
         from .. import constants as C
 
         eng = d.engine

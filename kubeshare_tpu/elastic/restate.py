@@ -1,7 +1,7 @@
 """Re-shard live training state onto a resized mesh (doc/elastic.md).
 
 The flip half of an elastic resize is pure control plane — bookings and
-the ``TPU_VISIBLE_CHIPS`` layout. This module is the data plane: while
+the ``KUBESHARE_TPU_VISIBLE_CHIPS`` layout. This module is the data plane: while
 the gang is drain-paused, every param/optimizer leaf moves from the old
 :class:`~jax.sharding.NamedSharding` to the layout
 :func:`~..parallel.mesh.param_sharding` assigns on the NEW mesh, by the

@@ -80,7 +80,7 @@ class ElasticTrainer:
         """Adapt to the orchestrator's restate callback:
         ``device_bank`` maps a planned chip count to the device list to
         use (in-process stand-in for the launcher re-rendering
-        ``TPU_VISIBLE_CHIPS``). Raising propagates — the orchestrator
+        ``KUBESHARE_TPU_VISIBLE_CHIPS``). Raising propagates — the orchestrator
         aborts the resize back to the old mesh."""
 
         def _restate(plan: dict) -> None:

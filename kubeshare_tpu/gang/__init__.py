@@ -3,7 +3,7 @@ sub-meshes (doc/gang.md).
 
 :mod:`.coordinator` — :class:`~.coordinator.GangTokenCoordinator`,
 two-phase reserve/commit grants spanning every member chip.
-:mod:`.carve` — the ``TPU_VISIBLE_CHIPS`` carve format
+:mod:`.carve` — the ``KUBESHARE_TPU_VISIBLE_CHIPS`` carve format
 (``chip@x.y``) and block validation against the planned sub-mesh.
 """
 

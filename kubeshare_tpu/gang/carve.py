@@ -1,5 +1,5 @@
 """Sub-mesh carving: the scheduler's ``select_submesh`` block rendered
-into the ``TPU_VISIBLE_CHIPS`` env contract and parsed back.
+into the ``KUBESHARE_TPU_VISIBLE_CHIPS`` env contract and parsed back.
 
 Wire format (backward compatible): each comma-separated entry is either
 the seed form ``chip_id`` or the carved form ``chip_id@x.y`` where the
@@ -12,7 +12,7 @@ block and can rebuild the gang's device mesh from it.
 Because ``select_block`` places blocks on a *torus*, a carve may wrap an
 axis (coords ``{0, 3}`` on a 4-wide ring are adjacent). Validating that
 a carve is the contiguous block the scheduler planned therefore needs
-the node mesh shape, carried separately in ``KUBESHARE_TPU_MESH``
+the node mesh shape, carried separately in ``KUBESHARE_TPU_NODE_MESH``
 (``constants.ENV_MESH_SHAPE``, e.g. ``"2x4"``) — overloading the chip
 list itself would break the seed parser's fail-closed contract.
 """
@@ -47,7 +47,7 @@ def parse_mesh(text: str) -> tuple[int, ...]:
 
 
 def carve_env(chip_ids, coords_list) -> str:
-    """Render chip ids + their mesh coords into the TPU_VISIBLE_CHIPS
+    """Render chip ids + their mesh coords into the KUBESHARE_TPU_VISIBLE_CHIPS
     value. ``coords_list`` entries may be ``None``/empty (chips without
     topology coords fall back to the seed form)."""
     if len(chip_ids) != len(coords_list):
@@ -64,7 +64,7 @@ def carve_env(chip_ids, coords_list) -> str:
 
 
 def parse_visible_chips(env: str) -> list[tuple[str, tuple[int, ...] | None]]:
-    """Parse a TPU_VISIBLE_CHIPS value into ``[(chip_id, coords|None)]``.
+    """Parse a KUBESHARE_TPU_VISIBLE_CHIPS value into ``[(chip_id, coords|None)]``.
     Seed-form entries parse with ``coords=None``."""
     out: list[tuple[str, tuple[int, ...] | None]] = []
     for entry in env.split(","):

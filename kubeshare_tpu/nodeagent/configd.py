@@ -122,7 +122,11 @@ def main(argv=None) -> None:
     parser.add_argument("--period", type=float, default=DEFAULT_PERIOD_S)
     args = parser.parse_args(argv)
 
-    chips = discover_chips(args.backend, host=args.node)
+    # the node inventory the launcher's probe wrote (or a probe of its
+    # own, in a child) — never an in-process backend: the chip proxy
+    # owns the chip by the time this daemon runs
+    chips = discover_chips(args.backend, host=args.node,
+                           state_dir=args.base_dir)
     daemon = ConfigDaemon(
         RegistryClient(args.registry_host, args.registry_port),
         node=args.node, chip_ids=[c.chip_id for c in chips],

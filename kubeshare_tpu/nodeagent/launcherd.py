@@ -46,7 +46,10 @@ def default_proxy_cmd(chip_id: str, index: int, exec_port: int,
     """The real per-chip command (gem-schd launch parity,
     ``launcher.py:22-32``)."""
     env = dict(os.environ)
-    env[C.ENV_VISIBLE_CHIPS] = str(index)
+    # the proxy owns exactly this chip: the grant in the repo's variable,
+    # the local index in the runtime's own (attach._pin_visible_devices
+    # makes the same translation for pods)
+    env[C.ENV_VISIBLE_CHIPS] = chip_id
     env["TPU_VISIBLE_DEVICES"] = str(index)
     cmd = [sys.executable, "-m", "kubeshare_tpu.isolation.proxy",
            "-P", str(exec_port), "-S", str(token_port)]
@@ -235,7 +238,10 @@ def main(argv=None) -> None:
                              "metric snapshot (doc/observability.md)")
     args = parser.parse_args(argv)
 
-    chips = discover_chips(args.backend, host=args.node)
+    # in a child + the node inventory (topology/discovery.py): this
+    # daemon must never hold the chips its proxies are about to take
+    chips = discover_chips(args.backend, host=args.node,
+                           state_dir=args.base_dir)
     daemon = LauncherDaemon([c.chip_id for c in chips],
                             base_dir=args.base_dir, poll_s=args.poll)
     daemon.start()

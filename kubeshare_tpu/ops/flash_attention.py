@@ -24,8 +24,9 @@ one accumulating dQ over the k loop, one accumulating dK/dV over the q
 loop (separate kernels so each accumulator is owned by exactly one
 sequential grid lane — no cross-program races). Peak memory is
 O(block²) on the backward too, so long sequences train, not just
-infer. The public entry falls back to interpreter mode off-TPU, so CPU
-CI runs the identical kernel bodies.
+infer. Compiled (Mosaic) where the program is lowered for a TPU, the
+interpreter elsewhere (:mod:`.kernelcall`), so CPU CI runs the identical
+kernel bodies and a proxy-attached pod's export carries the compiled one.
 """
 
 from __future__ import annotations
@@ -39,6 +40,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .attention import MASK_VALUE, kv_groups
+from .kernelcall import kernel_call
 
 BLOCK_Q = 128
 BLOCK_K = 128
@@ -183,7 +185,7 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, window=None):
     qr, kr, vr = _fold(q), _fold(k), _fold(v)
     vma = _vma(q, k, v)
 
-    out, lse = pl.pallas_call(
+    out, lse = kernel_call(lambda interp: pl.pallas_call(
         functools.partial(_kernel, block_q=bq, block_k=bk, n_k=n_k,
                           causal=causal, scale=scale, window=window),
         grid=(b * h, s_q // bq, n_k),
@@ -205,8 +207,8 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, window=None):
             pltpu.VMEM((bq, 1), jnp.float32),   # running sum
             pltpu.VMEM((bq, d), jnp.float32),   # output accumulator
         ],
-        interpret=interpret,
-    )(qr, kr, vr)
+        interpret=interp,
+    ), qr, kr, vr, interpret=interpret)
     return _unfold(out, b, h), lse
 
 
@@ -327,7 +329,7 @@ def _flash_bwd(q, k, v, o, lse, g, g_lse, causal, block_q, block_k,
     kspec = pl.BlockSpec((1, bk, d), lambda i, j, kk: (kvrow(i), kk, 0))
     rowspec = pl.BlockSpec((1, bq, 1), lambda i, j, kk: (i, j, 0))
 
-    dq = pl.pallas_call(
+    dq = kernel_call(lambda interp: pl.pallas_call(
         functools.partial(_bwd_dq_kernel, block_q=bq, block_k=bk, n_k=n_k,
                           causal=causal, scale=scale, window=window),
         grid=(b * h, n_q, n_k),
@@ -335,8 +337,8 @@ def _flash_bwd(q, k, v, o, lse, g, g_lse, causal, block_q, block_k,
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((b * h, s_q, d), q.dtype, vma=vma),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=interpret,
-    )(qr, kr, vr, dor, lse, dcap)
+        interpret=interp,
+    ), qr, kr, vr, dor, lse, dcap, interpret=interpret)
 
     # dK/dV grid: one row per batch·KV-head; k blocks outer; the
     # sequential inner dim walks this kv head's whole GROUP of q heads ×
@@ -353,7 +355,7 @@ def _flash_bwd(q, k, v, o, lse, g, g_lse, causal, block_q, block_k,
     kspec2 = pl.BlockSpec((1, bk, d), lambda i, jj, t: (i, jj, 0))
     rowspec2 = pl.BlockSpec((1, bq, 1),
                             lambda i, jj, t: (qrow(i, t), t % n_q, 0))
-    dk, dv = pl.pallas_call(
+    dk, dv = kernel_call(lambda interp: pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, block_q=bq, block_k=bk, n_q=n_q,
                           group=group, causal=causal, scale=scale,
                           window=window),
@@ -366,8 +368,8 @@ def _flash_bwd(q, k, v, o, lse, g, g_lse, causal, block_q, block_k,
                                         vma=vma)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        interpret=interpret,
-    )(qr, kr, vr, dor, lse, dcap)
+        interpret=interp,
+    ), qr, kr, vr, dor, lse, dcap, interpret=interpret)
 
     return _unfold(dq, b, h), _unfold(dk, b, hk), _unfold(dv, b, hk)
 
@@ -443,15 +445,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     RING path stays full-causal (its per-step switch has no global
     offsets).
 
-    ``interpret=None`` auto-selects: compiled on TPU, interpreter
-    elsewhere (the interpreter runs the identical kernel body, so CPU CI
-    covers it bit-for-bit). Plug into ``mha_apply(attn_fn=...)`` /
+    ``interpret=None`` follows the platform the program is lowered for:
+    compiled for a TPU, interpreter elsewhere (the interpreter runs the
+    identical kernel body, so CPU CI covers it bit-for-bit). Plug into ``mha_apply(attn_fn=...)`` /
     ``transformer.apply`` for the single-chip long-context path.
     """
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
-    return _flash(q, k, v, causal, block_q, block_k, bool(interpret),
-                  window)
+    return _flash(q, k, v, causal, block_q, block_k, interpret, window)
 
 
 def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -469,7 +468,5 @@ def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array,
     — the composition :mod:`kubeshare_tpu.parallel.ringattention` uses
     to run this kernel per ring step. Differentiable in both outputs
     (the lse cotangent folds into the same backward kernels)."""
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
-    return _flash_lse(q, k, v, causal, block_q, block_k, bool(interpret),
+    return _flash_lse(q, k, v, causal, block_q, block_k, interpret,
                       window)

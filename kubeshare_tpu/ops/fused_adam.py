@@ -10,9 +10,9 @@ allocation at all (``input_output_aliases``).
 XLA usually fuses the optax chain well on its own; this kernel exists
 for the cases it doesn't (long chains interleaved with collectives) and
 as the framework's demonstration of the Pallas path for hot ops. The
-public entry :func:`adam_update` transparently falls back to the pure
-``jnp`` reference off-TPU, and the test suite runs the kernel in
-interpreter mode so CPU CI covers the same code path bit-for-bit.
+kernel is compiled (Mosaic) where the program is lowered for a TPU and
+interpreted elsewhere (:mod:`.kernelcall`), so CPU CI covers the same
+kernel body bit-for-bit; nothing drops to the ``jnp`` reference.
 """
 
 from __future__ import annotations
@@ -22,19 +22,30 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .kernelcall import kernel_call
+
 # Tiles: float32 min tile is (8, 128); one row-block of 1024 lanes keeps
 # the kernel shape-agnostic after the pad-and-reshape below.
 _LANES = 128
 _ROWS = 8
 
 
-def _adam_math(p, g, m, v, t, lr, b1, b2, eps):
-    """One Adam step (bias-corrected, Kingma & Ba 2014) — shared by the
-    kernel body and the reference so they cannot drift."""
+def _bias_corrections(t, b1, b2):
+    """``(1 - b1^t, 1 - b2^t)`` — computed by XLA OUTSIDE the kernel: the
+    chip's kernel compiler has no scalar ``pow`` (Mosaic: "failed to
+    legalize operation 'math.powf'"), and two scalars per step are not
+    worth a vector exp/log inside it."""
+    return 1.0 - b1 ** t, 1.0 - b2 ** t
+
+
+def _adam_math(p, g, m, v, c1, c2, lr, b1, b2, eps):
+    """One Adam step (bias-corrected, Kingma & Ba 2014) given the two
+    bias corrections — shared by the kernel body and the reference so
+    they cannot drift."""
     m_new = b1 * m + (1.0 - b1) * g
     v_new = b2 * v + (1.0 - b2) * (g * g)
-    m_hat = m_new / (1.0 - b1 ** t)
-    v_hat = v_new / (1.0 - b2 ** t)
+    m_hat = m_new / c1
+    v_hat = v_new / c2
     p_new = p - lr * m_hat / (jnp.sqrt(v_hat) + eps)
     return p_new, m_new, v_new
 
@@ -42,15 +53,16 @@ def _adam_math(p, g, m, v, t, lr, b1, b2, eps):
 def adam_update_reference(p, g, m, v, step, lr=1e-3, b1=0.9, b2=0.999,
                           eps=1e-8):
     """Pure-jnp Adam step; ``step`` is the 1-based step count."""
-    t = jnp.asarray(step, p.dtype)
-    return _adam_math(p, g, m, v, t, lr, b1, b2, eps)
+    c1, c2 = _bias_corrections(jnp.asarray(step, p.dtype), b1, b2)
+    return _adam_math(p, g, m, v, c1, c2, lr, b1, b2, eps)
 
 
-def _kernel(step_ref, p_ref, g_ref, m_ref, v_ref,
+def _kernel(corr_ref, p_ref, g_ref, m_ref, v_ref,
             p_out, m_out, v_out, *, lr, b1, b2, eps):
-    t = step_ref[0].astype(p_ref.dtype)
+    c1 = corr_ref[0].astype(p_ref.dtype)
+    c2 = corr_ref[1].astype(p_ref.dtype)
     p_new, m_new, v_new = _adam_math(
-        p_ref[:], g_ref[:], m_ref[:], v_ref[:], t, lr, b1, b2, eps)
+        p_ref[:], g_ref[:], m_ref[:], v_ref[:], c1, c2, lr, b1, b2, eps)
     p_out[:] = p_new
     m_out[:] = m_new
     v_out[:] = v_new
@@ -75,8 +87,9 @@ def _fused_flat(p, g, m, v, step, lr, b1, b2, eps, interpret):
                         memory_space=pltpu.VMEM)
     out_shape = [jax.ShapeDtypeStruct(p2.shape, p2.dtype)] * 3
     kernel = functools.partial(_kernel, lr=lr, b1=b1, b2=b2, eps=eps)
-    step_arr = jnp.asarray([step], jnp.float32)
-    p3, m3, v3 = pl.pallas_call(
+    corr = jnp.stack(_bias_corrections(jnp.asarray(step, jnp.float32),
+                                       b1, b2))
+    p3, m3, v3 = kernel_call(lambda interp: pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -85,8 +98,8 @@ def _fused_flat(p, g, m, v, step, lr, b1, b2, eps, interpret):
         out_shape=out_shape,
         # p, m, v update in place: zero extra HBM for the step
         input_output_aliases={1: 0, 3: 1, 4: 2},
-        interpret=interpret,
-    )(step_arr, p2, g2, m2, v2)
+        interpret=interp,
+    ), corr, p2, g2, m2, v2, interpret=interpret)
     unpad = lambda x: x.reshape(-1)[:n]
     return unpad(p3), unpad(m3), unpad(v3)
 
@@ -95,17 +108,16 @@ def adam_update(p, g, m, v, step, lr=1e-3, b1=0.9, b2=0.999, eps=1e-8,
                 interpret: bool | None = None):
     """Adam step over one tensor via the Pallas kernel.
 
-    ``interpret=None`` auto-selects: compiled on TPU, interpreter
-    elsewhere (the interpreter runs the identical kernel body, so CPU CI
-    exercises the real code path). Arbitrary shapes are flattened, padded
-    to the (8, 128) float32 tile, and restored.
+    ``interpret=None`` follows the platform the program is lowered for:
+    compiled for a TPU, interpreter elsewhere (the interpreter runs the
+    identical kernel body, so CPU CI exercises the real code path).
+    Arbitrary shapes are flattened, padded to the (8, 128) float32 tile,
+    and restored.
     """
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
     shape = p.shape
     flat = lambda x: jnp.asarray(x).reshape(-1)
     p2, m2, v2 = _fused_flat(flat(p), flat(g), flat(m), flat(v),
-                             step, lr, b1, b2, eps, bool(interpret))
+                             step, lr, b1, b2, eps, interpret)
     return p2.reshape(shape), m2.reshape(shape), v2.reshape(shape)
 
 

@@ -129,7 +129,7 @@ def shard_init(init_fn: Callable, key, mesh: Mesh):
 def make_carved_mesh(carve: str, devices=None,
                      mesh_shape: str | tuple[int, ...] | None = None) -> Mesh:
     """Build the gang's 2D ``(dp, tp)`` mesh from a carved
-    ``TPU_VISIBLE_CHIPS`` value (``"chip@x.y,..."``, doc/gang.md).
+    ``KUBESHARE_TPU_VISIBLE_CHIPS`` value (``"chip@x.y,..."``, doc/gang.md).
 
     The carve is validated against the planned sub-mesh block first —
     ``mesh_shape`` is the node mesh (``constants.ENV_MESH_SHAPE``, e.g.

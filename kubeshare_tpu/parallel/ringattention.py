@@ -89,8 +89,7 @@ def ring_attention_shard(q: jax.Array, k: jax.Array, v: jax.Array,
     o = qf * 0.0
     m = qf.max(axis=-1) * 0.0 + MASK_VALUE
     l = qf.sum(axis=-1) * 0.0
-    # sp is static at trace time → static trip count (no dynamic-trip
-    # dispatch cliff; see doc/bench-notes.md).
+    # sp is static at trace time → static trip count.
     o, m, l, _, _ = lax.fori_loop(0, sp, step, (o, m, l, k, v),
                                   unroll=True)
     return o / jnp.where(l > 0.0, l, 1.0)[..., None]

@@ -99,6 +99,9 @@ def distributed_init_from_env(env: dict | None = None) -> bool:
     if _initialized:
         return True
     import jax
+
+    from ..utils.compilecache import enable_compile_cache
+    enable_compile_cache()   # every member compiles the same gang step
     kwargs = {}
     timeout_s = env.get(C.ENV_RENDEZVOUS_TIMEOUT_S, "")
     if timeout_s:

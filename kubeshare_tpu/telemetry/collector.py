@@ -40,12 +40,18 @@ class CapacityCollector:
 
     def __init__(self, registry: RegistryClient, node: str | None = None,
                  backend: str = "auto", period_s: float = DEFAULT_PERIOD_S,
-                 lease_ttl_s: float = C.LEASE_TTL_S):
+                 lease_ttl_s: float = C.LEASE_TTL_S,
+                 state_dir: str | None = None):
         from ..utils import default_node_name
 
         self.registry = registry
         self.node = node or default_node_name()
         self.backend = backend
+        #: where the node's chip inventory lives (live discovery only):
+        #: the collector observes chips, it must never take them, so it
+        #: reads what a probe child found — the same record launcherd
+        #: and configd use — instead of enumerating in-process
+        self.state_dir = state_dir
         self.period_s = period_s
         # liveness rides with the collector: capacity says WHAT the node
         # offers, the lease says it is still THERE (doc/health.md keeps
@@ -65,7 +71,8 @@ class CapacityCollector:
         if self.heartbeat is not None:
             self.heartbeat.beat_once()
         try:
-            chips = discover_chips(self.backend, host=self.node)
+            chips = discover_chips(self.backend, host=self.node,
+                                   state_dir=self.state_dir)
         except Exception as e:
             log.error("chip discovery failed: %s", e)
             try:
@@ -165,7 +172,8 @@ def main(argv=None) -> None:
 
     collector = CapacityCollector(
         RegistryClient(args.registry_host, args.registry_port),
-        node=args.node, backend=args.backend, period_s=args.period)
+        node=args.node, backend=args.backend, period_s=args.period,
+        state_dir=C.SCHEDULER_DIR)
     collector.collect_once()
     collector.start()
     if args.metrics_port:

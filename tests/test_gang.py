@@ -24,7 +24,7 @@ MIN = 10.0
 
 
 # --------------------------------------------------------------------------
-# carve wire format: select_submesh block <-> TPU_VISIBLE_CHIPS
+# carve wire format: select_submesh block <-> KUBESHARE_TPU_VISIBLE_CHIPS
 # --------------------------------------------------------------------------
 
 def test_carve_env_round_trips_chips_and_coords():
@@ -102,7 +102,7 @@ def test_carve_block_rejects_scatter_holes_and_junk():
 
 
 # --------------------------------------------------------------------------
-# carved mesh: TPU_VISIBLE_CHIPS -> NamedSharding-ready Mesh
+# carved mesh: KUBESHARE_TPU_VISIBLE_CHIPS -> NamedSharding-ready Mesh
 # --------------------------------------------------------------------------
 
 def test_make_carved_mesh_builds_usable_namedsharding():

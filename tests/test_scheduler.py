@@ -269,7 +269,7 @@ def test_gang_locality_prefers_same_host():
 
 
 def test_gang_binding_env_round_trips_to_planned_block():
-    """The carved TPU_VISIBLE_CHIPS env (doc/gang.md) must parse back to
+    """The carved KUBESHARE_TPU_VISIBLE_CHIPS env (doc/gang.md) must parse back to
     exactly the contiguous sub-mesh block the scheduler planned, and the
     seed-format chip list must survive a strip."""
     from kubeshare_tpu.gang import (carve_block, parse_mesh,

@@ -1,0 +1,134 @@
+"""The chip's compiler, without the chip.
+
+libtpu is installed here and compiles for a TPU that is *described*, not
+attached (``on-chip-measurement`` guide §2, third rehearsal). These tests
+hand it the Pallas kernels at the shapes ``chip_smoke.py`` runs — what the
+interpret-mode tests can never see: a refused op (the fused Adam's scalar
+``pow`` was one), an unaligned block, too much VMEM.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may load the TPU library, and under xdist every
+worker imports every test file. All such tests live in this one file.
+A compile that passes here is not a chip run.
+"""
+
+import importlib
+import os
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+
+from chip_smoke import Plan  # noqa: E402
+
+fa = importlib.import_module("kubeshare_tpu.ops.flash_attention")
+fad = importlib.import_module("kubeshare_tpu.ops.fused_adam")
+
+PLAN = Plan()
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """Shapes placed on one described chip — with the persistent compile
+    cache off: an entry written by such a compile cannot be read back
+    without a chip, and the next run would warn on every test."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    sharding = SingleDeviceSharding(topo.devices[0])
+
+    def shaped(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    yield shaped
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _kernel_count(compiled) -> int:
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+
+
+@pytest.mark.parametrize("window", [None, PLAN.kernel_window])
+@pytest.mark.parametrize("b,s,h,hk,d", PLAN.kernel_cases)
+def test_flash_forward_compiles_for_v5e(one_chip, b, s, h, hk, d, window):
+    q, kv = one_chip((b, s, h, d)), one_chip((b, s, hk, d))
+    compiled = jax.jit(
+        lambda q, k, v: fa._flash_fwd(q, k, v, True, fa.BLOCK_Q, fa.BLOCK_K,
+                                      None, window)
+    ).lower(q, kv, kv).compile()
+    assert _kernel_count(compiled) == 1
+
+
+@pytest.mark.parametrize("window", [None, PLAN.kernel_window])
+@pytest.mark.parametrize("b,s,h,hk,d", PLAN.kernel_cases)
+def test_flash_dq_and_dkv_compile_for_v5e(one_chip, b, s, h, hk, d, window):
+    q, kv = one_chip((b, s, h, d)), one_chip((b, s, hk, d))
+    lse = one_chip((b * h, s, 1))
+    compiled = jax.jit(
+        lambda q, k, v, o, lse, g: fa._flash_bwd(
+            q, k, v, o, lse, g, None, True, fa.BLOCK_Q, fa.BLOCK_K, None,
+            window)
+    ).lower(q, kv, kv, q, lse, q).compile()
+    assert _kernel_count(compiled) == 2     # the dQ pass and the dK/dV pass
+
+
+@pytest.mark.parametrize("n", PLAN.adam_sizes)
+def test_fused_adam_compiles_for_v5e(one_chip, n):
+    x = one_chip((n,))
+    compiled = jax.jit(
+        lambda p, g, m, v: fad.adam_update(p, g, m, v, step=3)
+    ).lower(x, x, x, x).compile()
+    assert _kernel_count(compiled) == 1
+
+
+# -- what a proxy-attached pod ships ---------------------------------------
+# No topology needed: the pod traces on its CPU backend and exports for the
+# proxy's platform (isolation/client.py _trace_and_compile). The program
+# that reaches the TPU must carry the Mosaic kernel, not the interpreter.
+
+def _exported_for(platform, fn, *specs) -> str:
+    from jax import export
+    return export.export(jax.jit(fn), platforms=[platform])(
+        *specs).mlir_module()
+
+
+def test_cpu_traced_export_for_tpu_carries_the_compiled_flash_kernel():
+    q = jax.ShapeDtypeStruct((2, 256, 8, 32), jnp.float32)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True).sum()
+
+    assert jax.devices()[0].platform == "cpu"     # the tracing process
+    text = _exported_for("tpu", jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    assert text.count("tpu_custom_call") == 3     # fwd, dQ, dK/dV
+    assert "tpu_custom_call" not in _exported_for(
+        "cpu", jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+
+
+def test_cpu_traced_export_for_tpu_carries_the_compiled_adam_kernel():
+    x = jax.ShapeDtypeStruct((4096,), jnp.float32)
+
+    def step(p, g, m, v):
+        return fad.adam_update(p, g, m, v, step=1)
+
+    assert _exported_for("tpu", step, x, x, x, x).count(
+        "tpu_custom_call") == 1
+    assert "tpu_custom_call" not in _exported_for("cpu", step, x, x, x, x)
