@@ -1,0 +1,30 @@
+"""The whole step's share of the chip's peak: model operations of the
+window (6 N per trained token and 2 N per scored token, N the parameters
+that multiply, plus attention, from the configuration's shapes) over
+window x peak bf16 FLOP/s (in a traced run: the part of the window
+before the tracer starts, which slows the host)."""
+from __future__ import annotations
+
+import sys
+from pathlib import Path
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+import readlib as R  # noqa: E402
+
+KIND, LAYER, UNIT, SOURCE, MOVES = "per_layer", "whole step", "%", "host_clock", "train_tokens_per_s"
+
+
+def read(run: dict):
+    cfg, ops, seconds = run["config"], 0.0, R.counted(run)[1]
+    for t in R.by_role(run, "train"):
+        e = t["entry"]
+        ops += R.counted_steps(run, t) * R.flops.train_flops(
+            cfg, int(e["batch"]), int(e["seq_len"]))
+    for t in R.by_role(run, "score"):
+        ops += sum(R.flops.score_flops(cfg, r["length"])
+                   for r in R.requests(run, t)
+                   if r["done_s"] is not None
+                   and r["done_s"] <= seconds)
+    if ops <= 0:
+        return None
+    return 100.0 * ops / (seconds * run["peaks"]["bf16_flops_per_s"])
