@@ -163,8 +163,9 @@ class _ProxyShim:
                 pass
 
     def fetch(self, buf) -> np.ndarray:
-        self._flush_frees()
-        return self.client.get(buf)
+        with self.client.shim_clock:    # the shim's own cost, in CPU time
+            self._flush_frees()
+            return self.client.get(buf)
 
     # -- the jax.jit replacement ------------------------------------------
 
@@ -197,14 +198,19 @@ class _RemoteJitFunction:
         self.__wrapped__ = fn
 
     def __call__(self, *args, **kwargs):
-        import jax
+        # from here to the result's handles is the shim's own cost: the
+        # thread's CPU time, so no wait for a reply is in it
+        with self._shim.client.shim_clock:
+            if _contains_tracers(args, kwargs):
+                # We're INSIDE a trace (a library helper jitted at call
+                # time, e.g. optax.tree.bias_correction, invoked from a
+                # function being remoted): inline into the enclosing
+                # program, exactly what a nested jit does.
+                return self._fn(*args, **kwargs)
+            return self._call_remote(args, kwargs)
 
-        if _contains_tracers(args, kwargs):
-            # We're INSIDE a trace (a library helper jitted at call time,
-            # e.g. optax.tree.bias_correction, invoked from a function
-            # being remoted): inline into the enclosing program, exactly
-            # what a nested jit does.
-            return self._fn(*args, **kwargs)
+    def _call_remote(self, args, kwargs):
+        import jax
 
         shim = self._shim
         shim._flush_frees()

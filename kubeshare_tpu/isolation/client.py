@@ -50,6 +50,68 @@ def _real_jit():
     return real_jit()
 
 
+class ShimClock:
+    """What the tenant's side costs between two ``execute`` sends,
+    measured where it is spent and carried on the next ``execute``
+    (``protocol.SHIM_KEY``).
+
+    ``with clock:`` around shim code counts the calling thread's CPU time
+    there (``time.thread_time``; sections nest, the outermost counts):
+    the shim's and the client's own Python and the system calls of the
+    ``free``/``put``/``get`` round trips it chooses to make. A wait for a
+    reply costs the thread nothing, so none is in it: that time is the
+    wire's, the proxy's or, behind its device lock, the neighbour's
+    program's. ``replied`` records an ``execute``'s round trip, from the
+    send to the reply's coming in (not to the caller's asking for it: an
+    async caller may do its own work in between), a difference of
+    ``time.monotonic`` inside this process.
+    """
+
+    def __init__(self):
+        self._local = threading.local()     # depth, t0 of a thread
+        self._mu = threading.Lock()
+        self._shim_s = 0.0
+        self._sent = 0                      # execute sends so far
+        self._rtt = (0, 0.0)                # (of send number, seconds)
+
+    def __enter__(self) -> "ShimClock":
+        loc = self._local
+        depth = getattr(loc, "depth", 0)
+        if depth == 0:
+            loc.t0 = time.thread_time()
+        loc.depth = depth + 1
+        return self
+
+    def __exit__(self, *exc) -> None:
+        loc = self._local
+        loc.depth -= 1
+        if loc.depth == 0:
+            with self._mu:
+                self._shim_s += time.thread_time() - loc.t0
+
+    def send(self) -> tuple[int, float, dict]:
+        """An ``execute`` goes out now: its number, the time, and the
+        report of what was measured since the one before."""
+        loc = self._local
+        with self._mu:
+            if getattr(loc, "depth", 0):    # the open section, so far
+                cpu = time.thread_time()
+                self._shim_s += cpu - loc.t0
+                loc.t0 = cpu
+            report = {"shim_ms": round(self._shim_s * 1e3, 3)}
+            if self._sent and self._rtt[0] == self._sent:
+                report["rtt_ms"] = round(self._rtt[1] * 1e3, 3)
+            self._shim_s = 0.0
+            self._sent += 1
+            return self._sent, time.monotonic(), report
+
+    def replied(self, number: int, t_send: float, t_reply: float) -> None:
+        """``execute`` number ``number``, sent at ``t_send``, had its
+        reply come in at ``t_reply``."""
+        with self._mu:
+            self._rtt = (number, t_reply - t_send)
+
+
 @dataclass(frozen=True)
 class RemoteBuffer:
     """A device-resident array on the proxy."""
@@ -258,6 +320,8 @@ class ProxyClient:
                  reconnect="auto", fault_tag: str = "",
                  tpu_class: str = "best-effort"):
         self.name = name
+        #: the tenant side's own cost, reported on every execute
+        self.shim_clock = ShimClock()
         #: transfer slab size for put/get; arrays whose serialized form
         #: exceeds it stream in slices, so checkpoint-sized buffers cross a
         #: wire whose frame cap is far smaller than the buffer.
@@ -592,6 +656,8 @@ class ProxyClient:
             msg["donate"] = list(donate)
         if repeat != 1:
             msg["repeat"] = repeat
+        clock = self.shim_clock
+        number, t_send, msg[protocol.SHIM_KEY] = clock.send()
         tid = getattr(self._conn, "trace_id", "")
         tracer = obs_trace.get_tracer() if tid else None
         t0 = tracer.now_ms() if tracer is not None else 0.0
@@ -599,7 +665,9 @@ class ProxyClient:
             rep = self._conn.submit(msg, defer=defer)
 
             def resolve():
-                handles_out = list(rep.result()[0]["handles"])
+                reply = rep.result()[0]
+                clock.replied(number, t_send, rep.done_at)
+                handles_out = list(reply["handles"])
                 if tracer is not None:
                     # client-measured round trip: the critical-path
                     # "transport" segment (the proxy's own "execute"
@@ -610,6 +678,7 @@ class ProxyClient:
 
             return RemoteFuture(resolve, rep)
         reply, _ = self._conn.call(msg)   # lockstep: resolved already
+        clock.replied(number, t_send, time.monotonic())
         if tracer is not None:
             tracer.record("transport", tid, t0, tracer.now_ms(),
                           proc="client", op="execute")
@@ -640,6 +709,8 @@ class ProxyClient:
             n = int(reply.get("repeat", repeat))
             return list(reply["handles"]), n, int(reply.get("burst", n))
 
+        clock = self.shim_clock
+        number, t_send, msg[protocol.SHIM_KEY] = clock.send()
         tid = getattr(self._conn, "trace_id", "")
         tracer = obs_trace.get_tracer() if tid else None
         t0 = tracer.now_ms() if tracer is not None else 0.0
@@ -648,7 +719,9 @@ class ProxyClient:
             rep = self._conn.submit(msg)
 
             def resolve():
-                out = unwrap(rep.result()[0])
+                reply = rep.result()[0]
+                clock.replied(number, t_send, rep.done_at)
+                out = unwrap(reply)
                 if tracer is not None:
                     tracer.record("transport", tid, t0, tracer.now_ms(),
                                   proc="client", op="execute")
@@ -656,6 +729,7 @@ class ProxyClient:
 
             return RemoteFuture(resolve, rep)
         reply, _ = self._conn.call(msg)   # lockstep: resolved already
+        clock.replied(number, t_send, time.monotonic())
         if tracer is not None:
             tracer.record("transport", tid, t0, tracer.now_ms(),
                           proc="client", op="execute")

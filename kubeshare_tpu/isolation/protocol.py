@@ -64,6 +64,16 @@ RID_KEY = "_rid"
 #: client has observed. Lets the server prune its replay cache.
 ACK_KEY = "_ack"
 
+#: optional key of an ``execute`` request (of the op's schema: not one of
+#: the reserved transport keys above): what the tenant's side measured
+#: since its previous ``execute`` send, ``{"shim_ms": <CPU time of the
+#: calling thread inside shim and client code>, "rtt_ms": <the previous
+#: execute, send to reply coming in>}`` (``rtt_ms`` only where there was one).
+#: Differences taken inside the client process; the proxy adds them to
+#: the session's ``shim_ms_total`` / ``wire_ms_total``. A request without
+#: it, and a proxy that does not know it, behave as they always have.
+SHIM_KEY = "shim"
+
 #: transport features this build can negotiate at register time.
 FEATURES = ("resume", "seq", "preempt")
 
@@ -385,10 +395,14 @@ class PendingReply:
     money at pipelined small-op rates, and a windowed caller only ever
     blocks on one future at a time anyway."""
 
-    __slots__ = ("sink", "_cond", "_done", "_msg", "_blob", "_err")
+    __slots__ = ("sink", "done_at", "_cond", "_done", "_msg", "_blob",
+                 "_err")
 
     def __init__(self, sink=None, cond: threading.Condition | None = None):
         self.sink = sink
+        #: ``time.monotonic()`` when the reply came in (0.0 until then):
+        #: a round trip ends there, whenever the caller asks for it
+        self.done_at = 0.0
         self._cond = cond if cond is not None else threading.Condition()
         self._done = False
         self._msg = None
@@ -399,6 +413,7 @@ class PendingReply:
         with self._cond:
             self._msg = msg
             self._blob = blob
+            self.done_at = time.monotonic()
             self._done = True
             self._cond.notify_all()
 

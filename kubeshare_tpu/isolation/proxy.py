@@ -104,6 +104,12 @@ def _now_ms() -> float:
     return time.monotonic() * 1000.0
 
 
+#: per-session phase counters, cumulative milliseconds, reported by
+#: ``usage`` beside ``exec_ms_total`` (doc/observability.md names each)
+_PHASE_KEYS = ("self_ms_total", "idle_attach_ms_total", "idle_gate_ms_total",
+               "idle_proxy_ms_total", "shim_ms_total", "wire_ms_total")
+
+
 @dataclass
 class _Program:
     """Per-PROGRAM state, shared across sessions by blob hash.
@@ -166,6 +172,20 @@ class _Session:
     last_end_ms: float = 0.0      # when the last execution finished
     exec_count: int = 0
     exec_ms_total: float = 0.0
+    #: where this session's executions blocked and whose idle gap each
+    #: ended (_PHASE_KEYS; added to under ``lock``)
+    phase_ms: dict = field(
+        default_factory=lambda: dict.fromkeys(_PHASE_KEYS, 0.0))
+    # Phase stamps of the execute call in flight, all from _now_ms() on
+    # the connection's one worker thread: when the burst at the gate
+    # arrived (the request reaching _dispatch; in a chain, the previous
+    # burst's end), and for the whole call [arrival, ms spent waiting at
+    # the gate, for _dlock, or running on the device].
+    arrived_ms: float = 0.0
+    call: list | None = None
+    #: the previous execute's handler time (arrival to reply): what the
+    #: client's round trip minus this leaves is the wire
+    last_handler_ms: float | None = None
     # Chunked-transfer state (connection-serialized like everything else):
     # one cached serialized stream for sliced `get` as
     # (handle, parts list, total bytes) — parts, not joined bytes, so the
@@ -238,22 +258,28 @@ class _FifoLock:
         self._waiters: deque[threading.Event] = deque()
         self._held = False
 
-    def __enter__(self):
+    def acquire(self) -> None:
         with self._mu:
             if not self._held and not self._waiters:
                 self._held = True
-                return self
+                return
             ev = threading.Event()
             self._waiters.append(ev)
         ev.wait()  # ownership is handed off in release — no re-race
-        return self
 
-    def __exit__(self, *exc):
+    def release(self) -> None:
         with self._mu:
             if self._waiters:
                 self._waiters.popleft().set()
             else:
                 self._held = False
+
+    def __enter__(self):
+        self.acquire()
+        return self
+
+    def __exit__(self, *exc):
+        self.release()
         return False
 
 
@@ -324,6 +350,10 @@ class ChipProxy:
         # taken INSIDE the gate (never around it), so there is no ordering
         # cycle with the scheduler's own blocking.
         self._dlock = _FifoLock()
+        #: when the chip's last program ended (guarded by _dlock): the
+        #: start of the idle gap the next program ends. None until the
+        #: first program, whose gap is nobody's
+        self._last_device_end: float | None = None
         # blob-sha → _Program: compiled artifacts + burst cost model shared
         # across sessions (guarded by _slock for lookup; compiles race-safe
         # under _dlock). LRU-capped: a client churning unique programs must
@@ -652,7 +682,17 @@ class ChipProxy:
         renew forfeits the remaining quantum without ever interrupting
         an execute; the directed-grant queue hands the token to the
         higher-class beneficiary and then straight back.
+
+        Phase accounting (``_PHASE_KEYS``) happens here and in
+        :meth:`_run_fn`, so single executes and every burst of a chain
+        share it: the burst ``arrived`` at ``sess.arrived_ms``, was
+        ``granted`` when the scheduler returned (its ``ks.gate_wait``
+        event is written inside ``TokenScheduler.acquire``/``renew``),
+        and ``_run_fn`` leaves the ``_dlock`` wait and the idle gap's
+        split in ``timing``.
         """
+        timing = timing if timing is not None else {}
+        arrived = sess.arrived_ms
         with sess.lock:
             sess.busy = True
             holding = sess.holding
@@ -678,7 +718,10 @@ class ChipProxy:
                     sess.holding = True
                     sess.quota_ms = quota
                     sess.used_ms = 0.0
-            start = _now_ms()
+            granted = _now_ms()
+            gate_ms = granted - arrived if quota is not None else 0.0
+            timing.update(session=sess.name, arrived=arrived,
+                          granted=granted)
             # bracket the execute for the chip-time ledger: the hold is
             # granted-active only while fn() runs (getattr: injected
             # schedulers in tests may predate the ledger hooks)
@@ -694,23 +737,33 @@ class ChipProxy:
                 exec_end = getattr(self.scheduler, "execute_end", None)
                 if exec_end is not None:
                     exec_end()
-                wall = end - start
-                elapsed = (timing.get("exec_ms", wall)
-                           if timing is not None else wall)
+                elapsed = timing.get("exec_ms", end - granted)
+                dlock_ms = timing.get("dlock_ms", 0.0)
+                idle = timing.get("idle", (0.0, 0.0, 0.0))
                 with sess.lock:
                     sess.used_ms += elapsed
                     sess.exec_count += 1
                     sess.exec_ms_total += elapsed
                     sess.busy = False
                     sess.last_end_ms = end
+                    ms = sess.phase_ms
+                    ms["idle_attach_ms_total"] += idle[0]
+                    ms["idle_gate_ms_total"] += idle[1]
+                    ms["idle_proxy_ms_total"] += idle[2]
+                sess.arrived_ms = end   # a chain's next burst arrives now
+                if sess.call is not None:
+                    sess.call[1] += gate_ms + dlock_ms + elapsed
             return result
         finally:
             # only reached with busy still set when the token gate itself
             # failed (scheduler closed / renew raised) before dispatch
             if sess.busy:
+                now = _now_ms()
                 with sess.lock:
                     sess.busy = False
-                    sess.last_end_ms = _now_ms()
+                    sess.last_end_ms = now
+                if sess.call is not None:   # a wait, not handler work
+                    sess.call[1] += now - arrived
 
     def _watch_idle(self) -> None:
         """Return tokens from clients that stopped executing (one watchdog
@@ -792,26 +845,53 @@ class ChipProxy:
     def _handle_timed(self, req: dict, state: dict) -> dict:
         op = str(req.get("op"))
         t0 = time.perf_counter()
+        name = state.get("name") or str(req.get("name", ""))
+        # an execute is also the critical-path "execute" segment:
+        # server-side service time under the pod's trace, so topcli
+        # --critpath can split the client's RPC round-trip into transport
+        # vs on-chip work (obs/critpath.py)
+        tid = state.get("trace_id", "") if op == "execute" else ""
         try:
-            return self._handle(req, state)
+            with obs_trace.phase("rpc", name, tid, op=op, proc="chipproxy"):
+                return self._handle(req, state)
         finally:
             # unknown ops share one label — a misbehaving client must not
             # mint unbounded series
             _RPC_LAT.observe(op if op in _KNOWN_OPS else "other",
                              value=time.perf_counter() - t0)
-            if op == "execute":
-                # the critical-path "execute" segment: server-side
-                # service time under the pod's trace, so topcli
-                # --critpath can split the client's RPC round-trip into
-                # transport vs on-chip work (obs/critpath.py)
-                tid = state.get("trace_id", "")
-                if tid:
-                    tracer = obs_trace.get_tracer()
-                    end_ms = tracer.now_ms()
-                    tracer.record(
-                        "execute", tid,
-                        end_ms - (time.perf_counter() - t0) * 1000.0,
-                        end_ms, proc="chipproxy")
+            # an execute that reached _dispatch left its session here
+            sess = state.pop("executing", None)
+            if sess is not None:
+                self._note_replied(sess)
+
+    def _note_replied(self, sess: _Session) -> None:
+        """Phase stamp ``replied``: close the execute call ``_dispatch``
+        opened. The handler's self time is what is left of arrival to
+        reply once the gate wait, the ``_dlock`` wait and the device time
+        are taken out, all read on this thread from one clock."""
+        (arrived, waited), sess.call = sess.call, None
+        handler_ms = _now_ms() - arrived
+        sess.last_handler_ms = handler_ms
+        with sess.lock:
+            sess.phase_ms["self_ms_total"] += handler_ms - waited
+
+    def _note_shim(self, sess: _Session, report) -> None:
+        """What the tenant's side measured between its previous execute
+        send and this one (``protocol.SHIM_KEY``): time in shim and client
+        code, and the previous execute's round trip. Each is a difference
+        taken inside that process; the round trip less this side's handler
+        time for the same call is the wire."""
+        try:
+            shim_ms = float(report.get("shim_ms", 0.0))
+            rtt_ms = report.get("rtt_ms")
+            wire_ms = (float(rtt_ms) - sess.last_handler_ms
+                       if rtt_ms is not None
+                       and sess.last_handler_ms is not None else 0.0)
+        except (AttributeError, TypeError, ValueError):
+            return      # not the shim's report: serve the call as ever
+        with sess.lock:
+            sess.phase_ms["shim_ms_total"] += shim_ms
+            sess.phase_ms["wire_ms_total"] += wire_ms
 
     def _handle(self, req: dict, state: dict) -> dict:
         op = req.get("op")
@@ -1237,12 +1317,19 @@ class ChipProxy:
             return self._compile(sess, state["blob"], req.get("ncarry"))
 
         if op == "execute":
+            # phase stamp ``arrived``; _handle_timed closes the call
+            now = sess.arrived_ms = _now_ms()
+            sess.call = [now, 0.0]
+            state["executing"] = sess
+            if protocol.SHIM_KEY in req:
+                self._note_shim(sess, req[protocol.SHIM_KEY])
             return self._execute(sess, req)
 
         if op == "usage":
             with self._slock:
                 sessions = {s.name: {"exec_ms_total": s.exec_ms_total,
-                                     "exec_count": s.exec_count}
+                                     "exec_count": s.exec_count,
+                                     **s.phase_ms}
                             for s in self._sessions.values()}
             return {"ok": True,
                     "used_ms": self.scheduler.window_usage(sess.name),
@@ -1701,45 +1788,77 @@ class ChipProxy:
         # connection must not drive the device while this runs. Device
         # time is measured AFTER the lock is ours — the wait belongs to
         # whoever held the lock, not to this client's quota.
-        with self._dlock:
+        # Phase stamps device_start / device_end bound ``exec_ms``; with
+        # ``arrived`` and ``granted`` (left in ``timing`` by _gated) they
+        # split the idle gap this program ends.
+        timing = timing if timing is not None else {}
+        who = timing.get("session", "")
+        asked = _now_ms()
+        with obs_trace.phase("dlock_wait", who):
+            self._dlock.acquire()
+        try:
             start = _now_ms()
+            timing["dlock_ms"] = start - asked
+            timing["idle"] = self._split_idle(timing, start)
             try:
-                outs = fn(*args)
-                if not isinstance(outs, (list, tuple)):
-                    outs = [outs]
-                # Completion barrier = a host read of the smallest output
-                # (kept pending S3). Quota accounting needs the program
-                # FINISHED before the clock is read, or a client could
-                # queue bursts past its token. A host read cannot complete
-                # before the program does, and every output comes from the
-                # SAME XLA program, so one read is a barrier for all of
-                # them. On the directly attached v5e block_until_ready is
-                # a barrier too (PERF.md, PR 21) and ~0.3 ms cheaper per
-                # dispatch; S3 decides whether to switch. ``sync_out`` is the pick precomputed at compile
-                # time (_Executable.sync_out) — scanning jax .nbytes
-                # properties per dispatch costs ~25 µs and this runs per op
-                # on the pipelined wire's serial stage.
-                if sync_out is None:
-                    nonempty = [o for o in outs
-                                if getattr(o, "nbytes", 0) > 0]
-                    small = (min(nonempty, key=lambda o: o.nbytes)
-                             if nonempty else None)
-                    big = small is not None and small.nbytes > 65536
-                else:
-                    idx, big = sync_out
-                    small = outs[idx] if 0 <= idx < len(outs) else None
-                if small is None:     # all-empty: block_until_ready only
-                    self._jax.block_until_ready(outs)
-                else:
-                    if big:
-                        # Don't haul a big buffer to host just to sync:
-                        # a 1-element slice is a dependent dispatch that
-                        # completes strictly after the program.
-                        small = small.ravel()[:1]
-                    np.asarray(small)
+                with obs_trace.phase("device", who):
+                    outs = self._run_to_completion(fn, args, sync_out)
             finally:
-                if timing is not None:
-                    timing["exec_ms"] = _now_ms() - start
+                end = self._last_device_end = _now_ms()
+                timing["exec_ms"] = end - start
+        finally:
+            self._dlock.release()
+        return outs
+
+    def _split_idle(self, timing: dict, start: float) -> tuple:
+        """The chip's idle gap ``[last program's end, start]`` in three
+        parts that sum to it, each bound clamped into the gap: up to the
+        burst's arrival nobody had asked (**attach**: the tenant's
+        turn-around and the wire); from there to the grant a session was
+        asking while the token was elsewhere (**gate**, which includes the
+        previous holder's turn-around before its renew); from there to
+        ``start`` the **proxy**'s own handler work and ``_dlock``. Caller
+        holds ``_dlock``. The first program of a chip ends no gap."""
+        last = self._last_device_end
+        if last is None or "arrived" not in timing:
+            return (0.0, 0.0, 0.0)
+        arrived = min(max(timing["arrived"], last), start)
+        granted = min(max(timing["granted"], arrived), start)
+        return (arrived - last, granted - arrived, start - granted)
+
+    def _run_to_completion(self, fn, args: list, sync_out: tuple | None):
+        outs = fn(*args)
+        if not isinstance(outs, (list, tuple)):
+            outs = [outs]
+        # Completion barrier = a host read of the smallest output
+        # (kept pending S3). Quota accounting needs the program
+        # FINISHED before the clock is read, or a client could
+        # queue bursts past its token. A host read cannot complete
+        # before the program does, and every output comes from the
+        # SAME XLA program, so one read is a barrier for all of
+        # them. On the directly attached v5e block_until_ready is
+        # a barrier too (PERF.md, PR 21) and ~0.3 ms cheaper per
+        # dispatch; S3 decides whether to switch. ``sync_out`` is the
+        # pick precomputed at compile time (_Executable.sync_out) —
+        # scanning jax .nbytes properties per dispatch costs ~25 µs and
+        # this runs per op on the pipelined wire's serial stage.
+        if sync_out is None:
+            nonempty = [o for o in outs if getattr(o, "nbytes", 0) > 0]
+            small = (min(nonempty, key=lambda o: o.nbytes)
+                     if nonempty else None)
+            big = small is not None and small.nbytes > 65536
+        else:
+            idx, big = sync_out
+            small = outs[idx] if 0 <= idx < len(outs) else None
+        if small is None:     # all-empty: block_until_ready only
+            self._jax.block_until_ready(outs)
+        else:
+            if big:
+                # Don't haul a big buffer to host just to sync:
+                # a 1-element slice is a dependent dispatch that
+                # completes strictly after the program.
+                small = small.ravel()[:1]
+            np.asarray(small)
         return list(outs)
 
     def _cleanup(self, state: dict) -> None:

@@ -28,7 +28,7 @@ from ..obs import metrics as obs_metrics
 from ..obs import prof as obs_prof
 from ..obs import slo as obs_slo
 from ..obs.flight import default_recorder as flight_default_recorder
-from ..obs.trace import get_tracer
+from ..obs.trace import phase
 from ..utils.logger import get_logger
 from . import protocol
 from .native import load_library
@@ -491,7 +491,7 @@ class TokenScheduler:
                 trace_id: str = "") -> float:
         """Block until *name* is granted the token; returns quota_ms."""
         deadline = None if timeout is None else time.monotonic() + timeout
-        with self._cond:
+        with phase("gate_wait", name, trace_id, chip=self.chip), self._cond:
             self._core.request_token(name)
             self._note_demand(name)
             t0 = time.monotonic()
@@ -516,7 +516,7 @@ class TokenScheduler:
         collapsing shares to round-robin.
         """
         deadline = None if timeout is None else time.monotonic() + timeout
-        with self._cond:
+        with phase("gate_wait", name, trace_id, chip=self.chip), self._cond:
             self._core.release_token(name, used_ms, self._clock())
             self._note_release(name, used_ms)
             self._core.request_token(name)
@@ -756,12 +756,6 @@ class TokenScheduler:
                 self._blame.account_wait(self.chip, namespace, tpu_class,
                                          wait_s, now=now, trace_id=trace_id)
             self._ledger.grant(self.chip, namespace, tpu_class, now=now)
-        if trace_id:
-            tracer = get_tracer()
-            end = tracer.now_ms()
-            tracer.record("token-grant", trace_id,
-                          end - wait_s * 1000.0, end,
-                          client=name, chip=self.chip)
 
     def _note_timeout(self, name: str, wait_s: float, trace_id: str) -> None:
         # caller holds self._cond; the wait ended in TimeoutError — the

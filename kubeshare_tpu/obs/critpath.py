@@ -4,9 +4,10 @@ One request's wall time is spent across at least three processes —
 the front door / client (admission, transport), the scheduler service
 (queue wait, filter/reserve/bind), and the chip proxy (token
 grant-wait, execute). Each process exports spans sharing the pod's
-trace ID (``obs/trace.py``), but each process's tracer has its *own*
-monotonic epoch: timestamps from two sources are not comparable, so
-naive timeline stitching is wrong by whatever the epoch skew is.
+trace ID (``obs/trace.py``) on its node's CLOCK_MONOTONIC: comparable
+between the processes of one node, not between nodes, and one request's
+sources are on several, so naive timeline stitching is wrong by whatever
+the nodes' clocks differ.
 
 This module therefore attributes by *durations*, not absolute
 alignment:

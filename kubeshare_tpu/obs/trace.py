@@ -7,10 +7,11 @@ rides with the pod: minted at ``SchedulerEngine.submit``, carried on
 pod's timeline stitches submit → queue-wait → filter → reserve → bind →
 token-grant across three processes' worth of layers.
 
-Clock discipline: span durations come from ``time.monotonic`` (never
-wall time, never the engine's injectable fake clock), anchored once per
-tracer to an epoch so exported timestamps are stable across export
-calls. Export targets:
+Clock discipline: one clock, ``time.monotonic`` (never wall time, never
+the engine's injectable fake clock). Span times are CLOCK_MONOTONIC
+milliseconds: spans exported by several processes of one node share an
+axis, and so do the ``mono_us`` stat of every ``ks.*`` event that
+:func:`phase` writes into a profiler trace. Export targets:
 
 - ``export_jsonl(path)`` — one JSON object per line, grep-friendly.
 - ``chrome_trace()`` — Chrome trace-event JSON (``ph: "X"`` complete
@@ -20,6 +21,7 @@ calls. Export targets:
 from __future__ import annotations
 
 import json
+import sys
 import threading
 import time
 import uuid
@@ -29,6 +31,11 @@ from typing import Callable, Dict, List, Optional
 
 def new_trace_id() -> str:
     return uuid.uuid4().hex
+
+
+def now_ms() -> float:
+    """CLOCK_MONOTONIC in milliseconds: the one clock of every span."""
+    return time.monotonic() * 1000.0
 
 
 # -- span sinks --------------------------------------------------------------
@@ -126,11 +133,8 @@ class Tracer:
         self._lock = threading.Lock()
         self._spans: List[Span] = []
         self._capacity = capacity
-        # monotonic epoch so span times are comparable within a process
-        self._epoch = time.monotonic()
 
-    def now_ms(self) -> float:
-        return (time.monotonic() - self._epoch) * 1000.0
+    now_ms = staticmethod(now_ms)
 
     # -- recording -----------------------------------------------------------
 
@@ -294,3 +298,56 @@ def get_tracer() -> Tracer:
 
 def tracing_enabled() -> bool:
     return _active is not _NULL
+
+
+# -- phases of the sharing path ----------------------------------------------
+
+#: phases that are also a span of the pod's timeline, under the name
+#: ``critpath.SEGMENT_OF`` knows
+_PHASE_SPAN = {"rpc": "execute", "gate_wait": "token-grant"}
+
+
+class phase:
+    """One boundary of the sharing path (``rpc``, ``gate_wait``,
+    ``dlock_wait``, ``device``), written where the work happens.
+
+    While a profiler session runs, the block is an event ``ks.<name>``
+    on its thread's line of the trace, with the stats ``session`` (whose
+    work it is), ``mono_us`` (CLOCK_MONOTONIC at entry, microseconds:
+    the event's own start places that clock on the trace's axis) and
+    ``attrs``. Only where ``jax`` is already imported: this module never
+    imports it, and with no session running the annotation does nothing.
+
+    With a ``trace_id`` the block is also recorded, on exit, as the span
+    the pod's timeline has always had (``execute``, ``token-grant``).
+    """
+
+    __slots__ = ("_name", "_session", "_trace_id", "_attrs", "_ann", "_t0")
+
+    def __init__(self, name: str, session: str, trace_id: str = "",
+                 **attrs):
+        self._name = name
+        self._session = session
+        self._trace_id = trace_id
+        self._attrs = attrs
+
+    def __enter__(self) -> "phase":
+        self._ann = None
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is not None:
+            self._ann = profiler.TraceAnnotation(
+                "ks." + self._name, session=self._session,
+                mono_us=time.monotonic_ns() // 1000, **self._attrs)
+            self._ann.__enter__()
+        self._t0 = now_ms()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._trace_id and self._name in _PHASE_SPAN:
+            attrs = dict(self._attrs, client=self._session)
+            if exc_type is not None:
+                attrs["error"] = exc_type.__name__
+            get_tracer().record(_PHASE_SPAN[self._name], self._trace_id,
+                                self._t0, now_ms(), **attrs)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)

@@ -207,7 +207,7 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, window=None):
             pltpu.VMEM((bq, 1), jnp.float32),   # running sum
             pltpu.VMEM((bq, d), jnp.float32),   # output accumulator
         ],
-        interpret=interp,
+        interpret=interp, name="flash_fwd",
     ), qr, kr, vr, interpret=interpret)
     return _unfold(out, b, h), lse
 
@@ -337,7 +337,7 @@ def _flash_bwd(q, k, v, o, lse, g, g_lse, causal, block_q, block_k,
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((b * h, s_q, d), q.dtype, vma=vma),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=interp,
+        interpret=interp, name="flash_dq",
     ), qr, kr, vr, dor, lse, dcap, interpret=interpret)
 
     # dK/dV grid: one row per batch·KV-head; k blocks outer; the
@@ -368,7 +368,7 @@ def _flash_bwd(q, k, v, o, lse, g, g_lse, causal, block_q, block_k,
                                         vma=vma)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        interpret=interp,
+        interpret=interp, name="flash_dkv",
     ), qr, kr, vr, dor, lse, dcap, interpret=interpret)
 
     return _unfold(dq, b, h), _unfold(dk, b, hk), _unfold(dv, b, hk)

@@ -98,7 +98,7 @@ def _fused_flat(p, g, m, v, step, lr, b1, b2, eps, interpret):
         out_shape=out_shape,
         # p, m, v update in place: zero extra HBM for the step
         input_output_aliases={1: 0, 3: 1, 4: 2},
-        interpret=interp,
+        interpret=interp, name="fused_adam",
     ), corr, p2, g2, m2, v2, interpret=interpret)
     unpad = lambda x: x.reshape(-1)[:n]
     return unpad(p3), unpad(m3), unpad(v3)

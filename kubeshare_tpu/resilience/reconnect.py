@@ -123,6 +123,11 @@ class ReplayableReply:
     def sink(self):
         return self._rec.sink
 
+    @property
+    def done_at(self) -> float:
+        inner = self._rec.inner
+        return inner.done_at if inner is not None else 0.0
+
     def done(self) -> bool:
         inner = self._rec.inner
         return inner is not None and inner.done()

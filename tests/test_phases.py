@@ -1,0 +1,448 @@
+"""The sharing path measured where the work happens: the proxy's phase
+counters in ``usage`` (idle split, self time, the shim's report), the
+``phase`` helper of ``obs/trace.py`` and its ``ks.*`` events in a
+profiler trace.
+
+The idle split is driven on a patched ``proxy._now_ms``: the test owns
+the clock, so every gap has one right answer.
+"""
+
+import subprocess
+import sys
+import threading
+import time
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+from kubeshare_tpu.isolation import protocol
+from kubeshare_tpu.isolation import proxy as proxy_mod
+from kubeshare_tpu.isolation.client import ProxyClient, ShimClock
+from kubeshare_tpu.isolation.proxy import _PHASE_KEYS, ChipProxy
+from kubeshare_tpu.isolation.tokensched import TokenScheduler
+from kubeshare_tpu.obs import trace as obs_trace
+
+REPO = Path(__file__).resolve().parent.parent
+IDLE_KEYS = ("idle_attach_ms_total", "idle_gate_ms_total",
+             "idle_proxy_ms_total")
+DEVICE_MS = 50.0
+
+
+def wait_for(cond, what, timeout=10.0):
+    deadline = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < deadline, f"timed out waiting for {what}"
+        time.sleep(0.002)
+
+
+class Rig:
+    """A proxy on a clock the test owns. Every program takes DEVICE_MS of
+    it; nothing else moves it but ``at``."""
+
+    def __init__(self, monkeypatch):
+        self.t = 1000.0
+        monkeypatch.setattr(proxy_mod, "_now_ms", lambda: self.t)
+        # no idle release unless a test asks: hand-overs happen by renew
+        self.proxy = ChipProxy(scheduler=TokenScheduler(1000.0, 100.0, 10.0),
+                               idle_release_ms=1e12)
+        run = self.proxy._run_to_completion
+
+        def run_on_clock(fn, args, sync_out):
+            outs = run(fn, args, sync_out)
+            self.t += DEVICE_MS
+            return outs
+
+        self.proxy._run_to_completion = run_on_clock
+        self.proxy.serve()
+        # asks for usage on a connection no tenant's call can block
+        self.clients = {"observer": ProxyClient(
+            "127.0.0.1", self.proxy.port, "observer", 0.01, 0.01)}
+
+    def at(self, t):
+        assert t >= self.t
+        self.t = float(t)
+
+    def tenant(self, name):
+        c = ProxyClient("127.0.0.1", self.proxy.port, name, 0.5, 1.0)
+        x = c.put(np.ones((4, 4), np.float32))
+        exe = c.compile(lambda a: a * 2.0, x)
+        self.clients[name] = c
+        return lambda: exe(x)
+
+    def counters(self, name):
+        return self.clients["observer"].usage()["chip"]["sessions"][name]
+
+    def idle(self, name):
+        return tuple(self.counters(name)[k] for k in IDLE_KEYS)
+
+    def close(self):
+        # the proxy first: a test that failed half-way may have left a
+        # call blocked at the gate, and only closing the scheduler ends it
+        self.proxy.close()
+        for c in self.clients.values():
+            c._conn.close()
+
+
+@pytest.fixture
+def rig(monkeypatch):
+    r = Rig(monkeypatch)
+    yield r
+    r.close()
+
+
+def test_first_program_of_a_chip_counts_no_gap(rig):
+    step = rig.tenant("a")
+    rig.at(5000)                # however long the chip sat before it
+    step()
+    c = rig.counters("a")
+    assert rig.idle("a") == (0.0, 0.0, 0.0)
+    assert c["exec_count"] == 1 and c["exec_ms_total"] == DEVICE_MS
+    assert set(_PHASE_KEYS) <= set(c)
+
+
+def test_same_session_turn_around_is_all_attach(rig):
+    step = rig.tenant("a")
+    step()                      # ends at 1050
+    rig.at(1080)                # the tenant comes back 30 ms later
+    step()
+    assert rig.idle("a") == (30.0, 0.0, 0.0)
+    # it still held the token: no wait at the gate to take out, and on
+    # this clock the handler itself takes no time
+    assert rig.counters("a")["self_ms_total"] == 0.0
+
+
+def test_waiter_granted_after_the_holders_renew_is_gate(rig):
+    step_a, step_b = rig.tenant("a"), rig.tenant("b")
+    step_a()                    # a holds the token; program ends at 1050
+    rig.at(1060)
+    tb = threading.Thread(target=step_b, daemon=True)
+    tb.start()                  # b asks at 1060 and waits: a holds
+    sess_a = rig.proxy._session("a")
+    sess_b = rig.proxy._session("b")
+    wait_for(lambda: sess_b.busy, "b at the gate")
+    wait_for(lambda: "b" in rig.proxy.scheduler.waiting(),
+             "b waiting for the token")
+    sess_a.used_ms = sess_a.quota_ms        # a's quantum is spent
+    rig.at(1090)                # a comes back after 40 ms and must renew
+    ta = threading.Thread(target=step_a, daemon=True)
+    ta.start()
+    tb.join(10.0)
+    assert not tb.is_alive()
+    # the gap [1050, 1090] is b's: nobody asked for 10 ms, then b asked
+    # and the chip sat idle 30 ms until a's renew handed the token over
+    assert rig.idle("b") == (10.0, 30.0, 0.0)
+    # the 30 ms at the gate are a wait, not the handler's own time
+    assert rig.counters("b")["self_ms_total"] == 0.0
+    # b's program ended at 1140; a, blocked in renew since 1090, gets the
+    # token when b's idles out (what the watchdog does, done by hand on
+    # this clock): all of that gap is the gate's
+    with sess_b.lock:
+        sess_b.holding = False
+    rig.at(1160)
+    rig.proxy.scheduler.release("b", sess_b.used_ms)
+    ta.join(10.0)
+    assert not ta.is_alive()
+    assert rig.idle("a") == (0.0, 20.0, 0.0)
+    assert rig.counters("a")["self_ms_total"] == 0.0    # 70 ms in renew
+
+
+def test_dlock_held_elsewhere_is_proxy(rig):
+    step = rig.tenant("a")
+    step()                      # ends at 1050
+    rig.proxy._dlock.acquire()  # as a neighbour's put or compile holds it
+    rig.at(1070)
+    t = threading.Thread(target=step, daemon=True)
+    t.start()
+    wait_for(lambda: rig.proxy._dlock._waiters, "the execute at _dlock")
+    rig.at(1095)
+    rig.proxy._dlock.release()
+    t.join(10.0)
+    assert not t.is_alive()
+    assert rig.idle("a") == (20.0, 0.0, 25.0)
+    # the 25 ms behind _dlock are a wait too
+    assert rig.counters("a")["self_ms_total"] == 0.0
+
+
+def test_the_three_parts_equal_the_gap(rig):
+    """Whatever order the stamps come in, the parts are each >= 0 and sum
+    to the gap (bounds are clamped into it)."""
+    p = rig.proxy
+    p._last_device_end = 100.0
+    for arrived, granted, start in ((130, 130, 131), (40, 120, 125),
+                                    (40, 60, 110), (105, 300, 140),
+                                    (150, 120, 160), (100, 100, 100)):
+        parts = p._split_idle({"arrived": arrived, "granted": granted},
+                              start)
+        assert all(x >= 0.0 for x in parts), parts
+        assert sum(parts) == start - 100.0, (arrived, granted, start)
+    assert p._split_idle({}, 500.0) == (0.0, 0.0, 0.0)
+
+
+# -- real clock ---------------------------------------------------------------
+
+@pytest.fixture
+def proxy():
+    p = ChipProxy(scheduler=TokenScheduler(1000.0, 100.0, 10.0))
+    p.serve()
+    yield p
+    p.close()
+
+
+def connect(proxy, name):
+    return ProxyClient("127.0.0.1", proxy.port, name, 0.5, 1.0)
+
+
+def test_usage_grows_monotonically_and_self_time_is_never_negative(proxy):
+    """Two concurrent sessions, one of them on ``_execute_chain``: every
+    counter of every session only grows, so no execution ever added a
+    negative self time, wait or gap."""
+    stop = threading.Event()
+    errors: list = []
+
+    def plain():
+        try:
+            with connect(proxy, "plain") as c:
+                x = c.put(np.ones((64, 64), np.float32))
+                exe = c.compile(lambda a: a @ a * 0.01, x)
+                while not stop.is_set():
+                    out = exe(x)
+                    c.get(out)
+                    c.free(out)
+        except Exception as exc:
+            errors.append(exc)
+
+    def chained():
+        try:
+            with connect(proxy, "chained") as c:
+                loop = c.compile_loop(lambda s: (s + 1.0, s.sum()),
+                                      np.zeros((32,), np.float32))
+                carry = c.put(np.zeros((32,), np.float32))
+                while not stop.is_set():
+                    carry, _aux = loop.chain(8, carry)
+        except Exception as exc:
+            errors.append(exc)
+
+    threads = [threading.Thread(target=plain),
+               threading.Thread(target=chained)]
+    for t in threads:
+        t.start()
+    seen: dict = {}
+    with connect(proxy, "observer") as obs:
+        t_start = time.monotonic()
+
+        def enough():
+            # both have run a while (chunk compiles are slow) and closed a
+            # call: a chain counts every burst, its self time at the reply
+            return (time.monotonic() - t_start > 1.0 and all(
+                seen.get(n, {}).get("exec_count", 0) > 2
+                and seen[n]["self_ms_total"] > 0.0
+                for n in ("plain", "chained")))
+
+        while (time.monotonic() - t_start < 30.0 and not errors
+               and not enough()):
+            for name, c in obs.usage()["chip"]["sessions"].items():
+                prev = seen.get(name, {})
+                for k in _PHASE_KEYS + ("exec_ms_total", "exec_count"):
+                    assert c[k] >= prev.get(k, 0.0), (name, k, prev, c)
+                seen[name] = c
+            time.sleep(0.005)
+    stop.set()
+    for t in threads:
+        t.join(20.0)
+    assert not errors, errors
+    for name in ("plain", "chained"):
+        c = seen[name]
+        assert c["exec_count"] > 2
+        assert c["self_ms_total"] > 0.0
+        for k in _PHASE_KEYS:
+            assert c[k] >= 0.0, (name, k, c[k])
+    assert seen["observer"]["exec_count"] == 0
+    assert all(seen["observer"][k] == 0.0 for k in _PHASE_KEYS)
+
+
+def test_execute_with_and_without_the_shims_key(proxy):
+    with connect(proxy, "c") as c:
+        x = c.put(np.ones((4, 4), np.float32))
+        exe = c.compile(lambda a: a + 1.0, x)
+        mine = lambda: c.usage()["chip"]["sessions"]["c"]  # noqa: E731
+        bare = {"op": "execute", "name": "c", "exec_id": exe._exec_id,
+                "args": [x.handle]}
+        # an old client's request: served as ever, nothing reported
+        reply, _ = c._conn.call(dict(bare))
+        assert reply["ok"] and len(reply["handles"]) == 1
+        assert mine()["exec_count"] == 1
+        assert mine()["shim_ms_total"] == 0.0
+        assert mine()["wire_ms_total"] == 0.0
+        # not the shim's report: ignored, the call is served
+        reply, _ = c._conn.call(dict(bare, **{protocol.SHIM_KEY: "junk"}))
+        assert reply["ok"] and mine()["shim_ms_total"] == 0.0
+        # the shim's: its own time is added as sent; the round trip less
+        # the handler time of the previous execute is the wire
+        handler_ms = proxy._session("c").last_handler_ms
+        assert handler_ms is not None and handler_ms > 0.0
+        reply, _ = c._conn.call(dict(bare, **{protocol.SHIM_KEY: {
+            "shim_ms": 1.5, "rtt_ms": handler_ms + 0.25}}))
+        assert reply["ok"]
+        assert mine()["shim_ms_total"] == 1.5
+        assert mine()["wire_ms_total"] == pytest.approx(0.25)
+        # the client sends it by itself on every execute: the CPU time
+        # its thread spent in the shim's sections since the execute before
+        with c.shim_clock:
+            burn(0.005)
+            c.get(exe(x))
+        first = mine()
+        assert first["shim_ms_total"] >= 1.5 + 5.0
+        time.sleep(0.06)        # the tenant's own work, and a wait: neither
+        with c.shim_clock:
+            c.get(exe(x))
+        after = mine()
+        assert after["exec_count"] == 5
+        assert 0.0 < after["shim_ms_total"] - first["shim_ms_total"] < 50.0
+        assert first["wire_ms_total"] == pytest.approx(0.25)
+        assert after["wire_ms_total"] > first["wire_ms_total"]
+
+
+def burn(cpu_s):
+    """Spend ``cpu_s`` of this thread's CPU time."""
+    until = time.thread_time() + cpu_s
+    while time.thread_time() < until:
+        pass
+
+
+def test_shim_clock_counts_the_threads_cpu_time_and_no_wait():
+    clock = ShimClock()
+    with clock:
+        burn(0.02)
+        with clock:                         # nested: counted once
+            burn(0.01)
+        time.sleep(0.03)                    # a put's reply: a wait
+        number, t_send, first = clock.send()
+        time.sleep(0.05)                    # the execute round trip
+        clock.replied(number, t_send, t_send + 0.05)
+        burn(0.01)
+    burn(0.03)                              # outside: the tenant's own
+    number2, t_send2, second = clock.send()
+    assert (number, number2) == (1, 2)
+    assert 30.0 <= first["shim_ms"] < 40.0 and "rtt_ms" not in first
+    assert 10.0 <= second["shim_ms"] < 20.0
+    assert second["rtt_ms"] == 50.0
+    # a reply that came in long before the caller asked for it: the
+    # round trip ends where it came in
+    time.sleep(0.02)
+    clock.replied(number2, t_send2, t_send2 + 0.004)
+    assert 3.9 <= clock.send()[2]["rtt_ms"] <= 4.1
+    # only the execute before this one has a round trip to report
+    clock.send()
+    assert "rtt_ms" not in clock.send()[2]
+    # another thread's section is its own
+    t = threading.Thread(target=lambda: (clock.__enter__(), burn(0.01),
+                                         clock.__exit__(None, None, None)))
+    t.start()
+    t.join()
+    assert 10.0 <= clock.send()[2]["shim_ms"] < 20.0
+
+
+# -- the phase helper ---------------------------------------------------------
+
+def test_phase_records_the_old_spans_only_with_a_trace_id():
+    tracer = obs_trace.install_tracer(obs_trace.Tracer())
+    try:
+        with obs_trace.phase("rpc", "ns/pod", "tid-1", op="execute"):
+            pass
+        with obs_trace.phase("gate_wait", "ns/pod", "tid-1", chip="c0"):
+            pass
+        with obs_trace.phase("rpc", "ns/pod", op="execute"):
+            pass
+        with obs_trace.phase("gate_wait", "ns/pod"):
+            pass
+        with obs_trace.phase("device", "ns/pod", "tid-1"):
+            pass                # no span of the timeline has that name
+        spans = tracer.spans()
+    finally:
+        obs_trace.uninstall_tracer()
+    assert [s.name for s in spans] == ["execute", "token-grant"]
+    assert all(s.trace_id == "tid-1" for s in spans)
+    assert spans[0].attrs == {"op": "execute", "client": "ns/pod"}
+    assert spans[1].attrs == {"chip": "c0", "client": "ns/pod"}
+    # one clock: CLOCK_MONOTONIC milliseconds, whichever tracer asks
+    now = time.monotonic() * 1000.0
+    for s in spans:
+        assert now - 5000.0 < s.start_ms <= s.end_ms <= now + 1.0
+    assert abs(obs_trace.Tracer().now_ms() - obs_trace.now_ms()) < 50.0
+
+
+def test_phase_does_not_import_jax():
+    code = (
+        "import sys\n"
+        "from kubeshare_tpu.obs import trace\n"
+        "t = trace.install_tracer(trace.Tracer())\n"
+        "with trace.phase('rpc', 's', 'tid', op='execute'): pass\n"
+        "with trace.phase('device', 's'): pass\n"
+        "assert [s.name for s in t.spans()] == ['execute']\n"
+        "assert 'jax' not in sys.modules, 'phase imported jax'\n"
+        "print('ok')\n")
+    out = subprocess.run([sys.executable, "-c", code], cwd=str(REPO),
+                         capture_output=True, text=True, timeout=60)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "ok"
+
+
+_PROFILED = r'''
+import glob, json, sys, time
+import numpy as np
+import jax
+from jax.profiler import ProfileData
+from kubeshare_tpu.isolation.client import ProxyClient
+from kubeshare_tpu.isolation.proxy import ChipProxy
+from kubeshare_tpu.isolation.tokensched import TokenScheduler
+
+out = sys.argv[1]
+proxy = ChipProxy(scheduler=TokenScheduler(1000.0, 100.0, 10.0))
+proxy.serve()
+with ProxyClient("127.0.0.1", proxy.port, "ns/pod-0", 0.5, 1.0) as c:
+    x = c.put(np.ones((8, 8), np.float32))
+    exe = c.compile(lambda a: a @ a, x)
+    exe(x)
+    lo = time.monotonic_ns() // 1000
+    jax.profiler.start_trace(out)
+    for _ in range(3):
+        proxy._session("ns/pod-0").used_ms = 1e9    # quantum spent: renew
+        exe(x)
+    jax.profiler.stop_trace()
+    hi = time.monotonic_ns() // 1000
+proxy.close()
+path = sorted(glob.glob(out + "/**/*.xplane.pb", recursive=True))[-1]
+found = {}
+for plane in ProfileData.from_file(path).planes:
+    for line in plane.lines:
+        for ev in line.events:
+            if ev.name.startswith("ks."):
+                found.setdefault(ev.name, []).append(
+                    {k: v for k, v in ev.stats})
+print(json.dumps({"lo": lo, "hi": hi, "events": found}))
+'''
+
+
+def test_profiler_trace_holds_ks_events_with_session_and_mono_us(tmp_path):
+    """One CPU-backend profiler run, in a process of its own (the
+    profiler's state is the process's) and under its own time limit."""
+    out = subprocess.run(
+        [sys.executable, "-c", _PROFILED, str(tmp_path / "trace")],
+        cwd=str(REPO), capture_output=True, text=True, timeout=240)
+    assert out.returncode == 0, out.stderr[-3000:]
+    import json
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    events = got["events"]
+    assert {"ks.rpc", "ks.gate_wait", "ks.dlock_wait",
+            "ks.device"} <= set(events), sorted(events)
+    device = events["ks.device"]
+    assert len(device) == 3
+    for stats in device:
+        assert stats["session"] == "ns/pod-0"
+        assert got["lo"] <= int(stats["mono_us"]) <= got["hi"]
+    assert len(events["ks.gate_wait"]) == 3
+    assert all(s["session"] == "ns/pod-0" for s in events["ks.gate_wait"])
+    executes = [s for s in events["ks.rpc"] if s.get("op") == "execute"]
+    assert len(executes) == 3
+    assert all(s["session"] == "ns/pod-0" for s in executes)

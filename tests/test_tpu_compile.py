@@ -99,6 +99,32 @@ def test_fused_adam_compiles_for_v5e(one_chip, n):
     assert _kernel_count(compiled) == 1
 
 
+def _kernel_names(compiled) -> list[str]:
+    """The HLO instruction names of the program's Pallas kernels: what
+    their events on the trace's ``XLA Ops`` line are called."""
+    import re
+    return sorted(m.group(1).rstrip(".0123456789") for m in re.finditer(
+        r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        compiled.as_text()))
+
+
+def test_the_four_kernels_are_told_apart_by_name_in_the_tpu_program(one_chip):
+    b, s, h, hk, d = PLAN.kernel_cases[0]
+    q, kv = one_chip((b, s, h, d)), one_chip((b, s, hk, d))
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile()
+    assert _kernel_names(compiled) == ["flash_dkv", "flash_dq", "flash_fwd"]
+    x = one_chip((PLAN.adam_sizes[0],))
+    compiled = jax.jit(
+        lambda p, g, m, v: fad.adam_update(p, g, m, v, step=3)
+    ).lower(x, x, x, x).compile()
+    assert _kernel_names(compiled) == ["fused_adam"]
+
+
 # -- what a proxy-attached pod ships ---------------------------------------
 # No topology needed: the pod traces on its CPU backend and exports for the
 # proxy's platform (isolation/client.py _trace_and_compile). The program
