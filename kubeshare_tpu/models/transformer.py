@@ -152,9 +152,11 @@ def _loss_for_mesh(mesh):
     ``KUBESHARE_TPU_SP_ATTN``:
 
     - ``ring`` (default) — any head count, O((seq/sp)²) score memory;
-    - ``ring_flash`` — ring with the Pallas flash tile per step:
-      O(128²) live scores regardless of shard length (the long-context
-      default on the chip);
+    - ``ring_flash`` — ring with the Pallas flash tile per step: one
+      (block_q × block_k) score tile alive regardless of shard length,
+      the tile following the shard's shape up to the kernel's target
+      (``ops.flash_attention._blocks``; the long-context default on the
+      chip);
     - ``ulysses`` — all-to-all head/sequence exchange, two collectives
       total, needs heads divisible by sp;
     - ``ulysses_flash`` — ulysses with the flash kernel as the local

@@ -108,7 +108,8 @@ def ring_flash_attention_shard(q: jax.Array, k: jax.Array, v: jax.Array,
     Here each ring step instead calls
     :func:`~kubeshare_tpu.ops.flash_attention.flash_attention_lse`, so
     the largest live score tile is (block_q × block_k) VMEM-resident
-    REGARDLESS of shard length; partial outputs merge exactly via the
+    REGARDLESS of shard length (``None`` = the kernel's own tile rule,
+    from the shard's shape); partial outputs merge exactly via the
     returned logsumexp. Two-level flash: the ring blocks the sequence
     over chips (ICI), the kernel blocks the shard over VMEM.
 
@@ -118,26 +119,20 @@ def ring_flash_attention_shard(q: jax.Array, k: jax.Array, v: jax.Array,
     (skipped) — a 3-way ``lax.switch``, so the kernel never needs
     dynamic position offsets.
     """
-    from ..ops.flash_attention import BLOCK_K, BLOCK_Q, flash_attention_lse
+    from ..ops.flash_attention import flash_attention_lse
 
     sp = lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
-    b, nq, h, d = q.shape
-    # default to the kernel's VMEM tile sizes (clamped to the shard) —
-    # defaulting to nq would re-create the O(shard²) tile this exists
-    # to avoid
-    bq = min(BLOCK_Q, nq) if block_q is None else block_q
-    bk = min(BLOCK_K, nq) if block_k is None else block_k
     perm = [(j, (j + 1) % sp) for j in range(sp)]
 
     def tile_full(kblk, vblk):
         return flash_attention_lse(q, kblk, vblk, causal=False,
-                                   block_q=bq, block_k=bk,
+                                   block_q=block_q, block_k=block_k,
                                    interpret=interpret)
 
     def tile_diag(kblk, vblk):
         return flash_attention_lse(q, kblk, vblk, causal=True,
-                                   block_q=bq, block_k=bk,
+                                   block_q=block_q, block_k=block_k,
                                    interpret=interpret)
 
     def tile_masked(kblk, vblk):

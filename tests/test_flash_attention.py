@@ -1,15 +1,21 @@
 """Pallas flash-attention kernel vs the dense reference (interpreter
 mode on CPU — identical kernel body to the TPU path)."""
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-pytestmark = pytest.mark.slow  # compile-heavy: excluded from the default lane
-
 from kubeshare_tpu.ops.attention import dot_product_attention, mha_apply, mha_init
 from kubeshare_tpu.ops.flash_attention import flash_attention
+
+fa = importlib.import_module("kubeshare_tpu.ops.flash_attention")
+
+# compile-heavy float32 cases: excluded from the default lane; the tile
+# rule's table and the bfloat16 cases below run in it
+slow = pytest.mark.slow
 
 
 def qkv(b=2, s=64, h=2, d=16, seed=0):
@@ -18,6 +24,7 @@ def qkv(b=2, s=64, h=2, d=16, seed=0):
                  for k in keys)
 
 
+@slow
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_matches_dense(causal):
     q, k, v = qkv()
@@ -27,6 +34,7 @@ def test_flash_matches_dense(causal):
                                atol=1e-5, rtol=1e-5)
 
 
+@slow
 def test_flash_multiple_block_shapes():
     q, k, v = qkv(s=64)
     ref = dot_product_attention(q, k, v)
@@ -37,6 +45,7 @@ def test_flash_multiple_block_shapes():
                                    err_msg=f"bq={bq} bk={bk}")
 
 
+@slow
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_gradients_match_dense(causal):
     q, k, v = qkv(s=32)
@@ -55,6 +64,7 @@ def test_flash_gradients_match_dense(causal):
                                    atol=1e-4, rtol=1e-4)
 
 
+@slow
 def test_flash_gradients_asymmetric_blocks():
     """The dQ pass loops k blocks, the dK/dV pass loops q blocks — bq≠bk
     exercises both block indexers against the dense reference."""
@@ -75,6 +85,7 @@ def test_flash_gradients_asymmetric_blocks():
                                        err_msg=f"bq={bq} bk={bk}")
 
 
+@slow
 def test_flash_gradient_dtypes_match_primals():
     """custom_vjp cotangents must come back in the primal dtypes (bf16
     params train without an accidental fp32 upcast in the grads)."""
@@ -84,6 +95,7 @@ def test_flash_gradient_dtypes_match_primals():
     assert all(a.dtype == jnp.bfloat16 for a in g)
 
 
+@slow
 @pytest.mark.parametrize("kv_heads", [1, 2])
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_gqa_matches_dense(causal, kv_heads):
@@ -99,6 +111,7 @@ def test_flash_gqa_matches_dense(causal, kv_heads):
                                atol=1e-5, rtol=1e-5)
 
 
+@slow
 def test_flash_gqa_gradients_match_dense():
     """dK/dV must group-sum the per-q-head partials exactly."""
     q, _, _ = qkv(s=32, h=4)
@@ -120,6 +133,7 @@ def test_flash_gqa_gradients_match_dense():
                                    atol=1e-4, rtol=1e-4)
 
 
+@slow
 def test_flash_gqa_rejects_ragged_heads():
     q, _, _ = qkv(h=4)
     k = v = jax.random.normal(jax.random.PRNGKey(9), (2, 64, 3, 16))
@@ -127,12 +141,14 @@ def test_flash_gqa_rejects_ragged_heads():
         flash_attention(q, k, v, block_q=16, block_k=16)
 
 
+@slow
 def test_flash_rejects_ragged_blocks():
     q, k, v = qkv(s=48)
     with pytest.raises(ValueError, match="divisible"):
         flash_attention(q, k, v, block_q=32, block_k=32)
 
 
+@slow
 def test_gqa_mha_flash_matches_dense_path():
     """A grouped-query MHA block (kv_heads from the weight shape) runs
     both attention bodies on the SAME params — kernel vs reference."""
@@ -148,6 +164,7 @@ def test_gqa_mha_flash_matches_dense_path():
                                atol=1e-4, rtol=1e-4)
 
 
+@slow
 def test_flash_plugs_into_mha():
     params = mha_init(jax.random.PRNGKey(0), dim=32, heads=2)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 32))
@@ -159,6 +176,7 @@ def test_flash_plugs_into_mha():
                                atol=1e-4, rtol=1e-4)
 
 
+@slow
 @pytest.mark.parametrize("window", [1, 5, 16, 40, 64])
 def test_flash_sliding_window_matches_dense(window):
     """Band widths below/at/above the block size, including the full
@@ -170,6 +188,7 @@ def test_flash_sliding_window_matches_dense(window):
                                atol=1e-5, rtol=1e-5)
 
 
+@slow
 def test_flash_sliding_window_gradients_match_dense():
     q, k, v = qkv(s=32)
 
@@ -187,6 +206,7 @@ def test_flash_sliding_window_gradients_match_dense():
                                    atol=1e-4, rtol=1e-4)
 
 
+@slow
 def test_flash_sliding_window_with_gqa():
     q, _, _ = qkv(h=4)
     keys = jax.random.split(jax.random.PRNGKey(11), 2)
@@ -198,6 +218,7 @@ def test_flash_sliding_window_with_gqa():
                                atol=1e-5, rtol=1e-5)
 
 
+@slow
 def test_flash_window_requires_causal():
     q, k, v = qkv()
     with pytest.raises(ValueError, match="causal"):
@@ -205,3 +226,89 @@ def test_flash_window_requires_causal():
                         block_q=16, block_k=16)
     with pytest.raises(ValueError, match=">= 1"):
         flash_attention(q, k, v, window=0, block_q=16, block_k=16)
+
+
+# -- bfloat16 operands, float32 softmax --------------------------------------
+# The kernel hands the MXU its inputs' dtype. Against the dense reference
+# on the SAME bfloat16 values: a float32-operand kernel differs by the
+# bfloat16 rounding of its gradient outputs alone (~2e-3 of the largest
+# value; 2e-7 on the float32 output) and passes these by 10x; a dropped
+# 1/√d, or a dQ / dK scaled twice or not at all, is off by 8x at d=64.
+
+BF16_CASES = {
+    # derived tiles: 128 rows is one tile a head
+    "derived-single-tile": dict(s=128, h=2, hk=2, blocks=None, window=None),
+    "multi-tile-causal": dict(s=64, h=2, hk=2, blocks=(16, 32), window=None),
+    "multi-tile-window": dict(s=64, h=2, hk=2, blocks=(16, 16), window=24),
+    "multi-tile-gqa": dict(s=64, h=4, hk=2, blocks=(32, 16), window=None),
+}
+
+
+def _gap(got, ref):
+    ref = np.asarray(ref, np.float32)
+    return float(np.abs(np.asarray(got, np.float32) - ref).max()
+                 / np.abs(ref).max())
+
+
+@pytest.mark.parametrize("case", BF16_CASES)
+def test_flash_bf16_operands_match_dense_on_the_same_values(case):
+    c = BF16_CASES[case]
+    keys = jax.random.split(jax.random.PRNGKey(3), 4)
+    q = jax.random.normal(keys[0], (1, c["s"], c["h"], 64)).astype(jnp.bfloat16)
+    k, v = (jax.random.normal(kk, (1, c["s"], c["hk"], 64)).astype(jnp.bfloat16)
+            for kk in keys[1:3])
+    w = jax.random.normal(keys[3], q.shape)     # a generic output cotangent
+    blocks = ({} if c["blocks"] is None
+              else dict(block_q=c["blocks"][0], block_k=c["blocks"][1]))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, window=c["window"], **blocks)
+
+    def dense(q, k, v):
+        return dot_product_attention(q, k, v, window=c["window"])
+
+    out, vjp = jax.vjp(flash, q, k, v)
+    ref, ref_vjp = jax.vjp(dense, *(x.astype(jnp.float32) for x in (q, k, v)))
+    assert out.dtype == jnp.float32
+    assert _gap(out, ref) < 1e-2
+    for got, want, name in zip(vjp(w), ref_vjp(w), "qkv"):
+        assert got.dtype == jnp.bfloat16
+        assert _gap(got, want) < 2e-2, f"d{name}"
+
+
+# -- the tile rule -------------------------------------------------------------
+
+#: (sequence, head_dim, operand dtype) -> the tile the rule gives
+TILE_TABLE = [(128, 64, jnp.bfloat16, 128), (256, 64, jnp.bfloat16, 256),
+              (512, 64, jnp.bfloat16, 512), (1024, 64, jnp.bfloat16, 1024),
+              (1024, 128, jnp.bfloat16, 1024), (8192, 64, jnp.bfloat16, 1024),
+              (1024, 64, jnp.float32, 1024), (96, 64, jnp.bfloat16, 96),
+              (3000, 64, jnp.float32, 1000),    # no multiple of 128 divides
+              (2048, 512, jnp.bfloat16, 512),   # the budget halves the target
+              (4096, 4096, jnp.float32, 128)]
+
+
+@pytest.mark.parametrize("s,d,dtype,tile", TILE_TABLE)
+def test_tiles_follow_the_shape(s, d, dtype, tile):
+    itemsize = jnp.dtype(dtype).itemsize
+    bq, bk, limit = fa._blocks(s, s, d, dtype, None, None, True)
+    assert (bq, bk) == (tile, tile)
+    assert s % bq == 0 and bq <= fa.TILE_TARGET
+    # Mosaic's block rule: a whole dimension, or whole sublane tiles of rows
+    assert bq == s or bq % (32 // itemsize) == 0
+    need = fa._tile_vmem_bytes(bq, bk, d, itemsize)
+    assert need <= fa.VMEM_BUDGET
+    # the compiler is asked for more only where its default would not do
+    assert limit == (None if need <= 16 * 2 ** 20 else need)
+    # a caller's blocks win, one at a time too
+    assert fa._blocks(s, s, d, dtype, 8, 4, True)[:2] == (8, 4)
+    # (the other is then derived beside it, and may be larger for it)
+    assert fa._blocks(s, s, d, dtype, 8, None, True)[0] == 8
+    assert fa._blocks(s, s, d, dtype, None, 4, True)[1] == 4
+
+
+def test_tile_rule_rejects_a_sequence_it_cannot_block():
+    with pytest.raises(ValueError, match="divisible"):
+        fa._blocks(3000, 3000, 64, jnp.bfloat16, None, None, True)
+    with pytest.raises(ValueError, match="divisible"):
+        fa._blocks(1024, 1024, 64, jnp.bfloat16, 48, None, True)
