@@ -23,9 +23,11 @@ def _schedule(spec: dict, t: dict) -> list[dict]:
 
 def reference(ref, spec: dict, t: dict) -> dict:
     """In the check's process: reference scores of the sampled requests."""
+    import readlib
     import traffic
 
     cfg, seed, idx = spec["config"], int(spec["seed"]), t["index"]
+    vocab = readlib.sizes(cfg)["vocab"]
     schedule = {r["idx"]: r for r in _schedule(spec, t)}
     finished = {int(r[0]) for r in t["done"]["rows"] if r[3] is not None}
     sample = traffic.sample_requests(seed, idx, list(schedule.values()),
@@ -35,7 +37,7 @@ def reference(ref, spec: dict, t: dict) -> dict:
     for i in sample:
         r = schedule[i]
         toks = traffic.request_tokens(seed, idx, i, r["length"], r["bucket"],
-                                      int(cfg["vocab_size"]))
+                                      vocab)
         scores[str(i)] = ref.score(params, toks, r["length"], cfg)
     return {"scores": scores,
             "tokens": sum(schedule[i]["length"] for i in sample)}
@@ -64,9 +66,11 @@ def control(ref, spec: dict, t: dict, quant: str) -> dict:
     reference, computed in the precision below the configuration's, answers
     the sampled requests in the program's place; and when an answer is
     altered where it is produced (the last real token left out of it)."""
+    import readlib
     import traffic
 
     cfg, seed, idx = spec["config"], int(spec["seed"]), t["index"]
+    vocab = readlib.sizes(cfg)["vocab"]
     schedule = _schedule(spec, t)
     sample = traffic.sample_requests(seed, idx, schedule,
                                      {r["idx"] for r in schedule},
@@ -77,7 +81,7 @@ def control(ref, spec: dict, t: dict, quant: str) -> dict:
     for i in sample:
         r = by_idx[i]
         toks = traffic.request_tokens(seed, idx, i, r["length"], r["bucket"],
-                                      int(cfg["vocab_size"]))
+                                      vocab)
         truth[str(i)] = ref.score(params, toks, r["length"], cfg)
         low.append([i, r["due_s"], r["due_s"],
                     ref.score(params, toks, r["length"], cfg, quant)])

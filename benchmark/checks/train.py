@@ -20,12 +20,13 @@ NEGLIGIBLE_GRADIENT = 1e-3      # of the median leaf's norm
 
 def reference(ref, spec: dict, t: dict) -> dict:
     """In the check's process: the reference's readings for tenant ``t``."""
+    import readlib
     import traffic
 
     cfg, entry, seed = spec["config"], t["entry"], int(spec["seed"])
+    vocab = readlib.sizes(cfg)["vocab"]
     batches = [traffic.token_batch(seed, t["index"], i, int(entry["batch"]),
-                                   int(entry["seq_len"]),
-                                   int(cfg["vocab_size"]))
+                                   int(entry["seq_len"]), vocab)
                for i in range(len(t["warm"]["losses"]))]
     return ref.train_readings(traffic.key_words(seed, t["index"]), cfg,
                               batches, float(entry["lr"]))
@@ -79,12 +80,14 @@ def control(ref, spec: dict, t: dict, quant: str) -> dict:
     reference stands in the program's place (a) computed in the precision
     below the configuration's and (b) with each fault a training cell can
     have planted in it. Sets the upper readings of the limits."""
+    import readlib
     import traffic
 
     cfg, entry, seed = spec["config"], t["entry"], int(spec["seed"])
+    vocab = readlib.sizes(cfg)["vocab"]
     batches = [traffic.token_batch(seed, t["index"], i, int(entry["batch"]),
-                                   int(entry["seq_len"]),
-                                   int(cfg["vocab_size"])) for i in range(3)]
+                                   int(entry["seq_len"]), vocab)
+               for i in range(3)]
     key, lr = traffic.key_words(seed, t["index"]), float(entry["lr"])
     truth = ref.train_readings(key, cfg, batches, lr)
 

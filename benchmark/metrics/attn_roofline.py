@@ -13,20 +13,25 @@ import readlib as R  # noqa: E402
 KIND, LAYER, UNIT, SOURCE, MOVES = "per_layer", "kernels", "%", "device_trace", "train_tokens_per_s"
 
 
-def need(run: dict, a: float, b: float) -> float:
+def need(run: dict, a: float, b: float):
+    """Least seconds for the attention done in ``[a, b]``, by the counts
+    of the configuration's family; ``None`` where it counts none."""
     cfg, peaks, least = run["config"], run["peaks"], 0.0
-    layers = R.flops.dims(cfg)["layers"]
+    layer = R.count(cfg, "attention_layer")
+    count_layers = R.count(cfg, "attention_layers")
+    if layer is None or count_layers is None:
+        return None
+    layers = count_layers(cfg)
     for t in R.by_role(run, "train"):
         e = t["entry"]
-        one = R.flops.least_seconds(R.flops.attention_layer(
+        one = R.flops.least_seconds(layer(
             cfg, int(e["batch"]), int(e["seq_len"]), backward=True), peaks)[0]
         least += layers * one * R.steps_between(t, a, b)
     for t in R.by_role(run, "score"):
         for r in R.requests(run, t):
             if r["done_s"] is not None and a <= r["done_s"] <= b:
                 least += layers * R.flops.least_seconds(
-                    R.flops.attention_layer(cfg, 1, r["bucket"],
-                                            backward=False), peaks)[0]
+                    layer(cfg, 1, r["bucket"], backward=False), peaks)[0]
     return least
 
 
@@ -36,4 +41,5 @@ def read(run: dict):
         "seconds", 0.0)
     if took <= 0:
         return None
-    return 100.0 * need(run, trace["from_s"], trace["to_s"]) / took
+    least = need(run, trace["from_s"], trace["to_s"])
+    return None if least is None else 100.0 * least / took

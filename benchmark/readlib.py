@@ -1,12 +1,17 @@
 """What the metric readers share: differences of the proxy registry's
-counters between the window's two ends, and per-tenant work counts."""
+counters between the window's two ends, per-tenant work counts, and the
+way to the files a configuration names. No jax: the readers run in
+``run.py``'s process."""
 
 from __future__ import annotations
 
+import importlib.util
+import re
 import sys
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
+REPO = HERE.parent
 if str(HERE) not in sys.path:
     sys.path.insert(0, str(HERE))
 
@@ -14,16 +19,43 @@ import flops  # noqa: E402,F401  (readers import it from here)
 import traffic  # noqa: E402
 
 
-def reader(name: str):
-    """Another metric's reader, by its name."""
-    import importlib.util
-
+def _load(path: Path):
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_"),
-        HERE / "metrics" / f"{name}.py")
+        "bench_" + re.sub(r"\W", "_", str(path.relative_to(HERE))), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def reader(name: str):
+    """Another metric's reader, by its name."""
+    return _load(HERE / "metrics" / f"{name}.py")
+
+
+def named(config: dict, key: str):
+    """The module that a configuration's file names under ``key`` by its
+    path from the checkout's root: its ``reference``, its ``binding``
+    (``models/<family>.py``) or its ``counts`` (``counts/<family>.py``)."""
+    if not config.get(key):
+        raise KeyError(f"the configuration names no {key!r}")
+    path = (REPO / config[key]).resolve()
+    if HERE not in path.parents or not path.is_file():
+        raise FileNotFoundError(f"the configuration's {key} "
+                                f"{config[key]!r} is no file of the "
+                                "benchmark")
+    return _load(path)
+
+
+def sizes(config: dict) -> dict:
+    """``{"vocab", "positions"}`` of the configuration as run, by its
+    family's counts."""
+    return named(config, "counts").sizes(config)
+
+
+def count(config: dict, name: str):
+    """One function of the family's counts, or ``None`` where the family
+    offers no such count: its reader then says nothing."""
+    return getattr(named(config, "counts"), name, None)
 
 
 def counted(run: dict) -> tuple[str, float]:

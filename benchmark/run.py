@@ -13,10 +13,12 @@ its own to decide ``correct``.
 
 Driven by data: the cell's entry in ``BENCHMARK.json`` names a
 configuration (its ``file``) and a traffic mix (``mixes/<traffic>.json``);
-the mix names tenant roles (``tenants/<role>.py``, checked by
-``checks/<role>.py``); every metric is a reader ``metrics/<name>.py``;
+the configuration's file names what depends on its model's family (its
+``binding`` to the program's model, its ``counts``, its plain
+``reference``); the mix names tenant roles (``tenants/<role>.py``, checked
+by ``checks/<role>.py``); every metric is a reader ``metrics/<name>.py``;
 the limits of what is compared are ``limits/<workload>.json``. This file
-holds no cell's, configuration's, mix's, role's or metric's name.
+holds no cell's, configuration's, family's, mix's, role's or metric's name.
 """
 
 from __future__ import annotations
@@ -43,6 +45,8 @@ sys.path.insert(0, str(REPO))
 SHIM = REPO / "kubeshare_tpu" / "_shim"
 
 ATTACH_MODES = ("proxy",)       # the mix's schema knows more; see README
+#: what a configuration's file names by path: all that knows its family
+FAMILY_FILES = ("binding", "counts", "reference")
 
 
 @dataclass
@@ -230,6 +234,10 @@ def load_cell(workload: str) -> dict:
     cell = cells[workload]
     configs = {c["name"]: c for c in manifest["configs"]}
     config = json.loads((REPO / configs[cell["config"]]["file"]).read_text())
+    for key in FAMILY_FILES:
+        if not (config.get(key) and (REPO / config[key]).is_file()):
+            raise RunFailed(f"configuration {cell['config']!r}: its "
+                            f"{key!r} names no file ({config.get(key)!r})")
     mix_path = HERE / "mixes" / f"{cell['traffic']}.json"
     if not mix_path.is_file():
         raise RunFailed(f"no mix file {mix_path.relative_to(REPO)}")
@@ -253,6 +261,12 @@ def load_cell(workload: str) -> dict:
             if not (HERE / sub / f"{t['role']}.py").is_file():
                 raise RunFailed(f"tenant {t['name']!r}: unknown role "
                                 f"{t['role']!r} (no {sub}/{t['role']}.py)")
+    if (len(metrics_for(manifest, "end_to_end", workload)) < 2
+            or not metrics_for(manifest, "per_layer", workload)):
+        raise RunFailed(f"cell {workload!r} reports too little: append its "
+                        "name to the `workloads` list of every metric it "
+                        "reports (an end-to-end metric beside setup_s and "
+                        "a per-layer metric at the least; README)")
     return {"manifest": manifest, "cell": cell, "config": config,
             "mix": mix, "limits": limits}
 

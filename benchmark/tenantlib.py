@@ -1,7 +1,8 @@
-"""What the benchmark's tenant programs share: plain JAX on the program's
-own model code. A tenant calls ``jax.jit`` and knows nothing of the proxy;
-whatever its environment attached (the ``_shim`` on PYTHONPATH) decides
-where a jitted call runs.
+"""What the benchmark's tenant programs share: plain JAX, and no model's
+name. The model is the configuration's ``binding`` (``models/<family>.py``),
+its sizes are its ``counts``. A tenant calls ``jax.jit`` and knows nothing
+of the proxy; whatever its environment attached (the ``_shim`` on
+PYTHONPATH) decides where a jitted call runs.
 
 Import this only inside a tenant process (it imports jax).
 """
@@ -15,10 +16,8 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from kubeshare_tpu.models import transformer as T
-from kubeshare_tpu.ops.flash_attention import flash_attention
+import readlib
 
 ADAM_B1 = 0.9
 
@@ -29,27 +28,15 @@ def load_spec(argv) -> dict:
     return json.loads(Path(argv[1]).read_text())
 
 
-def model_dims(config: dict) -> dict:
-    """Sizes from the configuration's file. The head count is a module
-    constant of the program today (PERF.md lists the argument for R1): set
-    it before ``init``."""
-    T.HEADS = int(config["n_head"])
-    return {"seq_len": int(config["n_positions"]),
-            "vocab": int(config["vocab_size"]),
-            "dim": int(config["n_embd"]), "layers": int(config["n_layer"])}
-
-
-def bench_attn(q, k, v):
-    """The program's flash kernels under a stable scope, so the trace
-    reduction finds attention whatever later implements it."""
-    with jax.named_scope("bench_attn"):
-        return flash_attention(q, k, v, causal=True)
-
-
-def init_on_device(dims: dict, key_words: np.ndarray):
-    """Weights made on the device from the seed, in one jitted call."""
-    return jax.jit(lambda key: T.init(key, **dims))(
-        np.asarray(key_words, np.uint32))
+def model(config: dict, longest: int):
+    """``(binding, sizes)`` of the configuration: the module that builds
+    its model on the program's code, and ``{"vocab", "positions"}`` as
+    run. ``longest`` is the longest sequence this tenant will send."""
+    sizes = readlib.sizes(config)
+    if longest > sizes["positions"]:
+        raise SystemExit(f"a sequence of {longest} exceeds the "
+                         f"configuration's {sizes['positions']} positions")
+    return readlib.named(config, "binding"), sizes
 
 
 def leaf_names(tree) -> list[str]:

@@ -1,6 +1,7 @@
 """Role ``score``: an open-loop scoring tenant.
 
-Forward only: one sequence a request, padded to a bucket, answered with
+Forward only, on the logits of the configuration's binding (names no
+model): one sequence a request, padded to a bucket, answered with
 one float (the sequence's mean log-probability under the model: what a
 reranker or a perplexity filter returns). Requests arrive on a schedule
 fixed by the seed; one worker serves them first come, first served, and
@@ -21,39 +22,39 @@ import numpy as np  # noqa: E402
 
 import tenantlib as L  # noqa: E402
 import traffic  # noqa: E402
-from kubeshare_tpu.models import transformer as T  # noqa: E402
 
 
-def score_fn(params, tokens, length):
-    """Mean log-probability of ``tokens[0, 1:length]`` given their
-    prefixes; the padding past ``length`` is masked out (and, attention
-    being causal, never seen by a real position)."""
-    logits = T.apply(params, tokens, attn_fn=L.bench_attn)
-    logp = jax.nn.log_softmax(logits[0, :-1].astype(jnp.float32))
-    got = jnp.take_along_axis(logp, tokens[0, 1:, None], axis=-1)[:, 0]
-    live = jnp.arange(1, tokens.shape[1]) < length
-    return jnp.sum(jnp.where(live, got, 0.0)) / jnp.maximum(
-        jnp.sum(live), 1).astype(jnp.float32)
+def make_score(logits_fn):
+    def score_fn(params, tokens, length):
+        """Mean log-probability of ``tokens[0, 1:length]`` given their
+        prefixes; the padding past ``length`` is masked out (and,
+        attention being causal, never seen by a real position)."""
+        logits = logits_fn(params, tokens)
+        logp = jax.nn.log_softmax(logits[0, :-1].astype(jnp.float32))
+        got = jnp.take_along_axis(logp, tokens[0, 1:, None], axis=-1)[:, 0]
+        live = jnp.arange(1, tokens.shape[1]) < length
+        return jnp.sum(jnp.where(live, got, 0.0)) / jnp.maximum(
+            jnp.sum(live), 1).astype(jnp.float32)
+    return score_fn
 
 
 def main(argv) -> None:
     spec = L.load_spec(argv)
     t_start = time.monotonic()
     tenant, seed, idx = spec["tenant"], int(spec["seed"]), int(spec["index"])
-    dims = L.model_dims(spec["config"])
+    config = spec["config"]
     buckets = [int(b) for b in tenant["buckets"]]
-    if max(buckets) > dims["seq_len"]:
-        raise SystemExit(f"bucket {max(buckets)} exceeds the "
-                         f"configuration's {dims['seq_len']} positions")
+    binding, sizes = L.model(config, max(buckets))
     schedule = traffic.request_schedule(
         seed, idx, tenant["arrivals"], tenant["lengths"], buckets,
         float(spec["seconds"]))
-    params = L.init_on_device(dims, traffic.key_words(seed, idx))
-    score = jax.jit(score_fn)
+    # the weights made on the device from the seed, in one jitted call
+    params = jax.jit(binding.init(config))(traffic.key_words(seed, idx))
+    score = jax.jit(make_score(binding.logits(config)))
 
     def serve(req) -> float:
         toks = traffic.request_tokens(seed, idx, req["idx"], req["length"],
-                                      req["bucket"], dims["vocab"])
+                                      req["bucket"], sizes["vocab"])
         return float(score(params, toks, np.int32(req["length"])))
 
     # warm only the shapes this schedule uses

@@ -1,10 +1,10 @@
 """Role ``train``: a closed-loop training tenant.
 
-Plain JAX: the program's transformer, its flash attention under the scope
-``bench_attn`` and its fused Adam under ``bench_opt``, one ``jax.jit``-ed
-step. Set-up builds ONE step object with its state, drives it through its
-first three steps (the ones the reference follows) and hands the same
-object to the window.
+Plain JAX: the loss of the configuration's binding (its kernels under the
+binding's own scopes) and the program's fused Adam under ``bench_opt``,
+one ``jax.jit``-ed step. Set-up builds ONE step object with its state,
+drives it through its first three steps (the ones the reference follows)
+and hands the same object to the window. Names no model.
 """
 
 from __future__ import annotations
@@ -21,16 +21,14 @@ import optax  # noqa: E402
 
 import tenantlib as L  # noqa: E402
 import traffic  # noqa: E402
-from kubeshare_tpu.models import transformer as T  # noqa: E402
 from kubeshare_tpu.ops.fused_adam import fused_adam  # noqa: E402
 
 CHECK_STEPS = 3
 
 
-def make_step(optimizer):
+def make_step(loss_fn, optimizer):
     def step(params, opt_state, tokens, targets):
-        loss, grads = jax.value_and_grad(T.loss_fn)(
-            params, (tokens, targets), attn_fn=L.bench_attn)
+        loss, grads = jax.value_and_grad(loss_fn)(params, (tokens, targets))
         with jax.named_scope("bench_opt"):
             updates, opt_state = optimizer.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)
@@ -42,21 +40,21 @@ def main(argv) -> None:
     spec = L.load_spec(argv)
     t_start = time.monotonic()
     tenant, seed, idx = spec["tenant"], int(spec["seed"]), int(spec["index"])
-    dims = L.model_dims(spec["config"])
+    config = spec["config"]
     batch, seq = int(tenant["batch"]), int(tenant["seq_len"])
-    if seq > dims["seq_len"]:
-        raise SystemExit(f"seq_len {seq} exceeds the configuration's "
-                         f"{dims['seq_len']} positions")
+    binding, sizes = L.model(config, seq)
+    init = binding.init(config)
     key = traffic.key_words(seed, idx)
     optimizer = fused_adam(float(tenant["lr"]))
 
-    params = L.init_on_device(dims, key)
+    # the weights made on the device from the seed, in one jitted call
+    params = jax.jit(init)(key)
     opt_state = jax.jit(optimizer.init)(params)
-    step = make_step(optimizer)
+    step = make_step(binding.loss(config), optimizer)
     names = L.leaf_names(params)
 
     def feed(i):
-        return traffic.token_batch(seed, idx, i, batch, seq, dims["vocab"])
+        return traffic.token_batch(seed, idx, i, batch, seq, sizes["vocab"])
 
     # the first steps, through the window's own call and feed; what the
     # reference is compared with is read from the state they leave
@@ -71,7 +69,7 @@ def main(argv) -> None:
     # the parameters' change over those steps, against the same init
     # regenerated inside the program (no second copy is kept resident)
     delta = jax.jit(lambda p, k: L.leaf_norms(jax.tree_util.tree_map(
-        lambda a, b: a - b, p, T.init(k, **dims))))(params, key)
+        lambda a, b: a - b, p, init(k))))(params, key)
     delta_norms = np.asarray(delta).tolist()
     L.say("WARM", {"setup_s": time.monotonic() - t_start,
                    "leaves": names, "losses": losses,

@@ -18,6 +18,8 @@ TINY_CONFIG = {
     "n_positions": 128, "n_ctx": 128, "vocab_size": 256,
     "layer_norm_epsilon": 1e-05, "activation_function": "gelu_new",
     "reference": "benchmark/reference/gpt2.py",
+    "binding": "benchmark/models/gpt2.py",
+    "counts": "benchmark/counts/gpt2.py",
     "precision": {"params": "float32", "matmul": "bfloat16",
                   "control": "int8"},
 }
@@ -70,16 +72,21 @@ LIKE = {"tiny-pair": "gpt2s-pair-even",
 
 
 def make_tree(tmp: Path, mixes: dict | None = None,
-              limits: dict | None = None, like: dict | None = None) -> Path:
+              limits: dict | None = None, like: dict | None = None,
+              config: dict | None = None) -> Path:
     """A checkout-shaped tree: a COPY of ``benchmark/`` (tests add files
     to it, never edit one), the program by symlink, and a manifest whose
-    cells are the tiny mixes on the tiny configuration."""
+    cells are the tiny mixes on the tiny configuration ``config``
+    (default: GPT-2's family; its ``parameters_as_run`` given, or
+    counted for that family)."""
     root = tmp / "checkout"
     shutil.copytree(BENCH, root / "benchmark",
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     os.symlink(REPO / "kubeshare_tpu", root / "kubeshare_tpu")
     bench = root / "benchmark"
-    cfg = dict(TINY_CONFIG, parameters_as_run=tiny_params(TINY_CONFIG))
+    cfg = dict(TINY_CONFIG if config is None else config)
+    if "parameters_as_run" not in cfg:
+        cfg["parameters_as_run"] = tiny_params(cfg)
     (bench / "configs" / "tiny.json").write_text(json.dumps(cfg))
     mixes = TINY_MIXES if mixes is None else mixes
     real = json.loads((REPO / "BENCHMARK.json").read_text())
@@ -108,7 +115,30 @@ def make_tree(tmp: Path, mixes: dict | None = None,
     return root
 
 
+def forget_other_trees(root: Path) -> None:
+    """A run is one process on one checkout; the tests drive many trees in
+    one process. Drop the benchmark's own modules that another tree left
+    in ``sys.modules`` (``readlib`` finds a configuration's files from
+    where it was loaded), and look in this tree first."""
+    bench = root / "benchmark"
+    for name in ("readlib", "flops", "traffic", "check"):
+        mod = sys.modules.get(name)
+        if mod is not None and bench not in Path(mod.__file__).parents:
+            del sys.modules[name]
+    sys.path.insert(0, str(bench))
+
+
+def add_to_lists(manifest: dict, cell: str, metrics) -> None:
+    """What a PR that adds a cell does to the manifest's metrics: append
+    the cell's name to the ``workloads`` list of each metric it reports."""
+    for group in ("end_to_end", "per_layer"):
+        for m in manifest[group]:
+            if m["name"] in metrics and "workloads" in m:
+                m["workloads"].append(cell)
+
+
 def load_run(root: Path):
+    forget_other_trees(root)
     path = root / "benchmark" / "run.py"
     spec = importlib.util.spec_from_file_location(
         f"bench_run_{abs(hash(str(root)))}", path)

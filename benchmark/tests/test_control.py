@@ -17,7 +17,7 @@ LIMITS = {k: v["limit"] for k, v in helpers.TINY_LIMITS["numbers"].items()}
 @pytest.fixture(scope="module")
 def readings(tmp_path_factory):
     root = helpers.make_tree(tmp_path_factory.mktemp("control"))
-    sys.path.insert(0, str(root / "benchmark"))
+    helpers.forget_other_trees(root)
     spec = importlib.util.spec_from_file_location(
         "bench_control", root / "benchmark" / "control.py")
     mod = importlib.util.module_from_spec(spec)
