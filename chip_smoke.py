@@ -89,7 +89,9 @@ class Plan:
     kernel_cases: tuple = ((2, 256, 8, 8, 32), (2, 256, 8, 2, 32),
                            (2, 1024, 8, 8, 128), (2, 1024, 8, 2, 128))
     kernel_window: int = 128
-    adam_sizes: tuple = (4096 * 256, 1000)
+    #: leaves of the fused Adam: sizes (1-D) or shapes; the last is ragged
+    #: in rows against the block and in lanes against the tile
+    adam_sizes: tuple = (4096 * 256, 1000, (2100, 1003))
     #: in-kernel matmuls run at the MXU's default precision (bf16 passes)
     #: against a float32 ``highest`` reference: max |err| ≤ tol · max |ref|
     kernel_tol: float = 2e-2
@@ -676,8 +678,9 @@ def _child_kernels(p: dict) -> None:
                 failures.append(f"{tag}: error {rel} > {p['kernel_tol']}")
 
     for n in p["adam_sizes"]:
+        shape = tuple(np.atleast_1d(n))
         rng = np.random.default_rng(0)
-        params = jnp.asarray(rng.normal(size=(n,)).astype(np.float32))
+        params = jnp.asarray(rng.normal(size=shape).astype(np.float32))
         m, v = jnp.zeros_like(params), jnp.zeros_like(params)
         opt = optax.adam(1e-3)
         state, ref = opt.init(params), params
@@ -686,7 +689,7 @@ def _child_kernels(p: dict) -> None:
             params, params, m, v)
         with jax.default_matmul_precision("highest"):
             for t in range(1, 4):
-                grad = jnp.asarray(rng.normal(size=(n,)).astype(np.float32))
+                grad = jnp.asarray(rng.normal(size=shape).astype(np.float32))
                 upd, state = opt.update(grad, state, ref)
                 ref = optax.apply_updates(ref, upd)
                 params, m, v = fad.adam_update(params, grad, m, v, step=t)

@@ -1,8 +1,9 @@
 """Pallas fused-Adam kernel vs the jnp reference and optax.
 
 The kernel runs in interpreter mode on CPU — the same kernel body the
-TPU compiles, so these tests pin the math, the padding/reshape plumbing,
-and the in-place aliasing contract.
+TPU compiles, so these tests pin the math, the block rule (whole rows of
+the leaf as the chip stores it, a ragged edge block left to Pallas) and
+the contract: p, g, m, v in, new p, m, v out.
 """
 
 import numpy as np
@@ -12,25 +13,59 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from kubeshare_tpu.ops import fused_adam as fad
 from kubeshare_tpu.ops.fused_adam import (adam_update,
                                           adam_update_reference,
                                           adam_update_tree)
 
+#: Lanes beyond which one operand's eight rows pass the block's budget:
+#: a leaf wider than this is cut in lanes as well as in rows.
+WIDE = fad.BLOCK_BYTES // (8 * 4) + 1000
 
-@pytest.mark.parametrize("shape", [(1024,), (8, 128), (37,), (3, 5, 7)])
-def test_kernel_matches_reference(shape):
+#: (shape, dtype, grid steps the block rule must give it). The layouts the
+#: benchmark's cells have, at sizes the interpreter can run: the edge
+#: block of every ragged case reads beyond the leaf.
+CASES = [
+    ((1024,), jnp.float32, (1,)),
+    ((8, 128), jnp.float32, (1, 1)),
+    ((37,), jnp.float32, (1,)),
+    ((3, 5, 7), jnp.float32, (1, 1)),
+    ((1003, 256), jnp.float32, (1, 1)),       # ragged rows, aligned lanes
+    ((256, 1003), jnp.float32, (1, 1)),       # kept transposed on the chip
+    ((50257,), jnp.float32, (1,)),            # the vocabulary bias
+    ((2 * fad.BLOCK_BYTES // 4 + 77,), jnp.float32, (3,)),   # ragged 1-D
+    ((20, WIDE), jnp.float32, (3, 2)),        # ragged in both directions
+    ((2100, 256), jnp.float32, (3, 1)),       # ragged last block of rows
+    ((3,), jnp.float32, (1,)),                # smaller than one tile
+    ((2100, 256), jnp.bfloat16, (3, 1)),      # 16 sublanes a tile
+]
+
+
+@pytest.mark.parametrize("shape,dtype,grid", CASES)
+def test_kernel_matches_reference(shape, dtype, grid):
     rng = np.random.default_rng(0)
-    p, g, m, v = (rng.normal(size=shape).astype(np.float32)
+    p, g, m, v = (jnp.asarray(rng.normal(size=shape), dtype)
                   for _ in range(4))
-    v = np.abs(v)
+    v = jnp.abs(v)
     got = adam_update(p, g, m, v, step=3, lr=1e-2)
-    want = adam_update_reference(jnp.asarray(p), jnp.asarray(g),
-                                 jnp.asarray(m), jnp.asarray(v),
-                                 step=3, lr=1e-2)
+    want = adam_update_reference(p, g, m, v, step=3, lr=1e-2)
+    # float32 arithmetic either way; a bfloat16 leaf is rounded once on the
+    # way out, where the jitted kernel's fused multiply-add and the eager
+    # reference's two roundings land an ulp apart in 0.6% of the elements
+    tol = 1e-6 if dtype == jnp.float32 else float(jnp.finfo(dtype).eps)
     for a, b in zip(got, want):
-        assert a.shape == shape
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-6, atol=1e-6)
+        assert a.shape == shape and a.dtype == dtype
+        # the interpreter fills what an edge block reads beyond the leaf
+        # with NaN: one leaking into a stored element shows here
+        assert bool(jnp.isfinite(a).all())
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   rtol=tol, atol=tol)
+    # the case is the layout its comment says: the grid the rule gives
+    view = jax.eval_shape(fad._as_stored,
+                          jax.ShapeDtypeStruct(shape, dtype)).shape
+    block, _ = fad._block(view, dtype)
+    assert tuple(-(-n // b) for n, b in zip(view, block)) == grid
 
 
 def test_matches_optax_over_steps():

@@ -19,6 +19,7 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -101,13 +102,76 @@ def test_flash_dq_and_dkv_compile_for_v5e(one_chip, b, s, h, hk, d, dtype,
     assert _kernel_count(compiled) == 2     # the dQ pass and the dK/dV pass
 
 
-@pytest.mark.parametrize("n", PLAN.adam_sizes)
-def test_fused_adam_compiles_for_v5e(one_chip, n):
-    x = one_chip((n,))
+@pytest.mark.parametrize("shape", PLAN.adam_sizes)
+def test_fused_adam_compiles_for_v5e(one_chip, shape):
+    x = one_chip(tuple(np.atleast_1d(shape)))
     compiled = jax.jit(
         lambda p, g, m, v: fad.adam_update(p, g, m, v, step=3)
     ).lower(x, x, x, x).compile()
     assert _kernel_count(compiled) == 1
+
+
+#: One leaf of every shape the benchmark's two trainers hold (GPT-2 small
+#: and medium as `models/transformer.py` builds them), at full size: the
+#: vocabulary in rows, in lanes (kept transposed on the chip) and 1-D.
+CELL_LEAVES = [(768, 768), (768, 2304), (768, 3072), (3072, 768),
+               (1024, 768), (50257, 768), (768, 50257), (768,), (3072,),
+               (50257,), (1024, 1024), (1024, 3072), (1024, 4096),
+               (4096, 1024), (50257, 1024), (1024, 50257), (1024,), (4096,)]
+
+
+def test_fused_adam_moves_each_leaf_once_in_a_step_that_donates_nothing(
+        one_chip):
+    """Through the optax wrapper, as every tenant runs it: one kernel a
+    leaf, and around it nothing that copies a leaf. A leaf the kernel's
+    view of which is not how the chip stores it shows as a ``copy`` or a
+    ``transpose`` here; an aliased output as a ``copy`` in front of the
+    call. (XLA's own ``copy-start`` of an operand into VMEM ahead of the
+    call is a prefetch, not a second pass over HBM.)"""
+    import re
+
+    import optax
+
+    params = {f"leaf{i:02d}": one_chip(s) for i, s in enumerate(CELL_LEAVES)}
+    optimizer = fad.fused_adam(1e-3)
+    state = jax.tree_util.tree_map(
+        lambda s: one_chip(s.shape, s.dtype),
+        jax.eval_shape(optimizer.init, params))
+
+    def step(params, state, grads):
+        updates, state = optimizer.update(grads, state, params)
+        return optax.apply_updates(params, updates), state
+
+    compiled = jax.jit(step).lower(params, state, params).compile()
+    assert _kernel_names(compiled) == ["fused_adam"] * len(CELL_LEAVES)
+    text = compiled.as_text()
+    moved = [(kind, dims) for dims, kind in re.findall(
+        r" = \w+\[([\d,]+)\]\S* (copy|pad|slice|transpose|reshape)\(",
+        text[text.index("ENTRY"):])
+        if np.prod([int(d) for d in dims.split(",")]) >= 768]
+    # an asynchronous copy whose target is not VMEM (``S(1)``) is a copy
+    # of the leaf in HBM all the same: how XLA copies a large aliased leaf
+    moved += [("copy-start", layout) for layout in re.findall(
+        r" = \(\w+\[[\d,]+\](\S*), [^=]* copy-start\(", text)
+        if "S(1)" not in layout]
+    assert moved == []
+
+
+@pytest.mark.parametrize("shape", CELL_LEAVES)
+def test_fused_adam_block_is_large_and_inside_its_vmem_limit(shape):
+    """The block the rule gives each of the cells' leaves: what the
+    pipeline holds of it (seven operands, double-buffered, padded to
+    tiles) is inside the limit the call declares, the limit inside what
+    the flash kernels allow themselves, and no leaf takes more grid
+    steps than its bytes over half the block's budget."""
+    view = jax.eval_shape(fad._as_stored,
+                          jax.ShapeDtypeStruct(shape, jnp.float32)).shape
+    block, limit = fad._block(view, jnp.float32)
+    padded = (-(-block[0] // 1024) * 1024 if len(block) == 1
+              else -(-block[0] // 8) * 8 * -(-block[1] // 128) * 128)
+    assert 14 * padded * 4 <= limit <= fa.VMEM_BUDGET
+    steps = int(np.prod([-(-n // b) for n, b in zip(view, block)]))
+    assert steps <= 1 + 4 * int(np.prod(shape)) // (fad.BLOCK_BYTES // 2)
 
 
 def _kernel_names(compiled) -> list[str]:
