@@ -3,7 +3,7 @@
 
 PY ?= python
 
-.PHONY: all native test bench bench-proxy bench-recovery bench-health bench-autopilot bench-rightsize bench-elastic bench-slo bench-serving bench-fleet bench-chaos bench-gang bench-contention bench-preempt bench-profile bench-replay bench-shard bench-failover image clean obs-check
+.PHONY: all native test bench-proxy bench-recovery bench-health bench-autopilot bench-rightsize bench-elastic bench-slo bench-serving bench-fleet bench-chaos bench-gang bench-contention bench-preempt bench-profile bench-replay bench-shard bench-failover image clean obs-check
 
 all: native
 
@@ -51,9 +51,6 @@ obs-check:
 		assert d['entries'], 'empty flight dump'; \
 		print('flight dump ok: %d entries' % len(d['entries']))"
 	JAX_PLATFORMS=cpu $(PY) scripts/fleet_smoke.py
-
-bench:
-	$(PY) bench.py
 
 # Transport micro-bench (doc/isolation-wire.md): prints fresh numbers,
 # deltas vs the committed baseline, and refreshes bench_proxy.json.

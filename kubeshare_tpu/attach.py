@@ -45,6 +45,8 @@ import numpy as np
 
 from . import constants as C
 from .utils.logger import get_logger
+from .utils.realjit import keep as _keep_real_jit
+from .utils.realjit import real_jit  # noqa: F401  (attach.real_jit, public)
 
 log = get_logger("attach")
 
@@ -53,10 +55,9 @@ _active: "_AttachState | None" = None
 
 
 class _AttachState:
-    def __init__(self, mode: str, real_jit, shim=None, gate=None,
+    def __init__(self, mode: str, shim=None, gate=None,
                  originals: dict | None = None):
         self.mode = mode
-        self.real_jit = real_jit
         self.shim = shim
         self.gate = gate
         #: other jax attributes replaced at attach time, for detach
@@ -192,8 +193,7 @@ class _RemoteJitFunction:
         self._static_argnums = _as_tuple(jit_kwargs.get("static_argnums"))
         self._static_argnames = _as_tuple(jit_kwargs.get("static_argnames"))
         # donate_argnums is accepted but not forwarded: the proxy frees
-        # dead buffers via RemoteArray GC instead (XLA-level donation is
-        # reserved for the fused-loop path where aliasing is structural).
+        # dead buffers via RemoteArray GC instead (ROADMAP S4).
         self._cache: dict = {}
         self.__wrapped__ = fn
 
@@ -400,10 +400,10 @@ def attach_proxy(host: str, port: int, name: str, request: float,
         except RuntimeError:
             pass
         shim = _ProxyShim(host, port, name, request, limit, memory)
-        real_jit = jax.jit
+        _keep_real_jit(jax.jit)
         jax.jit = shim.jit
         originals = _guard_proxy_surface(jax)
-        _active = _AttachState("proxy", real_jit, shim=shim,
+        _active = _AttachState("proxy", shim=shim,
                                originals=originals)
         # A zero-touch workload never calls detach(); unregister at
         # interpreter exit so the proxy drops the session immediately
@@ -530,13 +530,13 @@ def attach_gate(host: str, port: int, name: str, request: float,
             log.info("HBM cap armed from the allocator's stats: "
                      "tpu_mem=%d bytes", memory)
 
-        real_jit = jax.jit
+        genuine_jit = jax.jit
         in_gated_jit = threading.local()   # the eager meter stands down
 
         def gated_jit(fn=None, **kw):
             if fn is None:
                 return lambda f: gated_jit(f, **kw)
-            jitted = real_jit(fn, **kw)
+            jitted = genuine_jit(fn, **kw)
 
             def run(*args, **kwargs):
                 if (_contains_tracers(args, kwargs)
@@ -558,9 +558,10 @@ def attach_gate(host: str, port: int, name: str, request: float,
             run.__wrapped__ = jitted
             return run
 
+        _keep_real_jit(genuine_jit)
         jax.jit = gated_jit
         originals = _meter_eager_ops(jax, gate, hbm, in_gated_jit)
-        _active = _AttachState("gate", real_jit, gate=gate,
+        _active = _AttachState("gate", gate=gate,
                                originals=originals)
         atexit.register(detach)   # release the token on clean exit
         log.info("attached (gate mode) to %s:%d as %s", host, port, name)
@@ -732,7 +733,8 @@ def detach() -> None:
             return
         import jax
 
-        jax.jit = _active.real_jit
+        jax.jit = real_jit()
+        _keep_real_jit(None)
         for api, fn in _active.originals.items():
             if isinstance(fn, tuple):     # (owner, attr, value) restore
                 owner, attr, value = fn
@@ -750,13 +752,3 @@ def active_mode() -> str:
     return _active.mode if _active is not None else ""
 
 
-def real_jit():
-    """The genuine ``jax.jit`` even while the attach shim has replaced the
-    public attribute — framework internals (client tracing, the proxy's
-    AOT compiles) must never recurse into the shim."""
-    state = _active
-    if state is not None and state.real_jit is not None:
-        return state.real_jit
-    import jax
-
-    return jax.jit

@@ -29,6 +29,7 @@ import numpy as np
 from ..obs import metrics as obs_metrics
 from ..obs import trace as obs_trace
 from ..utils.logger import get_logger
+from ..utils.realjit import real_jit
 from . import protocol
 from .protocol import load_array
 
@@ -39,15 +40,6 @@ _WINDOW_STALLS = obs_metrics.default_registry().counter(
     "Times a windowed put/get stream had to block on its oldest in-flight "
     "chunk before submitting the next (transfer credit exhausted — the "
     "wire or the peer is the bottleneck, not this client).", labels=("op",))
-
-
-def _real_jit():
-    """The genuine ``jax.jit`` even when the transparent-attach shim has
-    replaced the public attribute (attach.py routes workload jits through
-    THIS client — tracing here must not recurse into the shim)."""
-    from ..attach import real_jit
-
-    return real_jit()
 
 
 class ShimClock:
@@ -232,78 +224,6 @@ class RemoteExecutable:
                 raise
             if not donate and uploaded:
                 client.free(*uploaded)
-            out_bufs = [RemoteBuffer(h, tuple(shape), dtype)
-                        for h, (shape, dtype) in zip(handles, self.out_meta)]
-            return jax.tree_util.tree_unflatten(self._out_tree, out_bufs)
-
-        return RemoteFuture(resolve, fut._pending)
-
-
-class RemoteLoop:
-    """A compiled loop program (see :meth:`ProxyClient.compile_loop`).
-
-    ``new_carry, aux = loop(n, carry, *consts)`` runs ``n`` fused
-    iterations on the proxy. The previous carry's device buffers are
-    donated (freed) on success — the carry *threads*; consts persist.
-    """
-
-    def __init__(self, client: "ProxyClient", exec_id: int, in_tree, out_tree,
-                 out_meta: list[tuple[list[int], str]], ncarry: int):
-        self._client = client
-        self._exec_id = exec_id
-        self._in_tree = in_tree
-        self._out_tree = out_tree
-        self.out_meta = out_meta
-        self._ncarry = ncarry
-        #: iterations the proxy actually ran on the last call — it may clamp
-        #: a long burst to keep one dispatch near the scheduling quantum.
-        self.last_n = 0
-        #: the per-burst clamp inside the last chain() call (equals
-        #: last_n for plain calls) — the burst controller's steady state
-        self.last_burst = 0
-
-    def __call__(self, n: int, carry, *consts):
-        return self._dispatch_async(int(n), carry, consts,
-                                    chain=False).result()
-
-    def call_async(self, n: int, carry, *consts) -> "RemoteFuture":
-        """Dispatch a fused burst without waiting: the future resolves to
-        the ``(new_carry, aux)`` tree. ``last_n``/``last_burst`` update
-        when the future RESOLVES (the clamp is in the reply), so read
-        them after ``result()``."""
-        return self._dispatch_async(int(n), carry, consts, chain=False)
-
-    def chain(self, n: int, carry, *consts):
-        """Run toward ``n`` iterations with SERVER-SIDE burst chaining:
-        the proxy re-feeds each token-gated burst's carry into the next,
-        so the per-burst client round trip (the turnaround that idles
-        the chip when the co-tenant is token-blocked) disappears. May
-        stop early (bounded bursts per call) — ``last_n`` reports the
-        steps actually run; call again for the remainder. Fairness is
-        unchanged: every burst passes the token gate individually."""
-        return self._dispatch_async(int(n), carry, consts,
-                                    chain=True).result()
-
-    def _dispatch_async(self, n: int, carry, consts,
-                        chain: bool) -> "RemoteFuture":
-        import jax
-        if n < 1:
-            # Clamping 0 → 1 would silently apply an extra step to the
-            # carry; a true 0-iteration call can't exist (the carry would
-            # have to pass through untouched).
-            raise ValueError(f"loop count must be >= 1, got {n}")
-        leaves = jax.tree_util.tree_leaves((carry, *consts))
-        if not all(isinstance(x, RemoteBuffer) for x in leaves):
-            raise TypeError("RemoteLoop args must be device-resident "
-                            "(put them first)")
-        carry_handles = [b.handle for b in leaves[:self._ncarry]]
-        fut = self._client._execute_n_async(
-            self._exec_id, [b.handle for b in leaves],
-            donate=carry_handles,
-            **({"chain_steps": n} if chain else {"repeat": n}))
-
-        def resolve():
-            handles, self.last_n, self.last_burst = fut.result()
             out_bufs = [RemoteBuffer(h, tuple(shape), dtype)
                         for h, (shape, dtype) in zip(handles, self.out_meta)]
             return jax.tree_util.tree_unflatten(self._out_tree, out_bufs)
@@ -559,10 +479,14 @@ class ProxyClient:
 
     # -- programs ------------------------------------------------------------
 
-    def _trace_and_compile(self, fn, example_args, ncarry: int | None):
-        """Trace ``fn`` abstractly over ``example_args``, export StableHLO
-        for the proxy's platform, compile remotely. Returns
-        ``(exec_id, in_tree, out_tree, out_meta)``."""
+    def compile(self, fn, *example_args) -> RemoteExecutable:
+        """Trace ``fn`` locally (abstract — no local execution), export
+        StableHLO for the proxy's platform, and compile it on the proxy's
+        chip.
+
+        ``example_args`` may contain host arrays, :class:`RemoteBuffer`\\ s,
+        or ``jax.ShapeDtypeStruct``\\ s — only shapes/dtypes matter.
+        """
         import jax
         from jax import export
 
@@ -585,60 +509,17 @@ class ProxyClient:
             out_tree_store.append(out_tree)
             return tuple(out_leaves)
 
+        # the genuine jit: attach.py routes workload jits through THIS
+        # client, so tracing here must not recurse into its shim
         exported = export.export(
-            _real_jit()(flat_fn), platforms=list(self.platforms))(*flat_specs)
-        msg = {"op": "compile", "name": self.name}
-        if ncarry is not None:
-            msg["ncarry"] = ncarry
-        reply, _ = self._conn.call(msg, blob=exported.serialize())
-        return reply["exec_id"], in_tree, out_tree_store[0], reply["out_meta"]
-
-    def compile(self, fn, *example_args) -> RemoteExecutable:
-        """Trace ``fn`` locally (abstract — no local execution), serialize,
-        and compile it on the proxy's chip.
-
-        ``example_args`` may contain host arrays, :class:`RemoteBuffer`\\ s,
-        or ``jax.ShapeDtypeStruct``\\ s — only shapes/dtypes matter.
-        """
-        exec_id, in_tree, out_tree, out_meta = self._trace_and_compile(
-            fn, example_args, None)
-        return RemoteExecutable(self, exec_id, in_tree, out_tree, out_meta)
-
-    def compile_loop(self, fn, carry, *consts) -> "RemoteLoop":
-        """Compile ``fn(carry, *consts) -> (carry, aux)`` as a *loop
-        program*: :class:`RemoteLoop` runs N iterations per dispatch, the
-        proxy fusing them into one XLA execution (``lax.fori_loop``).
-
-        This is the TPU-native hot path for training: per-step round trips
-        (client ⇄ proxy ⇄ chip transport) disappear; one token-gated burst
-        covers N steps, exactly the kernel-burst unit the reference's
-        Gemini meters (``launcher.py:78-80``).
-        """
-        import jax
-
-        carry_leaves, carry_tree = jax.tree_util.tree_flatten(carry)
-        ncarry = len(carry_leaves)
-
-        def checked_fn(c, *cs):
-            new_carry, aux = fn(c, *cs)
-            new_tree = jax.tree_util.tree_structure(new_carry)
-            if new_tree != jax.tree_util.tree_structure(c):
-                raise TypeError(
-                    f"loop fn must preserve carry structure: {new_tree} "
-                    f"!= {jax.tree_util.tree_structure(c)}")
-            return new_carry, aux
-
-        exec_id, in_tree, out_tree, out_meta = self._trace_and_compile(
-            checked_fn, (carry, *consts), ncarry)
-        return RemoteLoop(self, exec_id, in_tree, out_tree, out_meta, ncarry)
-
-    def _execute(self, exec_id: int, handles: list[int],
-                 donate=(), repeat: int = 1) -> list[int]:
-        return self._execute_n(exec_id, handles, donate, repeat)[0]
+            real_jit()(flat_fn), platforms=list(self.platforms))(*flat_specs)
+        reply, _ = self._conn.call({"op": "compile", "name": self.name},
+                                   blob=exported.serialize())
+        return RemoteExecutable(self, reply["exec_id"], in_tree,
+                                out_tree_store[0], reply["out_meta"])
 
     def execute_async(self, exec_id: int, handles: list[int],
-                      donate=(), repeat: int = 1,
-                      defer: bool = False) -> "RemoteFuture":
+                      donate=(), defer: bool = False) -> "RemoteFuture":
         """Submit an execute without waiting for its reply; the future
         resolves to the output handle list. On a pipelined connection
         many dispatches ride the wire concurrently (the proxy still
@@ -648,14 +529,10 @@ class ProxyClient:
         ``defer=True`` corks the request (see ``Connection.submit``):
         back-to-back small dispatches share one wire write. Call
         ``flush()`` before blocking on a deferred future."""
-        # built inline (not via _execute_n_async) so the hot dispatch
-        # path wraps ONE future, not a future-of-a-future
         msg = {"op": "execute", "name": self.name, "exec_id": exec_id,
                "args": handles}
         if donate:
             msg["donate"] = list(donate)
-        if repeat != 1:
-            msg["repeat"] = repeat
         clock = self.shim_clock
         number, t_send, msg[protocol.SHIM_KEY] = clock.send()
         tid = getattr(self._conn, "trace_id", "")
@@ -688,52 +565,6 @@ class ProxyClient:
         """Send any corked (``defer=True``) requests now."""
         if self._conn.pipelined:
             self._conn.flush()
-
-    def _execute_n(self, exec_id: int, handles: list[int],
-                   donate=(), repeat: int = 1,
-                   chain_steps: int = 0) -> tuple[list[int], int, int]:
-        return self._execute_n_async(exec_id, handles, donate, repeat,
-                                     chain_steps).result()
-
-    def _execute_n_async(self, exec_id: int, handles: list[int],
-                         donate=(), repeat: int = 1,
-                         chain_steps: int = 0) -> "RemoteFuture":
-        msg = {"op": "execute", "name": self.name, "exec_id": exec_id,
-               "args": handles, "donate": list(donate)}
-        if chain_steps:
-            msg["chain_steps"] = chain_steps
-        else:
-            msg["repeat"] = repeat
-
-        def unwrap(reply: dict) -> tuple[list[int], int, int]:
-            n = int(reply.get("repeat", repeat))
-            return list(reply["handles"]), n, int(reply.get("burst", n))
-
-        clock = self.shim_clock
-        number, t_send, msg[protocol.SHIM_KEY] = clock.send()
-        tid = getattr(self._conn, "trace_id", "")
-        tracer = obs_trace.get_tracer() if tid else None
-        t0 = tracer.now_ms() if tracer is not None else 0.0
-
-        if self._conn.pipelined:
-            rep = self._conn.submit(msg)
-
-            def resolve():
-                reply = rep.result()[0]
-                clock.replied(number, t_send, rep.done_at)
-                out = unwrap(reply)
-                if tracer is not None:
-                    tracer.record("transport", tid, t0, tracer.now_ms(),
-                                  proc="client", op="execute")
-                return out
-
-            return RemoteFuture(resolve, rep)
-        reply, _ = self._conn.call(msg)   # lockstep: resolved already
-        clock.replied(number, t_send, time.monotonic())
-        if tracer is not None:
-            tracer.record("transport", tid, t0, tracer.now_ms(),
-                          proc="client", op="execute")
-        return RemoteFuture(lambda: unwrap(reply))
 
     def usage(self) -> dict:
         reply, _ = self._conn.call({"op": "usage", "name": self.name})

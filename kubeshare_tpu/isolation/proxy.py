@@ -41,6 +41,7 @@ from ..obs.flight import default_recorder as flight_default_recorder
 from ..resilience import faults as _faults
 from ..resilience.journal import SessionJournal
 from ..utils.logger import get_logger
+from ..utils.realjit import real_jit
 from ..preempt.slicer import BoundarySlicer
 from . import protocol
 from .protocol import load_array
@@ -115,22 +116,10 @@ class _Program:
     """Per-PROGRAM state, shared across sessions by blob hash.
 
     Identical clients (the common co-location case: N replicas of one
-    training script) export byte-identical StableHLO; compiling and
-    cost-profiling per session would pay every multi-second XLA compile
-    N times, for each client and each burst bucket.
+    training script) export byte-identical StableHLO; compiling per
+    session would pay every multi-second XLA compile N times.
     """
-    # AOT-compiled single call + fused loops, one per STATIC power-of-two
-    # trip count (lazy; at most log2(max burst) entries). Static: a dynamic
-    # trip count measured far slower per dispatch where this was tuned
-    # (stale constant, S4 re-measures on the directly attached chip).
-    single: object = None
-    chunks: dict = field(default_factory=dict)
-    # Burst cost model: burst_ms ≈ step_ms + (n-1) * loop_step_ms. The two
-    # are tracked separately because the FIRST call carries the fixed
-    # dispatch+completion latency of one program while in-loop iterations
-    # only pay device time.
-    step_ms: float = 0.0          # EMA of single-call time (incl. fixed lat.)
-    loop_step_ms: float = 0.0     # EMA of per-iteration time INSIDE the loop
+    single: object = None         # the AOT-compiled program (lazy)
 
 
 @dataclass
@@ -140,8 +129,7 @@ class _Executable:
     in_specs: list                # ShapeDtypeStruct per arg
     out_nbytes: int               # total output allocation, pre-checked
     out_meta: list[tuple[list[int], str]]  # (shape, dtype) per output
-    prog: _Program                # compiled artifacts + cost, sha-shared
-    ncarry: int | None = None     # loop programs: first ncarry args/outs thread
+    prog: _Program                # the compiled program, sha-shared
     # Hot-path precomputations (the execute handler runs per dispatched op
     # and is the serial stage of the pipelined transport — jax Array
     # .nbytes/.dtype property chains cost tens of µs per op if consulted
@@ -177,9 +165,8 @@ class _Session:
     phase_ms: dict = field(
         default_factory=lambda: dict.fromkeys(_PHASE_KEYS, 0.0))
     # Phase stamps of the execute call in flight, all from _now_ms() on
-    # the connection's one worker thread: when the burst at the gate
-    # arrived (the request reaching _dispatch; in a chain, the previous
-    # burst's end), and for the whole call [arrival, ms spent waiting at
+    # the connection's one worker thread: when the request reached
+    # _dispatch, and for the whole call [arrival, ms spent waiting at
     # the gate, for _dlock, or running on the device].
     arrived_ms: float = 0.0
     call: list | None = None
@@ -201,9 +188,6 @@ class _Session:
     #: workload class (sharedtpu/class) propagated at register — tags the
     #: token scheduler's per-tenant grant-wait series
     tpu_class: str = "best-effort"
-    #: program-boundary yields this session performed after its hold was
-    #: marked preempted (surfaced in chain replies when negotiated)
-    preempt_yields: int = 0
     # -- resilience state (resumable sessions only) ---------------------
     #: features negotiated at register; frozen for the session's lifetime
     features: frozenset = frozenset()
@@ -229,7 +213,7 @@ class _Session:
     #: their HBM reservation released; a replayed chunk referencing one
     #: gets a typed refusal telling the client to restart the upload
     aborted_staging: set = field(default_factory=set)
-    #: exec_id -> (serialized exported program, ncarry): retained for
+    #: exec_id -> serialized exported program: retained for
     #: journal/export so a restarted or destination proxy can recompile
     program_blobs: dict = field(default_factory=dict)
     #: import staging sid -> destination handle (migration transfers)
@@ -238,11 +222,6 @@ class _Session:
     def fresh_id(self) -> int:
         self.next_id += 1
         return self.next_id
-
-
-def _bucket(n: int) -> int:
-    """Largest power of two ≤ n — the static trip counts we compile for."""
-    return 1 << (max(1, int(n)).bit_length() - 1)
 
 
 class _FifoLock:
@@ -287,14 +266,16 @@ class HBMError(RuntimeError):
     pass
 
 
-class _ExecutionError(Exception):
-    """Wraps an exception raised by the device execution itself — as
-    opposed to token-gate failures (scheduler closed / client removed),
-    which happen before any buffer could have been donated."""
-
-    def __init__(self, cause: BaseException):
-        super().__init__(str(cause))
-        self.cause = cause
+def _refuse_loop_keys(req: dict, *keys: str) -> None:
+    """Input from outside the program: an older client's fused-loop keys
+    name an execution path this proxy does not have. Answered with a clean
+    error that names the key, before anything is charged or dispatched."""
+    for key in keys:
+        # "repeat": 1 is what such a client sent with every plain call
+        if key in req and (key, req[key]) != ("repeat", 1):
+            raise ValueError(
+                f"{key!r} is not supported: a program runs once per "
+                f"execute (the fused-loop path is gone)")
 
 
 class ChipProxy:
@@ -327,7 +308,7 @@ class ChipProxy:
                                               ledger=default_ledger(),
                                               blame=default_blame()))
         # program-boundary slicing (preempt/slicer.py): between token-
-        # gated bursts the proxy asks whether its hold was preempted and
+        # gated executes the proxy asks whether its hold was preempted and
         # yields via renew — never mid-execute (the slicer refuses while
         # an execute is in flight and its stats prove it)
         self.slicer = BoundarySlicer(self.scheduler)
@@ -354,7 +335,7 @@ class ChipProxy:
         #: start of the idle gap the next program ends. None until the
         #: first program, whose gap is nobody's
         self._last_device_end: float | None = None
-        # blob-sha → _Program: compiled artifacts + burst cost model shared
+        # blob-sha → _Program: compiled artifacts shared
         # across sessions (guarded by _slock for lookup; compiles race-safe
         # under _dlock). LRU-capped: a client churning unique programs must
         # not grow the proxy without bound — evicted programs just
@@ -569,8 +550,7 @@ class ChipProxy:
             "buffers": [{"handle": int(h), "shape": list(b.shape),
                          "dtype": str(b.dtype), "nbytes": int(b.nbytes)}
                         for h, b in sess.buffers.items()],
-            "programs": [{"exec_id": int(i), "ncarry": nc}
-                         for i, (_blob, nc) in sess.program_blobs.items()],
+            "programs": [{"exec_id": int(i)} for i in sess.program_blobs],
             "staging": sorted(int(s) for s in sess.staging),
             "aborted": sorted(int(s) for s in sess.aborted_staging),
             "replies": [[int(r), rep] for r, rep in sess.replies.items()],
@@ -639,8 +619,10 @@ class ChipProxy:
             sess.hbm_used += int(dev.nbytes)
         for spec in m.get("programs", ()):
             blob = self.journal.load_program(token, int(spec["exec_id"]))
-            self._install_program(sess, blob, spec.get("ncarry"),
-                                  exec_id=int(spec["exec_id"]))
+            # an older proxy's manifest may record "ncarry" (a fused-loop
+            # program): run once it IS one step, so it restores as a plain
+            # program and the field is not read
+            self._install_program(sess, blob, exec_id=int(spec["exec_id"]))
         with self._slock:
             self._sessions[name] = sess
             self._by_token[token] = sess
@@ -684,8 +666,7 @@ class ChipProxy:
         higher-class beneficiary and then straight back.
 
         Phase accounting (``_PHASE_KEYS``) happens here and in
-        :meth:`_run_fn`, so single executes and every burst of a chain
-        share it: the burst ``arrived`` at ``sess.arrived_ms``, was
+        :meth:`_run_fn`: the request ``arrived`` at ``sess.arrived_ms``, was
         ``granted`` when the scheduler returned (its ``ks.gate_wait``
         event is written inside ``TokenScheduler.acquire``/``renew``),
         and ``_run_fn`` leaves the ``_dlock`` wait and the idle gap's
@@ -707,8 +688,6 @@ class ChipProxy:
             elif exhausted or preempted:
                 if preempted:
                     self.slicer.note_yield(sess.name)
-                    with sess.lock:
-                        sess.preempt_yields += 1
                 quota = self.scheduler.renew(sess.name, used,
                                              trace_id=sess.trace_id)
             else:
@@ -750,7 +729,6 @@ class ChipProxy:
                     ms["idle_attach_ms_total"] += idle[0]
                     ms["idle_gate_ms_total"] += idle[1]
                     ms["idle_proxy_ms_total"] += idle[2]
-                sess.arrived_ms = end   # a chain's next burst arrives now
                 if sess.call is not None:
                     sess.call[1] += gate_ms + dlock_ms + elapsed
             return result
@@ -1131,9 +1109,8 @@ class ChipProxy:
             return {"ok": True, "total": total}
 
         if op == "export_program":
-            blob, ncarry = sess.program_blobs[int(req["exec_id"])]
-            state["reply_blob"] = [blob]
-            return {"ok": True, "ncarry": ncarry}
+            state["reply_blob"] = [sess.program_blobs[int(req["exec_id"])]]
+            return {"ok": True}
 
         if op == "import_buffer_begin":
             total = int(req["nbytes"])
@@ -1176,8 +1153,8 @@ class ChipProxy:
             return {"ok": True}
 
         if op == "import_program":
-            ncarry = req.get("ncarry")
-            self._install_program(sess, state["blob"], ncarry,
+            _refuse_loop_keys(req, "ncarry")
+            self._install_program(sess, state["blob"],
                                   exec_id=int(req["exec_id"]))
             self._journal_checkpoint(sess)
             return {"ok": True}
@@ -1314,7 +1291,11 @@ class ChipProxy:
             return {"ok": True}
 
         if op == "compile":
-            return self._compile(sess, state["blob"], req.get("ncarry"))
+            _refuse_loop_keys(req, "ncarry")
+            exec_id, out_meta, out_nbytes = self._install_program(
+                sess, state["blob"])
+            return {"ok": True, "exec_id": exec_id,
+                    "out_meta": out_meta, "out_nbytes": out_nbytes}
 
         if op == "execute":
             # phase stamp ``arrived``; _handle_timed closes the call
@@ -1374,15 +1355,7 @@ class ChipProxy:
         return {"ok": True, "handle": handle,
                 "shape": list(buf.shape), "dtype": str(buf.dtype)}
 
-    def _compile(self, sess: _Session, blob: bytes,
-                 ncarry: int | None = None) -> dict:
-        exec_id, out_meta, out_nbytes = self._install_program(
-            sess, blob, ncarry)
-        return {"ok": True, "exec_id": exec_id,
-                "out_meta": out_meta, "out_nbytes": out_nbytes}
-
     def _install_program(self, sess: _Session, blob: bytes,
-                         ncarry: int | None = None,
                          exec_id: int | None = None):
         """Deserialize + register an exported program. Shared by compile
         (fresh exec_id), migration import and journal recovery (caller
@@ -1398,19 +1371,16 @@ class ChipProxy:
         in_specs = [self._jax.ShapeDtypeStruct(a.shape, a.dtype)
                     for a in exported.in_avals]
         # Program identity = the STRIPPED StableHLO text: the serialized
-        # blob embeds source locations (the client's compile_loop call
+        # blob embeds source locations (the client's compile call
         # site!), so hashing it raw would defeat sharing between identical
         # clients started from different scripts/lines. Alias'd locs are
         # `loc(#locN)` refs plus `#locN = loc(...)` definition lines — both
-        # carry no program semantics. ncarry is part of the identity: the
-        # chunk program's donation and carry threading differ per ncarry
-        # even for an identical module.
+        # carry no program semantics.
         import re
         text = exported.mlir_module()
         text = re.sub(r"^#loc.*$", "", text, flags=re.MULTILINE)
         text = re.sub(r"loc\(#loc\d*\)", "", text)
-        sha = hashlib.sha256(
-            text.encode() + f"|{ncarry}".encode()).hexdigest()
+        sha = hashlib.sha256(text.encode()).hexdigest()
         with self._slock:
             prog = self._programs.pop(sha, None) or _Program()
             self._programs[sha] = prog      # (re-)insert at MRU position
@@ -1429,16 +1399,14 @@ class ChipProxy:
             exec_id = sess.fresh_id()
         sess.executables[exec_id] = _Executable(
             exec_id, exported.call, in_specs, out_nbytes, out_meta,
-            prog=prog, ncarry=None if ncarry is None else int(ncarry),
-            in_meta=in_meta, sync_out=sync_out)
-        sess.program_blobs[exec_id] = (
-            bytes(blob), None if ncarry is None else int(ncarry))
+            prog=prog, in_meta=in_meta, sync_out=sync_out)
+        sess.program_blobs[exec_id] = bytes(blob)
         if sess.resume_token:
             self.journal.save_program(sess.resume_token, exec_id, blob)
         return exec_id, out_meta, out_nbytes
 
     def _single_fn(self, exe: _Executable):
-        """AOT-compile the single-call program (lazily, OUTSIDE the token
+        """AOT-compile the program (lazily, OUTSIDE the token
         gate — a multi-second XLA compile charged as device usage would
         lock the client out for windows and starve everyone else of the
         token meanwhile).
@@ -1449,8 +1417,6 @@ class ChipProxy:
         step.
         """
         if exe.prog.single is None:
-            from ..attach import real_jit
-
             call = exe.call
 
             def _single(*args):
@@ -1462,97 +1428,12 @@ class ChipProxy:
                                        .lower(*exe.in_specs).compile())
         return exe.prog.single
 
-    def _chunk_fn(self, exe: _Executable, n: int):
-        """``n`` executions fused into ONE XLA program via ``lax.fori_loop``
-        with a *static* trip count — the TPU-native answer to per-step
-        dispatch overhead. The first ``ncarry`` outputs feed back into the
-        first ``ncarry`` args each iteration (train-step carry); the rest
-        are loop-invariant. One dispatch, one token-gated burst, buffers
-        stay device-resident throughout.
-
-        The trip count is baked in (``n`` must be a bucket from
-        :func:`_bucket`); whether a dynamic-n program costs more per
-        dispatch on the directly attached chip is S4's to measure.
-        Lazy-compiled per bucket, at
-        most log2(burst cap) compiles per program — and the trace cost is
-        n-independent (the loop is not unrolled).
-        """
-        fn = exe.prog.chunks.get(n)
-        if fn is None:
-            from ..attach import real_jit
-
-            jax = self._jax
-            call, ncarry = exe.call, exe.ncarry
-
-            def chunk(*args):
-                carry, consts = args[:ncarry], args[ncarry:]
-                outs = call(*carry, *consts)
-
-                def body(_, c):
-                    cur_carry, _aux = c
-                    o = call(*cur_carry, *consts)
-                    return tuple(o[:ncarry]), tuple(o[ncarry:])
-
-                init = (tuple(outs[:ncarry]), tuple(outs[ncarry:]))
-                final_carry, aux = jax.lax.fori_loop(0, n - 1, body, init)
-                return list(final_carry + aux)
-
-            # The protocol always donates the carry (RemoteLoop frees those
-            # handles on success), so give XLA the aliasing: without it a
-            # training client needs 2x its state in HBM at every dispatch.
-            with self._dlock:
-                fn = exe.prog.chunks.get(n)  # racing session lost; reuse
-                if fn is None:
-                    fn = (real_jit()(chunk,
-                                     donate_argnums=tuple(range(ncarry)))
-                          .lower(*exe.in_specs).compile())
-                    exe.prog.chunks[n] = fn
-        return fn
-
-    def _cap_repeat(self, exe: _Executable, repeat: int) -> int:
-        """Clamp a client-requested burst length. The fused loop is one
-        unpreemptible XLA execution, so an unbounded ``repeat`` would let a
-        client monopolize the chip past its quota AND slip usage out of the
-        sliding window. Before any timing exists the burst must be bounded
-        by *wall time*, and the only way to bound an unknown step is to run
-        exactly one: a steps-count cap (e.g. 128) at 200 ms/step would be a
-        25 s unpreemptible burst, 80× the base quota, blowing the client's
-        whole limit window.
-
-        Sizing after that balances two costs. Fairness wants bursts near
-        the base quantum (Gemini's burst ≙ quota relationship); throughput
-        wants each burst to amortize the FIXED per-dispatch latency of a
-        program (measured, not assumed: step_ms − loop_step_ms). So the
-        budget is the larger of 2·base and 32·fixed-latency (≤3%
-        overhead), bounded by a quarter of the accounting window so
-        shares still converge within a window. The 32× factor was tuned
-        where a dispatch cost tens of ms; kept pending S4's measurement
-        on the directly attached chip.
-
-        The second dispatch sizes itself PESSIMISTICALLY (marginal cost
-        assumed = full single-call cost) instead of a hardcoded 2-step
-        probe: no XLA compile is wasted on a probe-sized bucket.
-        """
-        cost = exe.prog
-        if cost.step_ms <= 0.0:
-            return 1
-        core = getattr(self.scheduler, "core", None)
-        base = getattr(core, "base_quota_ms", 300.0)
-        window = getattr(self.scheduler, "window_ms", 10_000.0)
-        if cost.loop_step_ms <= 0.0:
-            n = int(min(2.0 * base, window / 4.0) / cost.step_ms)
-            return max(1, min(repeat, n))
-        fixed = max(cost.step_ms - cost.loop_step_ms, 0.0)
-        budget = min(max(2.0 * base, 32.0 * fixed), window / 4.0)
-        n = 1 + int(max(0.0, budget - cost.step_ms) / cost.loop_step_ms)
-        return max(1, min(repeat, n))
-
     def _execute(self, sess: _Session, req: dict) -> dict:
+        _refuse_loop_keys(req, "repeat", "chain_steps")
         exe = sess.executables[int(req["exec_id"])]
         args = [sess.buffers[int(h)] for h in req["args"]]
         # Validate args BEFORE dispatch: a shape/dtype mismatch must be a
-        # clean client error, not a device failure that (for loop
-        # programs) would be treated as having consumed the donated carry.
+        # clean client error, not a device failure.
         if len(args) != len(exe.in_specs):
             raise ValueError(f"expected {len(exe.in_specs)} args, "
                              f"got {len(args)}")
@@ -1564,222 +1445,33 @@ class ChipProxy:
                     f"arg {i}: got {tuple(buf.shape)}/{buf.dtype}, program "
                     f"expects {shape}/{dtype}")
         donate = [int(h) for h in req.get("donate", [])]
-        chain_steps = int(req.get("chain_steps", 0))
-        if chain_steps:
-            if exe.ncarry is None:
-                raise ValueError("chain_steps requires a loop program "
-                                 "(ProxyClient.compile_loop)")
-            return self._execute_chain(sess, exe, req, chain_steps)
-        repeat = int(req.get("repeat", 1))
-        if repeat < 1:
-            raise ValueError(f"repeat must be >= 1, got {repeat}")
-        if repeat > 1 and exe.ncarry is None:
-            raise ValueError("repeat requires a loop program (compile with "
-                             "ncarry / ProxyClient.compile_loop)")
-        if exe.ncarry is not None:
-            # All loop-program dispatches ride a chunk executable (its
-            # fori_loop is a no-op at n=1) — a 1-step tail must not pay a
-            # second full XLA compile via the single path. The quota cap is
-            # then rounded DOWN to a power of two so the static-trip-count
-            # programs stay few (the client learns the clamp via
-            # reply["repeat"] and simply asks again for the remainder).
-            repeat = _bucket(self._cap_repeat(exe, repeat))
-            fn = self._chunk_fn(exe, repeat)
-        else:
-            fn = self._single_fn(exe)
+        fn = self._single_fn(exe)
         # Cap check up front — allocation must not happen over-cap even
         # transiently (donated buffers are freed only after success).
         self._charge(sess, exe.out_nbytes)
-        exec_ms_before = sess.exec_ms_total
         timing: dict = {}
-
-        def run_tagged():
-            try:
-                return self._run_fn(fn, args, timing, exe.sync_out)
-            except Exception as e:
-                raise _ExecutionError(e) from e
-
         try:
-            outs = self._gated(sess, run_tagged, timing)
-        except _ExecutionError as tagged:
-            err = tagged.cause
-            sess.hbm_used -= exe.out_nbytes
-            if exe.ncarry is not None:
-                # The chunk executable donates the carry at the XLA level,
-                # so a failed loop execution may already have invalidated
-                # those buffers. Drop the handles (and their HBM charge) and
-                # say so — dangling handles would surface as confusing
-                # errors on the next dispatch instead.
-                consumed = [int(h) for h in req["args"][:exe.ncarry]]
-                for handle in consumed:
-                    self._forget_buffer(sess, handle)
-                raise RuntimeError(
-                    f"loop execution failed and its donated carry was "
-                    f"consumed (handles {consumed} freed); re-put the "
-                    f"carry before retrying: {err}") from err
-            raise err
+            outs = self._gated(
+                sess, lambda: self._run_fn(fn, args, timing, exe.sync_out),
+                timing)
         except Exception:
-            # Token-gate failure (scheduler closed / client removed while
-            # waiting): nothing was dispatched, every buffer is intact.
+            # A token-gate failure (scheduler closed / client removed while
+            # waiting) dispatched nothing, and the compiled program aliases
+            # no argument, so a device failure consumed none either: every
+            # buffer is intact and only the output charge goes back.
             sess.hbm_used -= exe.out_nbytes
             raise
-        # Update the burst cost model from the *gated* execution time only
-        # (sess.exec_ms_total delta; the session is connection-serialized).
-        # Timing around _gated() would fold the token wait into the
-        # estimate, and under contention _cap_repeat would then clamp
-        # bursts far below the intended 2x base-quantum of device time.
-        self._update_cost_model(exe, repeat,
-                                sess.exec_ms_total - exec_ms_before)
-        handles = []
-        for out in outs:
-            handle = sess.fresh_id()
-            sess.buffers[handle] = out
-            handles.append(handle)
-            self._journal_buffer(sess, handle, out)
-        for handle in donate:
-            self._forget_buffer(sess, handle)
-        rep = {"ok": True, "handles": handles}
-        if repeat != 1 or int(req.get("repeat", 1)) != 1:
-            # only loop dispatches consume the echoed clamp; plain executes
-            # skip the key to keep the hot-path reply frame minimal
-            rep["repeat"] = repeat
-        return rep
-
-    def _update_cost_model(self, exe: _Executable, repeat: int,
-                           burst_ms: float) -> None:
-        cost = exe.prog
-        with self._slock:  # cost model + counter shared across connections
-            if repeat == 1:
-                cost.step_ms = (burst_ms if cost.step_ms <= 0.0
-                                else 0.5 * cost.step_ms + 0.5 * burst_ms)
-            else:
-                first = (cost.step_ms if cost.step_ms > 0.0
-                         else burst_ms / repeat)
-                per_loop = max(0.001, (burst_ms - first) / (repeat - 1))
-                cost.loop_step_ms = (
-                    per_loop if cost.loop_step_ms <= 0.0
-                    else 0.5 * cost.loop_step_ms + 0.5 * per_loop)
+        with self._slock:   # counter shared across connections
             self.total_execs += 1
-
-    #: bursts per chained call: bounds one reply's latency (and one
-    #: connection's server-thread occupancy) while still amortizing the
-    #: client round-trip across many token-gated bursts
-    MAX_CHAIN_BURSTS = 32
-
-    def _execute_chain(self, sess: _Session, exe: _Executable,
-                       req: dict, total: int) -> dict:
-        """Server-side burst chaining: run the loop program toward
-        ``total`` steps as a SEQUENCE of token-gated bursts, re-feeding
-        each burst's carry outputs into the next — zero client round
-        trips between bursts (the turnaround that idles the chip when
-        the co-tenant is token-blocked).
-
-        Fairness is untouched: every burst passes the token gate
-        individually (acquire/renew per quota exactly like single
-        dispatches), so co-tenants interleave at quantum granularity.
-        The chain stops early at MAX_CHAIN_BURSTS — the reply reports
-        the steps actually run and the client simply asks again.
-
-        Failure semantics match the single-burst loop path: once the
-        first burst dispatched, the client's donated carry is consumed —
-        a mid-chain failure frees the handles and says so.
-        """
-        if total < 1:
-            raise ValueError(f"chain_steps must be >= 1, got {total}")
-        ncarry = exe.ncarry
-        args = [sess.buffers[int(h)] for h in req["args"]]
-        consts = args[ncarry:]
-        carry = list(args[:ncarry])
-        donate = [int(h) for h in req.get("donate", [])]
-        yields_before = sess.preempt_yields
-        steps = 0
-        bursts = 0
-        last_burst = 0
-        outs: list = []
-        while steps < total and bursts < self.MAX_CHAIN_BURSTS:
-            repeat = _bucket(self._cap_repeat(exe, total - steps))
-            fn = self._chunk_fn(exe, repeat)
-            try:
-                self._charge(sess, exe.out_nbytes)
-            except HBMError:
-                if bursts == 0:
-                    raise      # nothing dispatched, buffers intact
-                break          # return the valid partial chain instead
-            exec_ms_before = sess.exec_ms_total
-            timing: dict = {}
-
-            def run_tagged():
-                try:
-                    return self._run_fn(fn, carry + consts, timing,
-                                        exe.sync_out)
-                except Exception as e:
-                    raise _ExecutionError(e) from e
-
-            try:
-                new_outs = self._gated(sess, run_tagged, timing)
-            except _ExecutionError as tagged:
-                err = tagged.cause
-                sess.hbm_used -= exe.out_nbytes
-                self._chain_abort(sess, exe, donate, bursts)
-                raise RuntimeError(
-                    f"chained loop failed after {steps} steps and the "
-                    f"donated carry was consumed (handles {donate} "
-                    f"freed); re-put the carry before retrying: "
-                    f"{err}") from err
-            except Exception:
-                # token-gate failure: THIS burst never dispatched
-                sess.hbm_used -= exe.out_nbytes
-                if bursts == 0:
-                    raise          # nothing consumed, buffers intact
-                self._chain_abort(sess, exe, donate, bursts)
-                raise RuntimeError(
-                    f"chained loop interrupted after {steps} steps and "
-                    f"the donated carry was consumed (handles {donate} "
-                    f"freed); re-put the carry before retrying")
-            self._update_cost_model(exe, repeat,
-                                    sess.exec_ms_total - exec_ms_before)
-            if bursts == 0:
-                # the client's carry handles were donated into burst 0
-                for handle in donate:
-                    self._forget_buffer(sess, handle)
-            else:
-                # the previous burst's outputs (carry consumed by
-                # donation, intermediate aux dropped) release their charge
-                sess.hbm_used -= exe.out_nbytes
-            outs = new_outs
-            carry = list(outs[:ncarry])
-            steps += repeat
-            # the steady-state clamp is the LARGEST burst in the chain —
-            # the final burst is often just the remainder tail
-            last_burst = max(last_burst, repeat)
-            bursts += 1
         handles = []
         for out in outs:
             handle = sess.fresh_id()
             sess.buffers[handle] = out
             handles.append(handle)
             self._journal_buffer(sess, handle, out)
-        # repeat = total steps run; burst = the per-burst clamp the
-        # token-gated cost model converged on (the quantity
-        # steady_state_burst reports)
-        rep = {"ok": True, "handles": handles, "repeat": steps,
-               "burst": last_burst}
-        sliced = sess.preempt_yields - yields_before
-        if sliced > 0 and "preempt" in sess.features:
-            # negotiated-only key: an un-negotiated peer's reply frame
-            # stays byte-for-byte even when its hold was sliced
-            rep["sliced"] = sliced
-        return rep
-
-    def _chain_abort(self, sess: _Session, exe: _Executable,
-                     donate: list[int], bursts: int) -> None:
-        """Mid-chain failure bookkeeping: drop the client's consumed
-        carry handles (burst 0 donated them) and the previous burst's
-        floating output charge."""
         for handle in donate:
             self._forget_buffer(sess, handle)
-        if bursts > 0:
-            sess.hbm_used -= exe.out_nbytes
+        return {"ok": True, "handles": handles}
 
     def _run_fn(self, fn, args: list, timing: dict | None = None,
                 sync_out: tuple | None = None):
@@ -1813,7 +1505,7 @@ class ChipProxy:
     def _split_idle(self, timing: dict, start: float) -> tuple:
         """The chip's idle gap ``[last program's end, start]`` in three
         parts that sum to it, each bound clamped into the gap: up to the
-        burst's arrival nobody had asked (**attach**: the tenant's
+        request's arrival nobody had asked (**attach**: the tenant's
         turn-around and the wire); from there to the grant a session was
         asking while the token was elsewhere (**gate**, which includes the
         previous holder's turn-around before its renew); from there to

@@ -5,12 +5,11 @@ The isolation proxy already brackets every execute with
 ``execute_begin``/``execute_end`` (the ledger's ``granted-active``
 hooks). :class:`BoundarySlicer` rides those brackets to guarantee the
 safety property the bench asserts: ``should_yield`` answers True only
-when the session is *not* inside an execute, so a multi-step hold (the
-proxy's execute chain runs up to 32 bursts under one token) slices at
-program boundaries. The yield itself is the proxy's existing ``renew``
-— an atomic release + re-request that keeps stride shares intact —
-so the wire stays byte-for-byte for peers that never negotiated the
-``preempt`` feature.
+when the session is *not* inside an execute, so a multi-step hold (a
+token spans several executes) slices at program boundaries. The yield
+itself is the proxy's existing ``renew`` — an atomic release +
+re-request that keeps stride shares intact — so the wire stays
+byte-for-byte for peers that never negotiated the ``preempt`` feature.
 
 ``stats()["mid_execute_yields"]`` counts yields recorded while an
 execute was in flight. It is zero by construction; the preempt bench

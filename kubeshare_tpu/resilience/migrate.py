@@ -81,13 +81,10 @@ def migrate_session(source_addr: tuple, dest_addr: tuple, token: str, *,
                          trace_id, span)
         for spec in manifest.get("programs", ()):
             exec_id = int(spec["exec_id"])
-            prep, blob = src.call({"op": "export_program", "token": token,
-                                   "exec_id": exec_id})
-            msg = {"op": "import_program", "token": token,
-                   "exec_id": exec_id}
-            if prep.get("ncarry") is not None:
-                msg["ncarry"] = int(prep["ncarry"])
-            dst.call(msg, blob=bytes(blob))
+            _, blob = src.call({"op": "export_program", "token": token,
+                                "exec_id": exec_id})
+            dst.call({"op": "import_program", "token": token,
+                      "exec_id": exec_id}, blob=bytes(blob))
         # the point of no return: source state drops, tombstone goes up
         src.call({"op": "migrate_finish", "token": token,
                   "moved": [dest_addr[0], int(dest_addr[1])]})

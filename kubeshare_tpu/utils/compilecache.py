@@ -1,7 +1,7 @@
 """One persistent XLA compile cache for every process that compiles.
 
 The chip proxy (it compiles every tenant's program), the model CLIs, the
-gang runner, ``bench.py`` and ``chip_smoke.py``'s children all call
+gang runner and ``chip_smoke.py``'s children all call
 :func:`enable_compile_cache` before their first compile. The cache's path
 is part of its key, so it must never move: where
 ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and nothing is

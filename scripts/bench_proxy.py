@@ -7,15 +7,12 @@ work, so it IS meaningful on the CPU backend (on a directly attached
 chip it is the whole cost added to each dispatch):
 
 - ``execute_rtt_ms``: round-trip of a trivial compiled program through
-  register→execute→reply, p50/p99 — the per-dispatch floor the fused
-  loop amortizes away.
+  register→execute→reply, p50/p99 — the per-dispatch floor.
 - ``put/get_gbps``: host↔proxy buffer bandwidth over the framed socket
   (64 MiB array, chunked path — windowed streaming when negotiated).
-- ``fused_loop_per_step_us``: marginal cost per fused training step at
-  a 64-step burst — what co-located clients actually pay per step.
 - ``async_dispatch_ops_per_sec``: small-op throughput with a window of
   ``execute_async`` futures in flight — the pipelined transport's
-  multiplexing win over the lockstep ``single_dispatch`` rate.
+  multiplexing win over the lockstep rate (1 / ``execute_rtt_ms``).
 
 Run: ``python scripts/bench_proxy.py`` → one JSON object
 (committed as ``bench_proxy.json``). ``--baseline FILE`` also prints
@@ -36,8 +33,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 #: keys worth a delta line (the rest of the JSON is descriptive)
 _METRICS = ("execute_rtt_ms_p50", "execute_rtt_ms_p99", "put_gbps",
-            "get_gbps", "fused_loop_per_step_us", "single_dispatch_ms_p50",
-            "async_dispatch_ops_per_sec")
+            "get_gbps", "async_dispatch_ops_per_sec")
 #: metrics where larger is better (the rest are latencies)
 _HIGHER_IS_BETTER = ("put_gbps", "get_gbps", "async_dispatch_ops_per_sec")
 
@@ -72,7 +68,6 @@ def run_bench(in_process: bool = False) -> dict:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    import jax.numpy as jnp
     import numpy as np
 
     from kubeshare_tpu.isolation.client import ProxyClient
@@ -166,39 +161,6 @@ def run_bench(in_process: bool = False) -> dict:
             gbits = big.nbytes / 1e9 * 8    # decimal Gbit (NIC convention)
             out["put_gbps"] = round(gbits / statistics.median(puts), 2)
             out["get_gbps"] = round(gbits / statistics.median(gets), 2)
-
-            # --- fused-loop marginal per-step cost ----------------------
-            def step(carry, k):
-                w, s = carry
-                w = w - 0.01 * (w @ k)
-                return (w, s + jnp.sum(w)), jnp.float32(0)
-
-            w = np.eye(64, dtype=np.float32)
-            carry = (c.put(w), c.put(np.float32(0)))
-            kbuf = c.put(np.eye(64, dtype=np.float32))
-            loop = c.compile_loop(step, carry, kbuf)
-            for _ in range(4):
-                # warm: the first call is clamped to 1 step (cost model
-                # unseeded), later calls bucket to 64 — only the n=1 and
-                # n=64 programs compile, which are exactly the two timed
-                carry, aux = loop(64, carry, kbuf)
-                c.free(aux)
-            n1, n64 = [], []
-            for _ in range(40):
-                t0 = time.perf_counter()
-                carry, aux = loop(1, carry, kbuf)
-                n1.append(time.perf_counter() - t0)
-                c.free(aux)
-                t0 = time.perf_counter()
-                carry, aux = loop(64, carry, kbuf)
-                assert loop.last_n == 64, loop.last_n
-                n64.append(time.perf_counter() - t0)
-                c.free(aux)
-            per_step_us = (statistics.median(n64) - statistics.median(n1)) \
-                / 63 * 1e6
-            out["fused_loop_per_step_us"] = round(per_step_us, 1)
-            out["single_dispatch_ms_p50"] = round(
-                statistics.median(n1) * 1e3, 3)
     finally:
         proxy.close()
     return out

@@ -66,6 +66,62 @@ def test_attach_proxy_routes_unmodified_jit(proxy, monkeypatch):
     assert jax.jit is real_jit  # detach restored the real jit
 
 
+def test_isolation_takes_the_real_jit_and_never_loads_attach(proxy):
+    """``isolation/`` lies below ``attach.py``. With the shim attached in
+    the proxy's own process, a program compiled and executed through the
+    proxy never enters the shim's ``jit`` (the client's tracing and the
+    proxy's AOT compile read the real one from ``utils/realjit.py``); a
+    proxy with no shim never loads ``kubeshare_tpu.attach`` at all."""
+    import jax
+
+    from kubeshare_tpu import attach
+    from kubeshare_tpu.isolation.client import ProxyClient
+
+    genuine = jax.jit
+    attach.attach_proxy("127.0.0.1", proxy.port, "workload", 0.5, 1.0)
+    shim_jit = jax.jit
+    entered = []
+
+    def counting(*args, **kw):
+        entered.append(args)
+        return shim_jit(*args, **kw)
+
+    jax.jit = counting
+    try:
+        assert attach.real_jit() is genuine
+        with ProxyClient("127.0.0.1", proxy.port, "direct", 0.5, 1.0) as c:
+            x = c.put(np.ones(4, np.float32))
+            out = c.compile(lambda a: a + 1.0, x)(x)
+            np.testing.assert_array_equal(c.get(out),
+                                          np.full(4, 2.0, np.float32))
+        assert not entered
+        assert proxy.total_execs == 1
+    finally:
+        attach.detach()
+    assert jax.jit is genuine and attach.real_jit() is genuine
+
+    code = """
+import sys
+import numpy as np
+from kubeshare_tpu.isolation.client import ProxyClient
+from kubeshare_tpu.isolation.proxy import ChipProxy
+from kubeshare_tpu.isolation.tokensched import TokenScheduler
+p = ChipProxy(scheduler=TokenScheduler(500, 30, 5))
+p.serve()
+with ProxyClient("127.0.0.1", p.port, "c", 0.5, 1.0) as c:
+    x = c.put(np.ones(4, np.float32))
+    out = c.compile(lambda a: a + 1.0, x)(x)
+    assert c.get(out).tolist() == [2.0] * 4
+p.close()
+assert "kubeshare_tpu.attach" not in sys.modules, "isolation/ loaded attach"
+"""
+    proc = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        env=dict(os.environ, PYTHONPATH=str(REPO), JAX_PLATFORMS="cpu"),
+        timeout=300, cwd=str(REPO))
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
 def test_attach_gate_meters_jit_calls(monkeypatch):
     import jax
 

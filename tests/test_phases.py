@@ -147,6 +147,32 @@ def test_waiter_granted_after_the_holders_renew_is_gate(rig):
     assert rig.counters("a")["self_ms_total"] == 0.0    # 70 ms in renew
 
 
+def test_a_wait_for_a_contended_token_is_never_charged(rig):
+    """What a tenant is charged (``exec_ms_total``, and ``used_ms``, what
+    its next renew reports to the gate) grows by device time only, however
+    long its execute waited for a token the neighbour held."""
+    step_a, step_b = rig.tenant("a"), rig.tenant("b")
+    step_a()                    # a holds the token; program ends at 1050
+    tb = threading.Thread(target=step_b, daemon=True)
+    tb.start()
+    wait_for(lambda: "b" in rig.proxy.scheduler.waiting(),
+             "b waiting for the token")
+    rig.at(1450)                # b has waited 400 ms, eight programs long
+    sess_a, sess_b = rig.proxy._session("a"), rig.proxy._session("b")
+    with sess_a.lock:           # a's token idles out (the watchdog's act)
+        sess_a.holding = False
+    rig.proxy.scheduler.release("a", sess_a.used_ms)
+    tb.join(10.0)
+    assert not tb.is_alive()
+    b = rig.counters("b")
+    assert b["exec_count"] == 1 and b["exec_ms_total"] == DEVICE_MS
+    assert sess_b.used_ms == DEVICE_MS
+    assert rig.clients["b"].usage()["used_ms"] == 0.0   # nothing released yet
+    # the wait is on the books as the chip's idle time at the gate
+    assert rig.idle("b") == (0.0, 400.0, 0.0)
+    assert b["self_ms_total"] == 0.0
+
+
 def test_dlock_held_elsewhere_is_proxy(rig):
     step = rig.tenant("a")
     step()                      # ends at 1050
@@ -194,9 +220,9 @@ def connect(proxy, name):
 
 
 def test_usage_grows_monotonically_and_self_time_is_never_negative(proxy):
-    """Two concurrent sessions, one of them on ``_execute_chain``: every
-    counter of every session only grows, so no execution ever added a
-    negative self time, wait or gap."""
+    """Two concurrent sessions, one fetching every result and one
+    threading its state: every counter of every session only grows, so no
+    execution ever added a negative self time, wait or gap."""
     stop = threading.Event()
     errors: list = []
 
@@ -212,19 +238,18 @@ def test_usage_grows_monotonically_and_self_time_is_never_negative(proxy):
         except Exception as exc:
             errors.append(exc)
 
-    def chained():
+    def stepper():
         try:
-            with connect(proxy, "chained") as c:
-                loop = c.compile_loop(lambda s: (s + 1.0, s.sum()),
-                                      np.zeros((32,), np.float32))
+            with connect(proxy, "stepper") as c:
                 carry = c.put(np.zeros((32,), np.float32))
+                exe = c.compile(lambda s: s + 1.0, carry)
                 while not stop.is_set():
-                    carry, _aux = loop.chain(8, carry)
+                    carry = exe(carry, donate=True)
         except Exception as exc:
             errors.append(exc)
 
     threads = [threading.Thread(target=plain),
-               threading.Thread(target=chained)]
+               threading.Thread(target=stepper)]
     for t in threads:
         t.start()
     seen: dict = {}
@@ -232,12 +257,11 @@ def test_usage_grows_monotonically_and_self_time_is_never_negative(proxy):
         t_start = time.monotonic()
 
         def enough():
-            # both have run a while (chunk compiles are slow) and closed a
-            # call: a chain counts every burst, its self time at the reply
+            # both have run a while and closed a call
             return (time.monotonic() - t_start > 1.0 and all(
                 seen.get(n, {}).get("exec_count", 0) > 2
                 and seen[n]["self_ms_total"] > 0.0
-                for n in ("plain", "chained")))
+                for n in ("plain", "stepper")))
 
         while (time.monotonic() - t_start < 30.0 and not errors
                and not enough()):
@@ -251,7 +275,7 @@ def test_usage_grows_monotonically_and_self_time_is_never_negative(proxy):
     for t in threads:
         t.join(20.0)
     assert not errors, errors
-    for name in ("plain", "chained"):
+    for name in ("plain", "stepper"):
         c = seen[name]
         assert c["exec_count"] > 2
         assert c["self_ms_total"] > 0.0

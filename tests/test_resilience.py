@@ -435,3 +435,62 @@ def test_latency_class_survives_live_migration():
     finally:
         p1.close()
         p2.close()
+
+
+@pytest.mark.parametrize("how", ["journal", "migrate"])
+def test_older_proxys_ncarry_restores_as_a_plain_program(tmp_path, how):
+    """A journal or a migration written by a proxy that still had the
+    fused-loop path records ``ncarry`` beside a program. Run once such a
+    program IS one step, so it restores as a plain one: recovery never
+    fails on the old field, and the next execute runs one step."""
+    import json
+
+    p1 = make_proxy(journal_dir=str(tmp_path) if how == "journal" else None)
+    p2 = None
+    try:
+        c = connect(p1, "looper")
+        w, x = c.put(np.zeros(4, np.float32)), c.put(np.ones(4, np.float32))
+        exe = c.compile(lambda w, x: (w + x, w.sum()), w, x)
+        token = c._conn.token
+        if how == "journal":
+            p1.crash()
+            path = tmp_path / f"{token}.json"
+            manifest = json.loads(path.read_text())
+            assert manifest["programs"]
+            for spec in manifest["programs"]:
+                spec["ncarry"] = 1          # as the older proxy wrote it
+            path.write_text(json.dumps(manifest))
+            p2 = make_proxy(journal_dir=str(tmp_path))
+            c.set_endpoint("127.0.0.1", p2.port)
+        else:
+            # the source answers as the older proxy did: ncarry in the
+            # manifest's programs and in export_program's reply
+            admin = p1._handle_admin
+
+            def older(op, req, state):
+                reply = admin(op, req, state)
+                if op == "export_session":
+                    for spec in reply["manifest"]["programs"]:
+                        spec["ncarry"] = 1
+                elif op == "export_program":
+                    reply["ncarry"] = 1
+                return reply
+
+            p1._handle_admin = older
+            p2 = make_proxy()
+            res = migrate_session(("127.0.0.1", p1.port),
+                                  ("127.0.0.1", p2.port), token)
+            assert res["programs"] == [{"exec_id": exe._exec_id,
+                                        "ncarry": 1}]
+        w2, aux = exe(w, x)                 # one execute = one step
+        np.testing.assert_array_equal(c.get(w2), np.ones(4, np.float32))
+        assert float(c.get(aux)) == 0.0
+        assert p2._session("looper").exec_count == 1
+        # what this proxy writes and exports records no such field
+        assert p2._manifest(p2._session("looper"))["programs"] == [
+            {"exec_id": exe._exec_id}]
+        c.close()
+    finally:
+        p1.close()
+        if p2 is not None:
+            p2.close()
