@@ -129,9 +129,9 @@ class RemoteArray:
 
     def __del__(self):
         # No I/O here: __del__ can fire on any thread mid-protocol-call.
-        # Queue the handle; the shim flushes before its next operation.
+        # Queue the handle: it rides on the client's next request.
         try:
-            self._shim.queue_free(self.buf)
+            self._shim.client.free_later(self.buf)
         except Exception:
             pass
 
@@ -145,27 +145,9 @@ class _ProxyShim:
 
         self.client = ProxyClient(host, port, name, request, limit,
                                   memory=memory)
-        self._pending_free: list = []
-        self._lock = threading.Lock()
-
-    # -- deferred frees ----------------------------------------------------
-
-    def queue_free(self, buf) -> None:
-        with self._lock:
-            self._pending_free.append(buf)
-
-    def _flush_frees(self) -> None:
-        with self._lock:
-            bufs, self._pending_free = self._pending_free, []
-        if bufs:
-            try:
-                self.client.free(*bufs)
-            except Exception:
-                pass
 
     def fetch(self, buf) -> np.ndarray:
         with self.client.shim_clock:    # the shim's own cost, in CPU time
-            self._flush_frees()
             return self.client.get(buf)
 
     # -- the jax.jit replacement ------------------------------------------
@@ -192,8 +174,12 @@ class _RemoteJitFunction:
         self._fn = fn
         self._static_argnums = _as_tuple(jit_kwargs.get("static_argnums"))
         self._static_argnames = _as_tuple(jit_kwargs.get("static_argnames"))
-        # donate_argnums is accepted but not forwarded: the proxy frees
-        # dead buffers via RemoteArray GC instead (ROADMAP S4).
+        # donate_argnums is accepted but not forwarded: a donated
+        # argument is still referenced by the caller's frame when the
+        # execute goes out, so the proxy cannot take it as dead; its
+        # buffer is freed when its RemoteArray is collected, and the free
+        # rides on the next execute. Forwarding it (so the runtime can
+        # reuse the buffers for the outputs) is ROADMAP S4.
         self._cache: dict = {}
         self.__wrapped__ = fn
 
@@ -213,7 +199,6 @@ class _RemoteJitFunction:
         import jax
 
         shim = self._shim
-        shim._flush_frees()
 
         static_items = []
         dyn_args = list(args)

@@ -19,10 +19,11 @@ Two pieces, matching the reference's two client obligations
 
 from __future__ import annotations
 
+import base64
 import threading
 import time
 from collections import deque
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 
 import numpy as np
 
@@ -111,6 +112,11 @@ class RemoteBuffer:
     handle: int
     shape: tuple[int, ...]
     dtype: str
+    #: the array's value, where the ``execute`` that made it brought it
+    #: back in its reply (the proxy's completion barrier had read it):
+    #: ``ProxyClient.get`` answers from it without a request
+    value: "np.ndarray | None" = field(default=None, compare=False,
+                                       repr=False)
 
     @property
     def nbytes(self) -> int:
@@ -177,33 +183,45 @@ class RemoteExecutable:
         return self.call_async(*args, donate=donate).result()
 
     def call_async(self, *args, donate: bool = False) -> RemoteFuture:
-        """Dispatch without waiting for completion: uploads happen now
-        (synchronously), the execute itself rides the pipelined
-        connection, and the returned :class:`RemoteFuture` resolves to
-        the output pytree — so call sites overlap dispatch with host
-        work (and with further dispatches)."""
+        """Dispatch without waiting for completion: the call is ONE
+        request where every host leaf is small (it rides inside the
+        ``execute``; a larger one is uploaded now, synchronously), the
+        execute itself rides the pipelined connection, and the returned
+        :class:`RemoteFuture` resolves to the output pytree — so call
+        sites overlap dispatch with host work (and with further
+        dispatches)."""
         import jax
         leaves = jax.tree_util.tree_leaves(args)
-        bufs, uploaded = [], []
-        # donate=True donates every argument (uploaded ones included);
-        # otherwise per-call uploads are freed afterwards — including on
-        # any failure from the upload loop onward (a retried step must not
-        # leak its auto-uploads against the HBM cap). Donation frees only
-        # after success, so the failure path never double-frees; the
-        # failure-path free is best-effort (the failure may have been the
-        # connection itself dying — the original error must win).
+        handles, inline, uploaded = [], [], []
+        # donate=True donates every argument that has a handle (uploaded
+        # ones included); otherwise per-call uploads are freed afterwards —
+        # including on any failure from the upload loop onward (a retried
+        # step must not leak its auto-uploads against the HBM cap).
+        # Donation frees only after success, so the failure path never
+        # double-frees; the failure-path free is best-effort (the failure
+        # may have been the connection itself dying — the original error
+        # must win). An inline leaf has no handle: the proxy drops it when
+        # the program ends, whichever way.
         client = self._client
+        can_inline = "inline" in client.features
         try:
-            for leaf in leaves:
+            for pos, leaf in enumerate(leaves):
                 if isinstance(leaf, RemoteBuffer):
-                    bufs.append(leaf)
+                    handles.append(leaf.handle)
+                    continue
+                arr = np.asarray(leaf, order="C")
+                if (can_inline and arr.nbytes <= protocol.INLINE_MAX
+                        and not arr.dtype.hasobject):
+                    inline.append((pos, arr))
+                    handles.append(None)
                 else:
-                    buf = client.put(leaf)
-                    bufs.append(buf)
+                    buf = client.put(arr)
+                    handles.append(buf.handle)
                     uploaded.append(buf)
-            fut = client.execute_async(
-                self._exec_id, [b.handle for b in bufs],
-                donate=[b.handle for b in bufs] if donate else ())
+            fut = client._send_execute(
+                self._exec_id, handles, inline=inline,
+                donate=[h for h in handles if h is not None] if donate
+                else ())
         except Exception:
             if uploaded:
                 try:
@@ -214,7 +232,7 @@ class RemoteExecutable:
 
         def resolve():
             try:
-                handles = fut.result()
+                reply = fut.result()
             except Exception:
                 if uploaded:
                     try:
@@ -225,7 +243,16 @@ class RemoteExecutable:
             if not donate and uploaded:
                 client.free(*uploaded)
             out_bufs = [RemoteBuffer(h, tuple(shape), dtype)
-                        for h, (shape, dtype) in zip(handles, self.out_meta)]
+                        for h, (shape, dtype) in zip(reply["handles"],
+                                                     self.out_meta)]
+            if "inline" in reply:
+                idx, text = reply["inline"]
+                buf = out_bufs[idx]
+                out_bufs[idx] = RemoteBuffer(
+                    buf.handle, buf.shape, buf.dtype,
+                    np.frombuffer(base64.b64decode(text),
+                                  dtype=np.dtype(buf.dtype)
+                                  ).reshape(buf.shape))
             return jax.tree_util.tree_unflatten(self._out_tree, out_bufs)
 
         return RemoteFuture(resolve, fut._pending)
@@ -288,6 +315,10 @@ class ProxyClient:
         self.device: str = reply.get("device", "")
         #: transport features BOTH ends agreed on at register
         self.features: frozenset[str] = frozenset(reply.get("features", ()))
+        #: handles dropped with no request of their own (``free_later``):
+        #: they ride on the next ``execute``, or go out before any other
+        #: request. A deque, appended to from ``__del__`` on any thread.
+        self._deferred: deque = deque()
 
     # -- buffers -------------------------------------------------------------
 
@@ -306,6 +337,7 @@ class ProxyClient:
         return max(2, min(16, (256 << 20) // max(chunk, 1)))
 
     def put(self, array) -> RemoteBuffer:
+        self._flush_deferred()
         arr = np.asarray(array)
         # parts = [npy header, flat data view]: the payload crosses the
         # socket straight from the array's memory — zero host copies on
@@ -384,6 +416,11 @@ class ProxyClient:
             raise
 
     def get(self, buf: RemoteBuffer) -> np.ndarray:
+        if buf.value is not None:
+            # came back with the execute's reply; a copy, so the caller's
+            # array is writable and its own
+            return buf.value.copy()
+        self._flush_deferred()
         chunk = self._chunk()
         conn = self._conn
         # The serialized stream is the buffer's bytes plus a <4 KiB .npy
@@ -460,9 +497,39 @@ class ProxyClient:
                 mv[doff:doff + dlen] = part
 
     def free(self, *bufs) -> None:
-        import jax
-        handles = [b.handle for b in jax.tree_util.tree_leaves(bufs)
-                   if isinstance(b, RemoteBuffer)]
+        self.free_later(*bufs)
+        self._flush_deferred()
+
+    def free_later(self, *bufs) -> None:
+        """Drop buffers without a request of their own: no I/O here (safe
+        from ``__del__``); the handles go out with the next request."""
+        for b in bufs:
+            if isinstance(b, RemoteBuffer):     # the common, cheap case
+                leaves = (b,)
+            else:
+                import jax
+                leaves = [leaf for leaf in jax.tree_util.tree_leaves(b)
+                          if isinstance(leaf, RemoteBuffer)]
+            for leaf in leaves:
+                self._deferred.append(leaf.handle)
+                # a freed buffer answers no get: not from the value that
+                # came with its execute's reply either
+                object.__setattr__(leaf, "value", None)
+
+    def _take_deferred(self) -> list[int]:
+        handles = []
+        try:
+            while True:
+                handles.append(self._deferred.popleft())
+        except IndexError:
+            return handles
+
+    def _flush_deferred(self) -> None:
+        """The round trip for queued frees, paid by every request that
+        cannot carry them (any but an ``execute`` to a proxy that speaks
+        ``inline``), so a tenant that stops calling its programs does not
+        keep dead buffers charged."""
+        handles = self._take_deferred()
         if handles:
             self._conn.call({"op": "free", "name": self.name,
                              "handles": handles})
@@ -498,6 +565,7 @@ class ProxyClient:
             arr = np.asarray(leaf)
             return jax.ShapeDtypeStruct(arr.shape, arr.dtype)
 
+        self._flush_deferred()
         flat_specs, in_tree = jax.tree_util.tree_flatten(
             jax.tree_util.tree_map(spec, example_args))
         out_tree_store = []
@@ -529,37 +597,65 @@ class ProxyClient:
         ``defer=True`` corks the request (see ``Connection.submit``):
         back-to-back small dispatches share one wire write. Call
         ``flush()`` before blocking on a deferred future."""
+        fut = self._send_execute(exec_id, handles, donate=donate,
+                                 defer=defer)
+        return RemoteFuture(lambda: list(fut.result()["handles"]),
+                            fut._pending)
+
+    def _send_execute(self, exec_id: int, handles: list, inline=(),
+                      donate=(), defer: bool = False) -> "RemoteFuture":
+        """One ``execute`` request; the future resolves to its reply.
+        ``inline``: ``[(arg position, host array)]`` for the nulls in
+        ``handles``; the arrays' bytes are the frame's blob. Queued frees
+        ride along where the proxy speaks ``inline``."""
         msg = {"op": "execute", "name": self.name, "exec_id": exec_id,
                "args": handles}
         if donate:
             msg["donate"] = list(donate)
+        blob = None
+        if inline:
+            msg["inline"] = [[pos, str(arr.dtype), list(arr.shape)]
+                             for pos, arr in inline]
+            blob = [arr.reshape(-1).view(np.uint8) for _, arr in inline
+                    if arr.nbytes]
+        frees = ()
+        if "inline" in self.features:
+            frees = self._take_deferred()
+            if frees:
+                msg["free"] = frees
+        else:
+            self._flush_deferred()
         clock = self.shim_clock
         number, t_send, msg[protocol.SHIM_KEY] = clock.send()
         tid = getattr(self._conn, "trace_id", "")
         tracer = obs_trace.get_tracer() if tid else None
         t0 = tracer.now_ms() if tracer is not None else 0.0
-        if self._conn.pipelined:
-            rep = self._conn.submit(msg, defer=defer)
+        def transported():
+            # client-measured round trip: the critical-path "transport"
+            # segment (the proxy's own "execute" span is subtracted in
+            # obs/critpath.py)
+            if tracer is not None:
+                tracer.record("transport", tid, t0, tracer.now_ms(),
+                              proc="client", op="execute")
 
-            def resolve():
-                reply = rep.result()[0]
-                clock.replied(number, t_send, rep.done_at)
-                handles_out = list(reply["handles"])
-                if tracer is not None:
-                    # client-measured round trip: the critical-path
-                    # "transport" segment (the proxy's own "execute"
-                    # span is subtracted in obs/critpath.py)
-                    tracer.record("transport", tid, t0, tracer.now_ms(),
-                                  proc="client", op="execute")
-                return handles_out
+        try:
+            if not self._conn.pipelined:    # lockstep: resolved already
+                reply, _ = self._conn.call(msg, blob=blob)
+                clock.replied(number, t_send, time.monotonic())
+                transported()
+                return RemoteFuture(lambda: reply)
+            rep = self._conn.submit(msg, blob=blob, defer=defer)
+        except protocol.FrameTooLarge:
+            self._deferred.extendleft(reversed(frees))  # nothing was sent
+            raise
 
-            return RemoteFuture(resolve, rep)
-        reply, _ = self._conn.call(msg)   # lockstep: resolved already
-        clock.replied(number, t_send, time.monotonic())
-        if tracer is not None:
-            tracer.record("transport", tid, t0, tracer.now_ms(),
-                          proc="client", op="execute")
-        return RemoteFuture(lambda: list(reply["handles"]))
+        def resolve():
+            reply = rep.result()[0]
+            clock.replied(number, t_send, rep.done_at)
+            transported()
+            return reply
+
+        return RemoteFuture(resolve, rep)
 
     def flush(self) -> None:
         """Send any corked (``defer=True``) requests now."""
@@ -567,6 +663,7 @@ class ProxyClient:
             self._conn.flush()
 
     def usage(self) -> dict:
+        self._flush_deferred()
         reply, _ = self._conn.call({"op": "usage", "name": self.name})
         return reply
 
@@ -586,6 +683,7 @@ class ProxyClient:
             # session would otherwise spend the whole reconnect budget
             # inside close()
             try:
+                self._flush_deferred()
                 self._conn.call({"op": "unregister", "name": self.name})
             except Exception:
                 pass

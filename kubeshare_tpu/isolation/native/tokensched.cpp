@@ -18,6 +18,15 @@
 //     enforcement (≙ gem-schd's window accounting).
 //   * quota — min(base_quota, remaining window allowance), floored at
 //     min_quota for grant eligibility.
+//   * bounded memory — the scheduler's own virtual time `vnow` is the
+//     largest vtime a grant was ever made at. A client that kept asking is
+//     never behind it; one that comes back (from idling, from its window
+//     cap, from a set-up phase the others did not share) is lifted to
+//     vnow - base_quota / request: it is owed one quantum of device time at
+//     most, and a client that ran alone meanwhile, having dragged vnow along,
+//     owes nothing. Without it vtime never forgets: usage from before the
+//     other client first asked decides every contended pick for as long as
+//     it takes to pay back.
 //
 // Pure computation: no threads, no sockets, no clocks. The caller (the
 // Python server in ../tokensched.py, or a test) supplies `now_ms` and does
@@ -89,6 +98,7 @@ struct Scheduler {
   std::string holder;  // client currently holding the token ("" = free)
   double holder_quota_ms = 0.0;
   double holder_since_ms = 0.0;
+  double vnow = 0.0;  // largest vtime a grant was made at
 };
 
 Client* find(Scheduler* s, const char* name) {
@@ -189,6 +199,8 @@ int ts_poll(void* h, double now_ms, char* name_out, int name_cap,
   double best_remaining = 0.0;
   for (auto& [k, c] : s->clients) {
     if (!c.waiting) continue;
+    // A returning client is owed one quantum at most (header: bounded memory).
+    c.vtime = std::max(c.vtime, s->vnow - s->base_quota_ms / c.request);
     const double cap_ms = c.limit * s->window_ms;
     const double used = c.window_usage(now_ms, s->window_ms);
     const double remaining = cap_ms - used;
@@ -215,6 +227,7 @@ int ts_poll(void* h, double now_ms, char* name_out, int name_cap,
   s->holder = best->name;
   s->holder_quota_ms = quota;
   s->holder_since_ms = now_ms;
+  s->vnow = std::max(s->vnow, best->vtime);
   std::snprintf(name_out, name_cap, "%s", best->name.c_str());
   *quota_ms_out = quota;
   *next_wake_ms_out = inf;

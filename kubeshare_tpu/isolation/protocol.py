@@ -74,8 +74,19 @@ ACK_KEY = "_ack"
 #: it, and a proxy that does not know it, behave as they always have.
 SHIM_KEY = "shim"
 
+#: a host array of at most this many bytes travels INSIDE an ``execute``
+#: (request key ``inline``, its bytes in the frame's blob), and a
+#: program's completion-barrier output of at most this many bytes comes
+#: back inside the reply (reply key ``inline``): the barrier reads such an
+#: output whole, above it only a one-element slice. One constant, so what
+#: rides in the call is what the proxy would have read anyway.
+INLINE_MAX = 65536
+
 #: transport features this build can negotiate at register time.
-FEATURES = ("resume", "seq", "preempt")
+#: ``"inline"``: an ``execute`` may carry ``inline`` (small host inputs)
+#: and ``free`` (handles to drop first), and its reply may carry ``inline``
+#: (the barrier's host read); see doc/isolation-wire.md.
+FEATURES = ("resume", "seq", "preempt", "inline")
 
 #: per-connection server credit: requests accepted off the wire but not
 #: yet replied to. Bounds the dispatch queue AND the reply queue, so a

@@ -16,8 +16,9 @@ Enforcement lives where the reference's lives:
 - **compute** — every execution is gated by the per-chip token scheduler
   (:mod:`.tokensched`, gem-schd parity): a client acquires a quota, keeps
   the token across back-to-back programs until the quota is exhausted
-  (Gemini's kernel-burst amortization), and an idle timer returns the token
-  early when the client stalls between steps;
+  (Gemini's kernel-burst amortization), an idle timer returns the token
+  early when the client stalls between steps, and where a program ends
+  while another client waits the scheduler's weighted pick says who holds;
 - **HBM** — device bytes are accounted per client at allocation time
   (``put`` and execution outputs), mirroring the hook's ``gpu_mem`` cap at
   ``cuMemAlloc`` (annotation default rule at ``pkg/scheduler/pod.go:419-424``).
@@ -25,6 +26,7 @@ Enforcement lives where the reference's lives:
 
 from __future__ import annotations
 
+import base64
 import os
 import socket
 import threading
@@ -160,6 +162,12 @@ class _Session:
     last_end_ms: float = 0.0      # when the last execution finished
     exec_count: int = 0
     exec_ms_total: float = 0.0
+    #: round trips: every request handled for the session, any op; host
+    #: arrays that came in on an ``execute``; barrier reads sent back in
+    #: its reply. ``rpc_count`` over ``exec_count`` is the requests a call.
+    rpc_count: int = 0
+    inline_in_total: int = 0
+    inline_out_total: int = 0
     #: where this session's executions blocked and whose idle gap each
     #: ended (_PHASE_KEYS; added to under ``lock``)
     phase_ms: dict = field(
@@ -659,6 +667,15 @@ class ChipProxy:
         ``TokenScheduler.renew`` documents). Idle clients return the token
         via the idle timer instead.
 
+        Where a program ends while another tenant waits, the scheduler
+        is asked at once who should hold (``renew_or_yield``: the same
+        atomic release + re-request, without the wait): the holder stands
+        in the weighted pick with what it has used, so a waiter waits for
+        the program in flight and never for the rest of a quantum of
+        several, its program runs under the holder's turn-around, and a
+        holder the pick prefers keeps its burst. With nobody waiting
+        nothing is asked and the hold goes on as above.
+
         A hold marked preempted (``TokenScheduler.preempted``) yields
         here too — this gate sits exactly at a program boundary, so the
         renew forfeits the remaining quantum without ever interrupting
@@ -723,12 +740,26 @@ class ChipProxy:
                     sess.used_ms += elapsed
                     sess.exec_count += 1
                     sess.exec_ms_total += elapsed
-                    sess.busy = False
                     sess.last_end_ms = end
                     ms = sess.phase_ms
                     ms["idle_attach_ms_total"] += idle[0]
                     ms["idle_gate_ms_total"] += idle[1]
                     ms["idle_proxy_ms_total"] += idle[2]
+                    holding, used = sess.holding, sess.used_ms
+                # still busy: the idle watchdog leaves this hold alone
+                # until the boundary's pick is made
+                asked = holding and self.scheduler.contended(sess.name)
+                quota = None
+                if asked:
+                    try:
+                        quota = self.scheduler.renew_or_yield(sess.name, used)
+                    except Exception:   # raced a drop: the hold is gone
+                        pass
+                with sess.lock:
+                    sess.busy = False
+                    if asked and sess.holding:  # its usage is on the books
+                        sess.holding = quota is not None
+                        sess.quota_ms, sess.used_ms = quota or 0.0, 0.0
                 if sess.call is not None:
                     sess.call[1] += gate_ms + dlock_ms + elapsed
             return result
@@ -885,6 +916,8 @@ class ChipProxy:
         if not name:
             raise PermissionError("not registered on this connection")
         sess = self._session(name)
+        with sess.lock:     # a session's connections are handled in parallel
+            sess.rpc_count += 1
 
         rid = req.pop(protocol.RID_KEY, None)
         ack = req.pop(protocol.ACK_KEY, None)
@@ -1284,10 +1317,7 @@ class ChipProxy:
             return {"ok": True}
 
         if op == "free":
-            for handle in req["handles"]:
-                self._forget_buffer(sess, int(handle))
-                if sess.fetch_cache and sess.fetch_cache[0] == int(handle):
-                    sess.fetch_cache = None
+            self._free_handles(sess, req["handles"])
             return {"ok": True}
 
         if op == "compile":
@@ -1304,12 +1334,15 @@ class ChipProxy:
             state["executing"] = sess
             if protocol.SHIM_KEY in req:
                 self._note_shim(sess, req[protocol.SHIM_KEY])
-            return self._execute(sess, req)
+            return self._execute(sess, req, state["blob"])
 
         if op == "usage":
             with self._slock:
                 sessions = {s.name: {"exec_ms_total": s.exec_ms_total,
                                      "exec_count": s.exec_count,
+                                     "rpc_count": s.rpc_count,
+                                     "inline_in_total": s.inline_in_total,
+                                     "inline_out_total": s.inline_out_total,
                                      **s.phase_ms}
                             for s in self._sessions.values()}
             return {"ok": True,
@@ -1333,6 +1366,12 @@ class ChipProxy:
             return {"ok": True}
 
         return {"ok": False, "error": f"unknown op {op!r}"}
+
+    def _free_handles(self, sess: _Session, handles) -> None:
+        for handle in map(int, handles):
+            self._forget_buffer(sess, handle)
+            if sess.fetch_cache and sess.fetch_cache[0] == handle:
+                sess.fetch_cache = None
 
     def _put_array(self, sess: _Session, arr) -> dict:
         # Pre-check with the host-side size so an over-cap upload is
@@ -1393,8 +1432,11 @@ class ChipProxy:
         out_sizes = [int(np.prod(shape or [1])) * np.dtype(dtype).itemsize
                      for shape, dtype in out_meta]
         nonempty = [(n, i) for i, n in enumerate(out_sizes) if n > 0]
-        sync_out = ((-1, False) if not nonempty
-                    else (min(nonempty)[1], min(nonempty)[0] > 65536))
+        # the smallest; of several that small the LAST (a step returns
+        # its loss after its state, whose step count is as small)
+        least = min(nonempty, key=lambda ni: (ni[0], -ni[1]), default=None)
+        sync_out = ((-1, False) if least is None
+                    else (least[1], least[0] > protocol.INLINE_MAX))
         if exec_id is None:
             exec_id = sess.fresh_id()
         sess.executables[exec_id] = _Executable(
@@ -1428,39 +1470,78 @@ class ChipProxy:
                                        .lower(*exe.in_specs).compile())
         return exe.prog.single
 
-    def _execute(self, sess: _Session, req: dict) -> dict:
+    def _execute(self, sess: _Session, req: dict, blob=None) -> dict:
         _refuse_loop_keys(req, "repeat", "chain_steps")
+        # handles the tenant dropped since its last request ride in on this
+        # one and go first: their memory is back before anything of this
+        # call is charged
+        self._free_handles(sess, req.get("free", ()))
         exe = sess.executables[int(req["exec_id"])]
-        args = [sess.buffers[int(h)] for h in req["args"]]
+        # a small host input came in the frame's blob: it has no handle (a
+        # null in ``args``), goes to the device under the program's own
+        # _dlock hold and is dropped when the program ends
+        inline = self._inline_inputs(req.get("inline", ()), blob)
+        args = [None if h is None else sess.buffers[int(h)]
+                for h in req["args"]]
         # Validate args BEFORE dispatch: a shape/dtype mismatch must be a
         # clean client error, not a device failure.
         if len(args) != len(exe.in_specs):
             raise ValueError(f"expected {len(exe.in_specs)} args, "
                              f"got {len(args)}")
+
+        def mismatch(i, shape, dtype):
+            return ValueError(
+                f"arg {i}: got {tuple(shape)}/{dtype}, program expects "
+                f"{exe.in_meta[i][0]}/{exe.in_meta[i][1]}")
+
         # direct tuple/np.dtype comparison against the compile-time
         # in_meta — stringifying dtypes here costs ~10 µs per dispatch
         for i, (buf, (shape, dtype)) in enumerate(zip(args, exe.in_meta)):
-            if tuple(buf.shape) != shape or buf.dtype != dtype:
-                raise ValueError(
-                    f"arg {i}: got {tuple(buf.shape)}/{buf.dtype}, program "
-                    f"expects {shape}/{dtype}")
+            if buf is not None and (tuple(buf.shape) != shape
+                                    or buf.dtype != dtype):
+                raise mismatch(i, buf.shape, buf.dtype)
+        if req["args"].count(None) != len(inline):
+            raise ValueError(f"{req['args'].count(None)} nulls in args, "
+                             f"{len(inline)} inline inputs")
+        canonical = self._jax.dtypes.canonicalize_dtype
+        inline_nbytes = 0
+        for pos, arr in inline:
+            if not (0 <= pos < len(args)) or args[pos] is not None:
+                raise ValueError(f"inline input for arg {pos}, which is "
+                                 f"no null in args")
+            # what device_put will make of it (int64 -> int32 with x64
+            # off): the check and the charge are the device buffer's
+            dtype = canonical(arr.dtype)
+            if (arr.shape, dtype) != exe.in_meta[pos]:
+                raise mismatch(pos, arr.shape, dtype)
+            inline_nbytes += arr.size * dtype.itemsize
+            args[pos] = arr
         donate = [int(h) for h in req.get("donate", [])]
         fn = self._single_fn(exe)
         # Cap check up front — allocation must not happen over-cap even
-        # transiently (donated buffers are freed only after success).
-        self._charge(sess, exe.out_nbytes)
+        # transiently (donated buffers are freed only after success). An
+        # inline input is charged like a put, refused before dispatch, and
+        # refunded when the call is over, whichever way it ends.
+        self._charge(sess, inline_nbytes)
         timing: dict = {}
         try:
-            outs = self._gated(
-                sess, lambda: self._run_fn(fn, args, timing, exe.sync_out),
-                timing)
-        except Exception:
-            # A token-gate failure (scheduler closed / client removed while
-            # waiting) dispatched nothing, and the compiled program aliases
-            # no argument, so a device failure consumed none either: every
-            # buffer is intact and only the output charge goes back.
-            sess.hbm_used -= exe.out_nbytes
-            raise
+            self._charge(sess, exe.out_nbytes)
+            try:
+                outs, read = self._gated(
+                    sess, lambda: self._run_fn(fn, args, timing,
+                                               exe.sync_out, inline),
+                    timing)
+            except Exception:
+                # A token-gate failure (scheduler closed / client removed
+                # while waiting) dispatched nothing, and the compiled
+                # program aliases no argument, so a device failure consumed
+                # none either: every buffer is intact and only the output
+                # charge goes back.
+                sess.hbm_used -= exe.out_nbytes
+                raise
+        finally:
+            sess.hbm_used -= inline_nbytes
+        sess.inline_in_total += len(inline)
         with self._slock:   # counter shared across connections
             self.total_execs += 1
         handles = []
@@ -1471,10 +1552,46 @@ class ChipProxy:
             self._journal_buffer(sess, handle, out)
         for handle in donate:
             self._forget_buffer(sess, handle)
-        return {"ok": True, "handles": handles}
+        reply = {"ok": True, "handles": handles}
+        if read is not None and "inline" in sess.features:
+            # the barrier's host read IS this output's value: it goes back
+            # with its handle (shape and dtype are out_meta's), as text, so
+            # the replay cache and the journal keep the reply whole
+            reply["inline"] = [exe.sync_out[0],
+                               base64.b64encode(read.tobytes()).decode()]
+            sess.inline_out_total += 1
+        return reply
 
-    def _run_fn(self, fn, args: list, timing: dict | None = None,
-                sync_out: tuple | None = None):
+    @staticmethod
+    def _inline_inputs(spec, blob) -> list:
+        """``[(arg position, host array)]`` of an ``execute``'s ``inline``
+        key, ``[[position, dtype, shape], ...]``: the arrays' C-order bytes
+        lie end to end in the frame's blob, in that order. Input from
+        outside the program: a size over ``protocol.INLINE_MAX`` or a blob
+        of another length is a clean error before anything is charged."""
+        out, off = [], 0
+        mv = memoryview(blob if blob is not None else b"")
+        for pos, dtype, shape in spec:
+            dtype = np.dtype(dtype)
+            if dtype.hasobject:
+                raise ValueError("object arrays cannot cross the proxy wire")
+            shape = tuple(int(d) for d in shape)
+            nbytes = int(np.prod(shape, dtype=np.int64)) * dtype.itemsize
+            if nbytes > protocol.INLINE_MAX or off + nbytes > mv.nbytes:
+                raise ValueError(
+                    f"inline input for arg {pos}: {nbytes} bytes (at most "
+                    f"{protocol.INLINE_MAX}, {mv.nbytes - off} left in the "
+                    f"blob)")
+            out.append((int(pos), np.frombuffer(
+                mv[off:off + nbytes], dtype=dtype).reshape(shape)))
+            off += nbytes
+        if off != mv.nbytes:
+            raise ValueError(f"execute blob holds {mv.nbytes} bytes, its "
+                             f"inline inputs {off}")
+        return out
+
+    def _run_fn(self, fn, args: list, timing: dict, sync_out: tuple,
+                inline=()):
         # _dlock inside the token gate: execution is already exclusive per
         # the scheduler, but a concurrent put/get/compile from another
         # connection must not drive the device while this runs. Device
@@ -1483,24 +1600,33 @@ class ChipProxy:
         # Phase stamps device_start / device_end bound ``exec_ms``; with
         # ``arrived`` and ``granted`` (left in ``timing`` by _gated) they
         # split the idle gap this program ends.
-        timing = timing if timing is not None else {}
         who = timing.get("session", "")
         asked = _now_ms()
         with obs_trace.phase("dlock_wait", who):
             self._dlock.acquire()
         try:
+            timing["dlock_ms"] = _now_ms() - asked
+            if inline:
+                # before device_start: the handler's own work, so it is
+                # in idle_proxy and self_ms and not in the tenant's exec_ms
+                args = list(args)
+                # one call for all of them: a put costs the host a few
+                # hundred microseconds each way it is made
+                put = self._jax.device_put([a for _, a in inline],
+                                           self.device)
+                for (pos, _), buf in zip(inline, put):
+                    args[pos] = buf
             start = _now_ms()
-            timing["dlock_ms"] = start - asked
             timing["idle"] = self._split_idle(timing, start)
             try:
                 with obs_trace.phase("device", who):
-                    outs = self._run_to_completion(fn, args, sync_out)
+                    result = self._run_to_completion(fn, args, sync_out)
             finally:
                 end = self._last_device_end = _now_ms()
                 timing["exec_ms"] = end - start
         finally:
             self._dlock.release()
-        return outs
+        return result
 
     def _split_idle(self, timing: dict, start: float) -> tuple:
         """The chip's idle gap ``[last program's end, start]`` in three
@@ -1518,7 +1644,9 @@ class ChipProxy:
         granted = min(max(timing["granted"], arrived), start)
         return (arrived - last, granted - arrived, start - granted)
 
-    def _run_to_completion(self, fn, args: list, sync_out: tuple | None):
+    def _run_to_completion(self, fn, args: list, sync_out: tuple):
+        """``(outputs, read)``: ``read`` is the host value of output
+        ``sync_out[0]`` where the barrier read it whole, else None."""
         outs = fn(*args)
         if not isinstance(outs, (list, tuple)):
             outs = [outs]
@@ -1534,24 +1662,19 @@ class ChipProxy:
         # pick precomputed at compile time (_Executable.sync_out) —
         # scanning jax .nbytes properties per dispatch costs ~25 µs and
         # this runs per op on the pipelined wire's serial stage.
-        if sync_out is None:
-            nonempty = [o for o in outs if getattr(o, "nbytes", 0) > 0]
-            small = (min(nonempty, key=lambda o: o.nbytes)
-                     if nonempty else None)
-            big = small is not None and small.nbytes > 65536
-        else:
-            idx, big = sync_out
-            small = outs[idx] if 0 <= idx < len(outs) else None
+        idx, big = sync_out
+        small = outs[idx] if 0 <= idx < len(outs) else None
+        read = None
         if small is None:     # all-empty: block_until_ready only
             self._jax.block_until_ready(outs)
+        elif big:
+            # Don't haul a big buffer to host just to sync:
+            # a 1-element slice is a dependent dispatch that
+            # completes strictly after the program.
+            np.asarray(small.ravel()[:1])
         else:
-            if big:
-                # Don't haul a big buffer to host just to sync:
-                # a 1-element slice is a dependent dispatch that
-                # completes strictly after the program.
-                small = small.ravel()[:1]
-            np.asarray(small)
-        return list(outs)
+            read = np.asarray(small)
+        return list(outs), read
 
     def _cleanup(self, state: dict) -> None:
         if self._crashed:

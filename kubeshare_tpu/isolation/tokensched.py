@@ -98,6 +98,7 @@ class PyTokenCore:
         self.min_quota_ms = min_quota_ms
         self._clients: dict[str, _PyClient] = {}
         self._holder: str | None = None
+        self._vnow = 0.0    # the scheduler's virtual time (see poll)
         self._closed = False
 
     def add_client(self, name: str, request: float, limit: float) -> None:
@@ -138,6 +139,10 @@ class PyTokenCore:
         for c in self._clients.values():
             if not c.waiting:
                 continue
+            # a client that comes back is owed one quantum of device time
+            # at most, however long it stayed away or was capped
+            c.vtime = max(c.vtime,
+                          self._vnow - self.base_quota_ms / c.request)
             cap = c.limit * self.window_ms
             remaining = cap - c.window_usage(now_ms, self.window_ms)
             if remaining < self.min_quota_ms:
@@ -152,6 +157,7 @@ class PyTokenCore:
         quota = max(self.min_quota_ms, min(self.base_quota_ms, best_remaining))
         best.waiting = False
         self._holder = best.name
+        self._vnow = max(self._vnow, best.vtime)
         return best.name, quota
 
     def release_token(self, name: str, used_ms: float, now_ms: float) -> None:
@@ -530,6 +536,46 @@ class TokenScheduler:
                 raise
             self._note_grant(name, time.monotonic() - t0, trace_id)
             return quota
+
+    def contended(self, name: str) -> bool:
+        """Is the pick at holder *name*'s program boundary the stride's to
+        make now: another client waits, and no directed grant is armed (a
+        hold marked preempted yields through :meth:`renew`, at its next
+        call, as it always has)."""
+        with self._cond:
+            return (not self._boost and name not in self._preempt_flags
+                    and any(q for n, q in self._waiting.items()
+                            if n != name))
+
+    def renew_or_yield(self, name: str, used_ms: float) -> float | None:
+        """:meth:`renew` for a holder that is not asking yet: where its
+        program ended while other clients wait, release + re-request + ONE
+        grant decision, never blocking. The new quota when the weighted
+        pick is still *name* (its hold goes on, the usage so far reported);
+        None when the token went to a waiter or *name* is at its window
+        cap, its request withdrawn: it comes back through :meth:`acquire`.
+
+        This is the one rule for a contended program boundary. The holder
+        stands in the pick with what it has used, as in ``renew``, so
+        request-weighted shares hold at the grain of one program whatever
+        its length, a waiter waits for the program in flight and never for
+        the rest of a quantum of several, and a holder the pick prefers (a
+        burst of short programs from a client under its share) keeps the
+        token without a hand-over between them.
+        """
+        with self._cond:
+            self._core.release_token(name, used_ms, self._clock())
+            self._core.request_token(name)
+            result = self._poll_grant()
+            if isinstance(result, tuple) and result[0] == name:
+                self._hold_quota[name] = result[1]
+                return result[1]
+            self._core.cancel_request(name)
+            self._note_release(name, used_ms)
+            if isinstance(result, tuple):
+                self._grants[result[0]] = result[1]
+            self._cond.notify_all()
+            return None
 
     def _take_grant(self, name: str, q: deque) -> float:
         # Caller holds self._cond; a grant for `name` exists and this

@@ -70,7 +70,9 @@ class ProxyServable:
     def execute(self, x: np.ndarray) -> np.ndarray:
         out = self._exe(self._params, np.asarray(x, dtype=np.float32))
         y = np.asarray(self.client.get(out))
-        self.client.free(out)    # outputs are HBM-charged device buffers
+        # outputs are HBM-charged device buffers: the free rides on the
+        # next batch's execute (or goes out at close)
+        self.client.free_later(out)
         return y
 
     def close(self) -> None:

@@ -66,6 +66,92 @@ def test_attach_proxy_routes_unmodified_jit(proxy, monkeypatch):
     assert jax.jit is real_jit  # detach restored the real jit
 
 
+def _score_shaped(jax, jnp):
+    """`float(score(params, toks, np.int32(length)))`: resident params,
+    two small host leaves, one float read back."""
+    params = jax.jit(lambda k: {"w": jnp.cos(jnp.arange(256.0) * k)})(
+        np.float32(0.1))
+    score = jax.jit(lambda p, toks, n: jnp.sum(
+        jnp.where(jnp.arange(toks.shape[0]) < n, p["w"][toks], 0.0)))
+    toks = np.arange(32, dtype=np.int32)
+
+    def call(i):
+        value = float(score(params, (toks + i) % 256, np.int32(4 + i)))
+        want = np.cos(np.arange(256.0) * np.float32(0.1))[
+            ((toks + i) % 256)[:4 + i]].sum()
+        assert value == pytest.approx(float(want), abs=1e-4)
+    return call, 2
+
+
+def _train_shaped(jax, jnp):
+    """`params, opt, loss = step(params, opt, x, y); float(loss)`: the
+    old state's arrays are collected at the assignment and their frees
+    ride on the next step."""
+    def step(params, opt, x, y):
+        def loss_fn(p):
+            return jnp.mean((x @ p["w"] + p["b"] - y) ** 2)
+        loss, g = jax.value_and_grad(loss_fn)(params)
+        opt = {"count": opt["count"] + 1.0,
+               "mu": jax.tree_util.tree_map(lambda m, g: 0.9 * m + g,
+                                            opt["mu"], g)}
+        params = jax.tree_util.tree_map(lambda p, m: p - 0.01 * m, params,
+                                        opt["mu"])
+        return params, opt, loss
+
+    step = jax.jit(step)
+    init = jax.jit(lambda k: ({"w": jnp.zeros((8,)) * k, "b": jnp.zeros(())},
+                              {"count": jnp.zeros(()),
+                               "mu": {"w": jnp.zeros((8,)),
+                                      "b": jnp.zeros(())}}))
+    state = list(init(np.float32(0.0)))
+    rng = np.random.default_rng(1)
+    x = rng.normal(size=(16, 8)).astype(np.float32)
+    y = rng.normal(size=(16,)).astype(np.float32)
+    losses = []
+
+    def call(i):
+        state[0], state[1], loss = step(state[0], state[1], x, y)
+        losses.append(float(loss))          # the host read ends the step
+        assert len(losses) < 2 or losses[-1] < losses[-2]
+    return call, 2
+
+
+@pytest.mark.parametrize("shape", [_score_shaped, _train_shaped],
+                         ids=["scorer", "trainer"])
+def test_a_jitted_call_under_proxy_attach_is_one_request(proxy, shape):
+    """What a tenant of the benchmark does, through the shim: after
+    warm-up every call is exactly one request to the proxy, with its host
+    leaves inside and its result's value in the reply."""
+    import gc
+
+    import jax
+    import jax.numpy as jnp
+
+    from kubeshare_tpu import attach
+
+    attach.attach_proxy("127.0.0.1", proxy.port, "tenant", 0.5, 1.0)
+    try:
+        call, leaves = shape(jax, jnp)
+        call(0)
+        call(1)
+        gc.collect()
+        sess = proxy._sessions["tenant"]
+        hbm = []
+        for i in range(2, 8):
+            before = (sess.rpc_count, sess.exec_count,
+                      sess.inline_in_total, sess.inline_out_total)
+            call(i)
+            assert (sess.rpc_count, sess.exec_count, sess.inline_in_total,
+                    sess.inline_out_total) == (
+                before[0] + 1, before[1] + 1, before[2] + leaves,
+                before[3] + 1)
+            hbm.append(sess.hbm_used)
+        # the collected results' buffers were freed by the calls after
+        assert len(set(hbm)) == 1, hbm
+    finally:
+        attach.detach()
+
+
 def test_isolation_takes_the_real_jit_and_never_loads_attach(proxy):
     """``isolation/`` lies below ``attach.py``. With the shim attached in
     the proxy's own process, a program compiled and executed through the

@@ -147,6 +147,67 @@ def test_waiter_granted_after_the_holders_renew_is_gate(rig):
     assert rig.counters("a")["self_ms_total"] == 0.0    # 70 ms in renew
 
 
+@pytest.mark.parametrize("a_used_ms", [0.0, 300.0],
+                         ids=["holder-behind", "holder-ahead"])
+def test_a_contended_program_end_asks_the_weighted_pick(rig, a_used_ms):
+    """Where a program ends while another tenant waits, the scheduler
+    says at once who holds, the holder standing in the pick with what it
+    has used. A holder the pick prefers keeps the token (its burst); else
+    the waiter's program starts at that stamp, under the holder's
+    turn-around, and nobody idles out or comes back first."""
+    step_a, step_b = rig.tenant("a"), rig.tenant("b")
+    sess_a, sess_b = rig.proxy._session("a"), rig.proxy._session("b")
+    for _ in range(3):          # b has used 150 ms at request 0.5
+        step_b()
+    with sess_b.lock:           # and gives the token back, by hand
+        sess_b.holding = False
+    rig.proxy.scheduler.release("b", sess_b.used_ms)
+    step_a()                    # a holds the token; ends at 1200
+    sess_a.used_ms += a_used_ms     # what it used before, in this hold
+    started, finish = threading.Event(), threading.Event()
+    run = rig.proxy._run_to_completion
+
+    def in_flight(fn, args, sync_out):
+        if not started.is_set():
+            started.set()
+            assert finish.wait(10.0)
+        return run(fn, args, sync_out)
+
+    rig.proxy._run_to_completion = in_flight
+    ta = threading.Thread(target=step_a, daemon=True)
+    ta.start()
+    assert started.wait(10.0)   # a's second program is on the chip
+    tb = threading.Thread(target=step_b, daemon=True)
+    tb.start()                  # b asks while it runs, and waits
+    wait_for(lambda: "b" in rig.proxy.scheduler.waiting(),
+             "b waiting for the token")
+    finish.set()                # a's program ends at 1250
+    ta.join(10.0)
+    assert not ta.is_alive()
+    # either way a's usage went on the scheduler's books at the boundary
+    assert rig.clients["a"].usage()["used_ms"] == 2 * DEVICE_MS + a_used_ms
+    assert sess_a.used_ms == 0.0
+    if a_used_ms:
+        # a at (100 + 300) / 0.5 is ahead of b's 150 / 0.5: b's turn, and
+        # its program started where a's ended: no gap for anyone to own
+        tb.join(10.0)
+        assert not tb.is_alive()
+        assert not sess_a.holding and sess_b.holding
+        assert rig.idle("b") == (0.0, 0.0, 0.0)
+    else:
+        # a at 100 / 0.5 is behind b's 150 / 0.5: it keeps the token, with
+        # a new quantum, and b waits until a idles out
+        assert sess_a.holding and sess_a.quota_ms == 100.0
+        assert "b" in rig.proxy.scheduler.waiting()
+        rig.at(1280)
+        with sess_a.lock:       # the watchdog's act, by hand
+            sess_a.holding = False
+        rig.proxy.scheduler.release("a", sess_a.used_ms)
+        tb.join(10.0)
+        assert not tb.is_alive()
+        assert rig.idle("b") == (0.0, 30.0, 0.0)
+
+
 def test_a_wait_for_a_contended_token_is_never_charged(rig):
     """What a tenant is charged (``exec_ms_total``, and ``used_ms``, what
     its next renew reports to the gate) grows by device time only, however
@@ -188,6 +249,35 @@ def test_dlock_held_elsewhere_is_proxy(rig):
     assert rig.idle("a") == (20.0, 0.0, 25.0)
     # the 25 ms behind _dlock are a wait too
     assert rig.counters("a")["self_ms_total"] == 0.0
+
+
+def test_an_inline_inputs_put_is_the_handlers_own_time(rig, monkeypatch):
+    """A host leaf that rode in on the execute goes to the device under
+    the program's _dlock hold, BEFORE the device_start stamp: the chip's
+    idle gap counts it as the proxy's, the handler as its own, and the
+    tenant is not charged for it."""
+    c = ProxyClient("127.0.0.1", rig.proxy.port, "a", 0.5, 1.0)
+    rig.clients["a"] = c
+    w = c.put(np.ones((4,), np.float32))
+    exe = c.compile(lambda w, x: w + x, w, np.zeros((4,), np.float32))
+    exe(w, np.ones((4,), np.float32))           # ends at 1050
+    real = rig.proxy._jax.device_put
+
+    def put_on_clock(x, device=None):
+        rig.t += 7.0            # both leaves go in one call
+        return real(x, device)
+
+    with monkeypatch.context() as m:
+        m.setattr(rig.proxy._jax, "device_put", put_on_clock)
+        rig.at(1080)
+        out = exe(w, np.ones((4,), np.float32))
+    np.testing.assert_array_equal(c.get(out), np.full((4,), 2.0))
+    a = rig.counters("a")
+    assert rig.idle("a") == (30.0, 0.0, 7.0)
+    assert a["self_ms_total"] == 7.0
+    assert a["exec_count"] == 2 and a["exec_ms_total"] == 2 * DEVICE_MS
+    assert rig.proxy._session("a").used_ms == 2 * DEVICE_MS
+    assert a["inline_in_total"] == 2 and a["inline_out_total"] == 2
 
 
 def test_the_three_parts_equal_the_gap(rig):
@@ -267,7 +357,9 @@ def test_usage_grows_monotonically_and_self_time_is_never_negative(proxy):
                and not enough()):
             for name, c in obs.usage()["chip"]["sessions"].items():
                 prev = seen.get(name, {})
-                for k in _PHASE_KEYS + ("exec_ms_total", "exec_count"):
+                for k in _PHASE_KEYS + ("exec_ms_total", "exec_count",
+                                        "rpc_count", "inline_in_total",
+                                        "inline_out_total"):
                     assert c[k] >= prev.get(k, 0.0), (name, k, prev, c)
                 seen[name] = c
             time.sleep(0.005)
@@ -282,6 +374,13 @@ def test_usage_grows_monotonically_and_self_time_is_never_negative(proxy):
         for k in _PHASE_KEYS:
             assert c[k] >= 0.0, (name, k, c[k])
     assert seen["observer"]["exec_count"] == 0
+    # every request is counted, whatever the op: the observer only asked
+    # for usage; "plain" made a free of its own for each execute
+    assert seen["observer"]["rpc_count"] > 2
+    assert seen["plain"]["rpc_count"] >= 2 * seen["plain"]["exec_count"]
+    assert seen["stepper"]["inline_in_total"] == 0
+    assert (seen["stepper"]["inline_out_total"]
+            == seen["stepper"]["exec_count"])
     assert all(seen["observer"][k] == 0.0 for k in _PHASE_KEYS)
 
 

@@ -242,22 +242,29 @@ def _greedy_client(proxy, name, request, stop, used_out, nloops=20):
         exe = c.compile(burn, bx)
         while not stop.is_set():
             bx = exe(bx, donate=True)
-        used_out[name] = c.usage()["exec_ms_total"]
+        usage = c.usage()
+        used_out[name] = usage["exec_ms_total"]
+        used_out[name + ".count"] = usage["exec_count"]
 
 
-@pytest.mark.parametrize("big,small", [(0.75, 0.25), (0.5, 0.5)])
-def test_colocated_shares_follow_requests(proxy, big, small):
+@pytest.mark.parametrize("big,small,nloops", [
+    (0.75, 0.25, 20), (0.5, 0.5, 20), (0.75, 0.25, 200), (0.75, 0.25, 1000)],
+    ids=["3to1", "even", "3to1-long-programs", "3to1-programs-over-a-quantum"])
+def test_colocated_shares_follow_requests(proxy, big, small, nloops):
     """Two greedy closed-loop clients of plain executes → device-time
     shares (``exec_ms_total``) within 0.15 of the requested ones: 3:1,
     and even for equal requests (every execute renews at the gate, so
-    neither holds the chip past its quota)."""
+    neither holds the chip past its quota). The same 3:1 for programs
+    longer than the smallest quota, and longer than a whole quantum: where
+    one ends with the other tenant waiting, the weighted pick says who
+    holds, and it is not an alternation."""
     stop = threading.Event()
     used: dict = {}
     threads = [
         threading.Thread(target=_greedy_client,
-                         args=(proxy, "big", big, stop, used)),
+                         args=(proxy, "big", big, stop, used, nloops)),
         threading.Thread(target=_greedy_client,
-                         args=(proxy, "small", small, stop, used)),
+                         args=(proxy, "small", small, stop, used, nloops)),
     ]
     for t in threads:
         t.start()
@@ -267,6 +274,11 @@ def test_colocated_shares_follow_requests(proxy, big, small):
         t.join(timeout=15.0)
     share = used["big"] / (used["big"] + used["small"])
     assert abs(share - big) <= 0.15, used
+    if nloops > 20:
+        # the programs were as long as claimed
+        per_program = {n: used[n] / used[n + ".count"] for n in ("big", "small")}
+        assert min(per_program.values()) >= (MIN if nloops == 200 else BASE), \
+            per_program
 
 
 def test_limit_cap_holds_solo_client(proxy):
@@ -720,8 +732,9 @@ def test_proxy_crash_fails_client_cleanly_and_resume_works():
     host_w = float(c.get(w))           # checkpoint to host
     p1.close()                          # crash
 
+    assert float(c.get(w)) == host_w    # came with the reply: no request
     with pytest.raises((RuntimeError, OSError)):
-        c.get(w)                        # dead proxy: clean error, no hang
+        c.usage()                       # dead proxy: clean error, no hang
     c.close()
 
     p2 = ChipProxy(scheduler=TokenScheduler(WINDOW, BASE, MIN))
@@ -952,3 +965,356 @@ def test_windowed_put_get_roundtrip_many_chunks(proxy):
         np.testing.assert_array_equal(c.get(buf), arr)
         got = c.get(buf)
         assert got.flags.writeable       # user-facing array stays mutable
+
+
+# -- a call is one round trip (the "inline" feature) --------------------------
+
+def _scorer(c):
+    """Resident params + two small host leaves -> one float."""
+    params = {"w": c.put(np.linspace(0.0, 1.0, 512, dtype=np.float32))}
+
+    def score(params, toks, n):
+        live = jnp.arange(toks.shape[0]) < n
+        return jnp.sum(jnp.where(live, params["w"][toks], 0.0))
+
+    toks = np.arange(64, dtype=np.int32)
+    exe = c.compile(score, params, toks, np.int32(64))
+
+    def call(i):
+        out = exe(params, (toks + i) % 512, np.int32(8 + i))
+        value = float(c.get(out))
+        c.free_later(out)       # what a collected RemoteArray does
+        assert value == pytest.approx(float(np.sum(
+            np.linspace(0.0, 1.0, 512, dtype=np.float32)[
+                ((toks + i) % 512)[:8 + i]])), rel=1e-5)
+    return call, 2
+
+
+def _trainer(c):
+    """Resident state + two host batches -> state + loss, loss read."""
+    state = {"w": c.put(np.zeros((16,), np.float32)),
+             "count": c.put(np.float32(0.0))}
+
+    def step(state, x, y):
+        err = x @ state["w"] - y
+        loss = jnp.mean(err ** 2)
+        w = state["w"] - 0.01 * (2.0 / x.shape[0]) * (x.T @ err)
+        return {"w": w, "count": state["count"] + 1.0}, loss
+
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(32, 16)).astype(np.float32)
+    y = rng.normal(size=(32,)).astype(np.float32)
+    exe = c.compile(step, state, x, y)
+    box = {"state": state, "losses": []}
+
+    def call(i):
+        new, loss = exe(box["state"], x, y)
+        box["losses"].append(float(c.get(loss)))
+        # the count is as small as the loss and comes before it: the
+        # barrier reads the LAST of the smallest, the loss
+        assert loss.value is not None and new["count"].value is None
+        c.free_later(box["state"], loss)
+        box["state"] = new
+        if len(box["losses"]) > 1:
+            assert box["losses"][-1] < box["losses"][-2]
+    return call, 2
+
+
+@pytest.mark.parametrize("shape", [_scorer, _trainer],
+                         ids=["scorer", "trainer"])
+def test_a_call_of_a_program_is_one_request(proxy, shape):
+    """After warm-up a scorer-shaped request and a trainer-shaped step
+    each cost exactly ONE request: the small host leaves ride in the
+    execute, the value the barrier read comes back in the reply, and the
+    frees of the call before ride along. The counters say so, and only
+    grow."""
+    with connect(proxy, "t") as c:
+        assert "inline" in c.features
+        call, leaves = shape(c)
+        call(0)                                   # warm-up
+        sess = proxy._session("t")
+        hbm = []
+        for i in range(1, 6):
+            before = (sess.rpc_count, sess.exec_count,
+                      sess.inline_in_total, sess.inline_out_total)
+            call(i)
+            assert sess.rpc_count == before[0] + 1
+            assert sess.exec_count == before[1] + 1
+            assert sess.inline_in_total == before[2] + leaves
+            assert sess.inline_out_total == before[3] + 1
+            hbm.append(sess.hbm_used)
+        # nothing accumulates: the inline inputs were dropped and the
+        # frees of each call rode in on the next
+        assert len(set(hbm)) == 1, hbm
+        seen = c.usage()["chip"]["sessions"]["t"]   # flushes the last frees
+        assert seen["rpc_count"] == sess.rpc_count == before[0] + 3
+        for i in range(6, 9):
+            call(i)
+            now = c.usage()["chip"]["sessions"]["t"]
+            for k in ("rpc_count", "exec_count", "inline_in_total",
+                      "inline_out_total"):
+                assert now[k] > seen[k], k
+            seen = now
+        assert proxy.hbm_accounting()["t"]["balanced"]
+
+
+def test_a_host_leaf_over_the_limit_goes_through_put(proxy):
+    with connect(proxy, "c") as c:
+        exe_small = c.compile(lambda a: jnp.sum(a * 2.0),
+                              np.zeros(protocol.INLINE_MAX // 4, np.float32))
+        exe_big = c.compile(lambda a: jnp.sum(a * 2.0),
+                            np.zeros(protocol.INLINE_MAX // 4 + 1,
+                                     np.float32))
+        sess = proxy._session("c")
+        small = np.arange(protocol.INLINE_MAX // 4, dtype=np.float32)
+        big = np.arange(protocol.INLINE_MAX // 4 + 1, dtype=np.float32)
+        r0, i0 = sess.rpc_count, sess.inline_in_total
+        assert float(c.get(exe_small(small))) == float(np.sum(small * 2.0))
+        assert (sess.rpc_count, sess.inline_in_total) == (r0 + 1, i0 + 1)
+        # one byte-count over: put, execute, free of the upload; the
+        # result is what the inline path gives
+        assert float(c.get(exe_big(big))) == float(np.sum(big * 2.0))
+        assert (sess.rpc_count, sess.inline_in_total) == (r0 + 4, i0 + 1)
+        assert c.usage()["hbm_used"] == 8     # the two results
+        assert proxy.hbm_accounting()["c"]["balanced"]
+
+
+def test_an_inline_input_over_tpu_mem_is_refused_before_dispatch(proxy):
+    with connect(proxy, "c", memory=4096) as c:
+        w = c.put(np.ones(512, np.float32))          # 2048 of 4096
+        exe = c.compile(lambda w, x: jnp.sum(w) + jnp.sum(x), w,
+                        np.zeros(1024, np.float32))
+        sess = proxy._session("c")
+        used = sess.hbm_used
+        with pytest.raises(RuntimeError, match="HBM cap"):
+            exe(w, np.ones(1024, np.float32))        # 4096 more: over
+        assert sess.exec_count == 0                  # never reached the gate
+        assert sess.hbm_used == used and sess.inline_in_total == 0
+        assert proxy.hbm_accounting()["c"]["balanced"]
+        # one that fits is charged while it lives and gone afterwards
+        exe2 = c.compile(lambda w, x: jnp.sum(w) + jnp.sum(x), w,
+                         np.zeros(256, np.float32))
+        assert float(c.get(exe2(w, np.ones(256, np.float32)))) == 768.0
+        assert sess.hbm_used == used + 4
+        assert proxy.hbm_accounting()["c"]["balanced"]
+
+
+@pytest.mark.parametrize("fails", [False, True],
+                         ids=["success", "device-failure"])
+def test_inline_inputs_are_gone_and_refunded_when_the_call_ends(
+        proxy, monkeypatch, fails):
+    with connect(proxy, "c", memory=1 << 20) as c:
+        w = c.put(np.ones(8, np.float32))
+        exe = c.compile(lambda w, x: w + x, w, np.zeros(8, np.float32))
+        sess = proxy._session("c")
+        used, held = sess.hbm_used, set(sess.buffers)
+        charged = []
+        real = proxy._run_to_completion
+
+        def run(fn, args, sync_out):
+            charged.append(sess.hbm_used)     # inside the call
+            if fails:
+                raise RuntimeError("device fell over")
+            return real(fn, args, sync_out)
+
+        monkeypatch.setattr(proxy, "_run_to_completion", run)
+        if fails:
+            with pytest.raises(RuntimeError, match="device fell over"):
+                exe(w, np.ones(8, np.float32))
+            assert sess.hbm_used == used and set(sess.buffers) == held
+        else:
+            out = exe(w, np.ones(8, np.float32))
+            np.testing.assert_array_equal(c.get(out), np.full(8, 2.0))
+            # only the output stays: the inline input never had a handle
+            assert sess.hbm_used == used + 32
+            assert set(sess.buffers) == held | {out.handle}
+        # while the program ran, input AND output were charged
+        assert charged == [used + 32 + 32]
+        assert proxy.hbm_accounting()["c"]["balanced"]
+
+
+def test_only_the_barriers_pick_comes_back(proxy, monkeypatch):
+    """A program with many small outputs makes ONE host read, and that
+    one value is what the reply carries."""
+    from kubeshare_tpu.isolation import proxy as proxy_mod
+
+    reads = []
+
+    class CountingNumpy:
+        def __getattr__(self, name):
+            return getattr(np, name)
+
+        @staticmethod
+        def asarray(x, *a, **k):
+            if isinstance(x, jax.Array):
+                reads.append(x.shape)
+            return np.asarray(x, *a, **k)
+
+    with connect(proxy, "c") as c:
+        x = c.put(np.arange(6, dtype=np.float32))
+        exe = c.compile(lambda x: (x, x[:2], x[:1] * 2.0, x[:3], x[1:2] + 5.0),
+                        x)
+        sess = proxy._session("c")
+        monkeypatch.setattr(proxy_mod, "np", CountingNumpy())
+        outs = exe(x)
+        monkeypatch.undo()
+        assert reads == [(1,)]
+        # the smallest, and of the two that small the last
+        assert [o.value is not None for o in outs] == [
+            False, False, False, False, True]
+        assert sess.inline_out_total == 1
+        r0 = sess.rpc_count
+        np.testing.assert_array_equal(c.get(outs[4]), [6.0])   # no request
+        value = c.get(outs[4])
+        value[0] = -1.0                       # the caller's own copy
+        np.testing.assert_array_equal(c.get(outs[4]), [6.0])
+        assert sess.rpc_count == r0
+        np.testing.assert_array_equal(c.get(outs[2]), [0.0])   # a get
+        assert sess.rpc_count == r0 + 1
+
+
+@pytest.mark.parametrize("op", ["execute", "get", "put", "compile", "usage",
+                                "free", "close"])
+def test_queued_frees_ride_on_an_execute_and_any_other_op_flushes_them(
+        proxy, op):
+    c = connect(proxy, "c")
+    x = c.put(np.ones(4, np.float32))
+    exe = c.compile(lambda a: a + 1.0, x)
+    dead = [c.put(np.zeros(64, np.float32)) for _ in range(3)]
+    other = c.put(np.zeros(2, np.float32))
+    sess = proxy._session("c")
+    c.free_later(dead[0], [dead[1], {"k": dead[2]}])      # any pytree
+    assert all(b.handle in sess.buffers for b in dead)    # no request yet
+    r0, used = sess.rpc_count, sess.hbm_used
+    if op == "execute":
+        exe(x)          # applied by that call, before it is charged
+        assert sess.rpc_count == r0 + 1
+        assert sess.hbm_used == used - 3 * 256 + 16
+    elif op == "close":
+        freed = []
+        real = proxy._free_handles
+        proxy._free_handles = lambda s, h: (freed.extend(h), real(s, h))
+        c.close()
+        assert sorted(freed) == sorted(b.handle for b in dead)
+        return
+    else:
+        {"get": lambda: c.get(other), "put": lambda: c.put(np.float32(1)),
+         "compile": lambda: c.compile(lambda a: a * 2.0, x),
+         "usage": c.usage, "free": lambda: c.free(other)}[op]()
+        # a free of its own first (free: the one request carries both)
+        assert sess.rpc_count == r0 + (1 if op == "free" else 2)
+    assert not any(b.handle in sess.buffers for b in dead)
+    c.close()
+
+
+def test_an_execute_without_the_new_keys_is_served_as_before(
+        proxy, monkeypatch):
+    """A client and proxy that never agreed on "inline" (an old peer on
+    either side) take the path of before: put, execute, free, get; a bare
+    execute by handles on a session that did agree is served too."""
+    def run(c):
+        w = c.put(np.arange(4, dtype=np.float32))
+        exe = c.compile(lambda w, x, n: jnp.sum(w * x) * n, w,
+                        np.zeros(4, np.float32), np.int32(0))
+        out = exe(w, np.ones(4, np.float32), np.int32(3))
+        return exe, w, out, float(c.get(out))
+
+    with monkeypatch.context() as m:
+        m.setattr(protocol, "FEATURES", ("resume", "seq", "preempt"))
+        with connect(proxy, "old") as c:
+            assert "inline" not in c.features
+            sess = proxy._session("old")
+            exe, w, out, value = run(c)
+            assert value == 18.0 and out.value is None
+            # put, compile | put, put, execute, free | get
+            assert sess.rpc_count == 7
+            assert (sess.inline_in_total, sess.inline_out_total) == (0, 0)
+            c.free_later(out)
+            out = exe(w, np.ones(4, np.float32), np.int32(1))
+            assert sess.rpc_count == 7 + 5      # a free of its own first
+            reply, _ = c._conn.call({"op": "execute", "name": "old",
+                                     "exec_id": exe._exec_id,
+                                     "args": [w.handle, w.handle,
+                                              c.put(np.int32(2)).handle]})
+            assert set(reply) == {"ok", "handles"}
+    with connect(proxy, "new") as c:
+        sess = proxy._session("new")
+        exe, w, out, value = run(c)
+        assert value == 18.0 and sess.rpc_count == 3
+        n = c.put(np.int32(2))
+        reply, _ = c._conn.call({"op": "execute", "name": "new",
+                                 "exec_id": exe._exec_id,
+                                 "args": [w.handle, w.handle, n.handle]})
+        assert reply["ok"] and len(reply["handles"]) == 1
+        assert sess.inline_in_total == 2        # the first call's two
+
+
+@pytest.mark.parametrize("bad,match", [
+    ({"inline": [[1, "float32", [4]]]}, "left in the blob"),
+    ({"inline": []}, "1 nulls in args"),
+    ({"inline": [[0, "float32", [4]]], "blob": 16}, "no null in args"),
+    ({"inline": [[1, "float32", [5]]], "blob": 20}, "program expects"),
+    ({"inline": [[1, "float32", [4]]], "blob": 20}, "execute blob holds"),
+    ({"inline": [[1, "float32", [20000]]], "blob": 80000}, "at most 65536"),
+], ids=["short-blob", "null-unfilled", "not-a-null", "wrong-shape",
+        "long-blob", "over-the-limit"])
+def test_a_malformed_inline_input_is_a_clean_error(proxy, bad, match):
+    with connect(proxy, "c") as c:
+        w = c.put(np.ones(4, np.float32))
+        exe = c.compile(lambda w, x: w + x, w, np.zeros(4, np.float32))
+        sess = proxy._session("c")
+        used = sess.hbm_used
+        msg = {"op": "execute", "name": "c", "exec_id": exe._exec_id,
+               "args": [w.handle, None], "inline": bad["inline"]}
+        blob = bytes(bad["blob"]) if "blob" in bad else None
+        with pytest.raises(RuntimeError, match=match):
+            c._conn.call(msg, blob=blob)
+        assert sess.exec_count == 0 and sess.hbm_used == used
+        # the stream is in step and the session serves on
+        np.testing.assert_array_equal(
+            c.get(exe(w, np.ones(4, np.float32))), np.full(4, 2.0))
+
+
+def test_frees_queued_from_other_threads_are_each_applied_once(proxy):
+    """``free_later`` is what ``RemoteArray.__del__`` calls, on whatever
+    thread the collector runs: handles queued while the owner's thread
+    keeps executing are neither lost nor sent twice."""
+    import sys
+
+    with connect(proxy, "c") as c:
+        x = c.put(np.ones(4, np.float32))
+        exe = c.compile(lambda a: a + 1.0, x)
+        dead = [c.put(np.zeros(8, np.float32)) for _ in range(400)]
+        sess = proxy._session("c")
+        freed = []
+        real = proxy._free_handles
+        proxy._free_handles = lambda s, h: (freed.extend(h), real(s, h))
+        go, threads = threading.Event(), []
+        for k in range(8):
+            def drop(mine=dead[k::8]):
+                go.wait(5.0)
+                for buf in mine:
+                    c.free_later(buf)
+            threads.append(threading.Thread(target=drop, daemon=True))
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for t in threads:
+                t.start()
+            go.set()
+            deadline = time.monotonic() + 20.0
+            while (any(t.is_alive() for t in threads)
+                   and time.monotonic() < deadline):
+                c.free_later(exe(x))
+            for t in threads:
+                t.join(5.0)
+                assert not t.is_alive()
+        finally:
+            sys.setswitchinterval(interval)
+        c.free_later(exe(x))
+        c.usage()                               # flushes what is left
+        assert sorted(h for h in freed if h in {b.handle for b in dead}) \
+            == sorted(b.handle for b in dead)
+        assert len(freed) == len(set(freed))
+        assert set(sess.buffers) == {x.handle}
+        assert proxy.hbm_accounting()["c"]["balanced"]

@@ -13,7 +13,10 @@ import time
 import numpy as np
 import pytest
 
+import jax.numpy as jnp
+
 from kubeshare_tpu.isolation import protocol
+from kubeshare_tpu.isolation import proxy as proxy_mod
 from kubeshare_tpu.isolation.client import ProxyClient
 from kubeshare_tpu.isolation.proxy import ChipProxy
 from kubeshare_tpu.isolation.tokensched import TokenScheduler
@@ -171,6 +174,47 @@ def test_lost_reply_recovered_via_request_timeout(proxy):
     assert c.usage()["hbm_used"] == x.nbytes   # seq 2: reply dropped
     faults.uninstall()
     np.testing.assert_array_equal(c.get(bx), x)
+    c.close()
+
+
+@pytest.mark.parametrize("how", ["reply-lost", "connection-killed"])
+def test_replayed_inline_execute_runs_once_and_gets_the_same_reply(
+        proxy, how):
+    """An execute is not idempotent, and with "inline" its reply carries
+    a value: the reply stays blobless so the replay cache keeps it whole.
+    The replayed request (its blob of inline inputs sent again) is
+    answered with the SAME handles and the SAME value, and the program
+    ran once."""
+    pol = ReconnectPolicy(max_attempts=4, base_delay_s=0.02,
+                          max_delay_s=0.1, dial_timeout_s=1.0,
+                          request_timeout_s=0.3, seed=5)
+    c = connect(proxy, "once", policy=pol, fault_tag="once")
+    w = c.put(np.arange(8, dtype=np.float32))
+    exe = c.compile(lambda w, x, n: jnp.sum(w * x) * n, w,
+                    np.zeros(8, np.float32), np.int32(0))
+    sess = proxy._session("once")
+    served0 = proxy_mod._REPLAY_SERVED.value()
+    if how == "reply-lost":
+        spec = faults.FaultSpec(
+            drop_reply_seq=c._conn._conn._next_seq + 1)
+    else:
+        spec = faults.FaultSpec(kill_conn_after_frames=1,
+                                kill_conn_tag="once")
+    faults.install(faults.Injector(spec))
+    out = exe(w, np.full(8, 2.0, np.float32), np.int32(3))
+    faults.uninstall()
+    if how == "reply-lost":     # (a killed connection MAY die unserved)
+        assert proxy_mod._REPLAY_SERVED.value() == served0 + 1
+    assert sess.exec_count == 1 and sess.inline_in_total == 2
+    assert sess.inline_out_total == 1
+    # what the replay was answered with is the first reply, value and all
+    assert out.handle in sess.buffers and len(sess.buffers) == 2
+    assert out.value is not None
+    r0 = sess.rpc_count
+    assert float(c.get(out)) == 2.0 * 28.0 * 3.0 and sess.rpc_count == r0
+    np.testing.assert_array_equal(
+        np.asarray(sess.buffers[out.handle]), c.get(out))
+    assert proxy.hbm_accounting()["once"]["balanced"]
     c.close()
 
 

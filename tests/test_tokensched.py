@@ -79,6 +79,67 @@ def test_stride_shares_converge_to_requests(core):
     assert 0.70 <= share <= 0.80
 
 
+def _hold(core, name, now, ms):
+    """*name* asks, is granted at *now* and releases *ms* later."""
+    core.request_token(name)
+    granted = core.poll(now)
+    assert isinstance(granted, tuple) and granted[0] == name, granted
+    core.release_token(name, ms, now + ms)
+    return now + ms
+
+
+@pytest.mark.parametrize("first", ["short", "long"])
+def test_virtual_time_forgets_beyond_one_quantum(core, first):
+    """Usage from before the other client first asked decides no pick for
+    longer than one quantum. ``short`` (request 0.3) warms up alone for 2 s
+    of device time, or ``long`` (0.7) does: either way, once both keep
+    asking, the one that stayed away is owed ``BASE`` ms of device time at
+    most, and after that the split is the requests'."""
+    req = {"short": 0.3, "long": 0.7}
+    for name, request in req.items():
+        core.add_client(name, request, 1.0)
+    other = "long" if first == "short" else "short"
+    now = 0.0
+    for _ in range(40):                     # 2,000 ms alone, half the time
+        now = _hold(core, first, now, 50.0) + 50.0
+    now += 20_000.0                         # out of every window
+    used = {"short": 0.0, "long": 0.0}
+    for i in range(400):
+        core.request_token("short")
+        core.request_token("long")
+        name, _ = core.poll(now)
+        now += 10.0
+        core.release_token(name, 10.0, now)
+        used[name] += 10.0
+        if used[first] == 0.0:
+            # the returning client's head start: one quantum and its side
+            # of the 50 ms hold the first made last, not of the 2 s
+            assert used[other] <= BASE + 50.0 * req[other] / req[first] \
+                + 10.0, (i, used)
+    share = used["long"] / (used["long"] + used["short"])
+    assert 0.66 <= share <= 0.74, used
+
+
+def test_a_client_that_kept_asking_is_never_lifted(core):
+    """The bound is on what a RETURNING client is owed: two clients that
+    keep asking, one of them in steps three times the quantum, get their
+    requests' split exactly as before."""
+    core.add_client("big", 0.75, 1.0)
+    core.add_client("small", 0.25, 1.0)
+    now, used = 0.0, {"big": 0.0, "small": 0.0}
+    for _ in range(3000):
+        core.request_token("big")
+        core.request_token("small")
+        name, _ = core.poll(now)
+        step = 3.0 * BASE if name == "small" else 10.0
+        now += step
+        # out of the window at once: the cap is not what is tested here
+        core.release_token(name, step, now - 2 * WINDOW)
+        used[name] += step
+    share = used["big"] / (used["big"] + used["small"])
+    assert 0.74 <= share <= 0.76, used
+
+
 def test_limit_cap_enforced(core):
     """limit=0.3 client alone on the chip is held to ≤30% of the window."""
     core.add_client("capped", 0.3, 0.3)
@@ -250,6 +311,95 @@ def test_renew_preserves_stride_shares():
         t.join(timeout=30.0)
     share = used["big"] / (used["big"] + used["small"])
     assert 0.62 <= share <= 0.78, share
+
+
+@pytest.mark.parametrize("big,small", [(0.75, 0.25), (0.5, 0.5)])
+def test_renew_or_yield_keeps_shares_at_every_program_boundary(big, small):
+    """Two closed-loop holders of LONG programs (each longer than the
+    quantum) that never exhaust a quota at the gate: at every program's end
+    with the other waiting, ``renew_or_yield`` makes the weighted pick, so
+    the shares are the requests', not an alternation."""
+    sched = TokenScheduler(10_000.0, 20.0, 2.0)
+    sched.add_client("big", big, 1.0)
+    sched.add_client("small", small, 1.0)
+    used = {"big": 0.0, "small": 0.0}
+    stop = threading.Event()
+
+    def worker(name):
+        while not stop.is_set():
+            sched.acquire(name, timeout=10.0)
+            holding = True
+            while holding and not stop.is_set():
+                time.sleep(0.005)           # a 25 ms program, in 5 ms
+                used[name] += 25.0
+                if sched.contended(name):
+                    holding = sched.renew_or_yield(name, 25.0) is not None
+                else:                       # nobody asked: the hold goes on
+                    sched.release(name, 25.0)
+                    holding = False
+            if holding:
+                sched.release(name, 0.0)
+
+    threads = [threading.Thread(target=worker, args=(n,), daemon=True)
+               for n in used]
+    for t in threads:
+        t.start()
+    time.sleep(1.5)
+    stop.set()
+    for t in threads:
+        t.join(timeout=15.0)
+    assert sum(used.values()) >= 2_000.0, used
+    share = used["big"] / sum(used.values())
+    assert abs(share - big) <= 0.05, used
+
+
+def test_renew_or_yield_outcomes():
+    """Kept: a new quantum, the usage on the books, the waiter still
+    waiting. Yielded: None, the waiter granted, the ex-holder's request
+    withdrawn (it is not asking: it comes back through acquire)."""
+    sched = TokenScheduler(WINDOW, BASE, MIN)
+    sched.add_client("a", 0.5, 1.0)
+    sched.add_client("b", 0.5, 1.0)
+    assert sched.acquire("a", timeout=5.0) == BASE
+    assert not sched.contended("a")
+    got: list = []
+    tb = threading.Thread(
+        target=lambda: got.append(sched.acquire("b", timeout=10.0)),
+        daemon=True)
+    tb.start()
+    deadline = time.monotonic() + 5.0
+    while not sched.contended("a"):
+        assert time.monotonic() < deadline
+        time.sleep(0.002)
+    assert not sched.contended("b")         # nobody else waits for b
+    # b (vtime 0) is behind a's 30 / 0.5: the token goes over
+    assert sched.renew_or_yield("a", 30.0) is None
+    tb.join(5.0)
+    assert got == [BASE] and sched.core.holder() == "b"
+    assert sched.window_usage("a") == pytest.approx(30.0)
+    assert sched.waiting() == []
+    # a asks again and waits; b at 20 / 0.5 stays behind a's 60: it keeps
+    ta = threading.Thread(
+        target=lambda: got.append(sched.acquire("a", timeout=10.0)),
+        daemon=True)
+    ta.start()
+    while not sched.contended("b"):
+        assert time.monotonic() < deadline
+        time.sleep(0.002)
+    assert sched.renew_or_yield("b", 20.0) == BASE
+    assert sched.core.holder() == "b" and sched.waiting() == ["a"]
+    assert sched.window_usage("b") == pytest.approx(20.0)
+    # at 20 + 50 it is ahead: a's turn
+    assert sched.renew_or_yield("b", 50.0) is None
+    ta.join(5.0)
+    assert got == [BASE, BASE] and sched.core.holder() == "a"
+    sched.release("a", 0.0)
+    # a lone holder at its window cap loses the token and is not left asking
+    sched.add_client("capped", 0.1, 0.1)
+    sched.acquire("capped", timeout=5.0)
+    assert sched.renew_or_yield("capped", 95.0) is None
+    assert sched.core.holder() is None
+    assert sched.core.poll(sched.now_ms()) == float("inf")
 
 
 def test_concurrent_waiters_same_name_fifo():
