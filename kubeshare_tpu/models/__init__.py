@@ -4,7 +4,11 @@ One module per reference eval workload (``/root/reference/test/**``):
 ``mnist`` (north-star benchmark), ``cifar10``, ``lstm``, ``resnet``,
 ``vgg`` — plus ``transformer``, the long-context causal-LM family the
 TPU build adds (dense or mixture-of-experts FFN, pluggable attention:
-dense / Pallas flash / sequence-parallel ring). Each exposes
+dense / Pallas flash / sequence-parallel ring), and ``lfm2``, a hybrid
+decoder driven by one configuration object and a per-layer list of
+block kinds (gated short convolution / grouped-query attention, dense
+gated MLP / top-k routed experts of which a stated share is held).
+Each exposes
 ``init(key)``, ``loss_fn(params, batch)``,
 ``batch_fn(key)`` and a ``python -m kubeshare_tpu.models.<name> --steps N``
 CLI; ``common.run_training`` provides the timed loop with the isolation
@@ -12,7 +16,7 @@ gate hook.
 """
 
 MODEL_NAMES = ("mnist", "cifar10", "lstm", "resnet", "vgg", "transformer",
-               "tinymlp")
+               "tinymlp", "lfm2")
 
 
 def get_model(name: str):

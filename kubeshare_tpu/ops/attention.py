@@ -176,3 +176,43 @@ def mha_apply(params: dict, x: jax.Array, heads: int, causal: bool = True,
         o = attn_fn(q, k, v)
     o = o.reshape(b, s, dim).astype(w_out.dtype)
     return o @ w_out
+
+
+# --- grouped-query attention with per-head q/k norm ---------------------------
+
+def gqa_init(key, dim: int, heads: int, kv_heads: int) -> dict:
+    """:func:`mha_init`'s fused projection plus the q/k norm gains."""
+    hd = dim // heads
+    return dict(mha_init(key, dim, heads, kv_heads=kv_heads),
+                q_norm=jnp.ones((hd,)), k_norm=jnp.ones((hd,)))
+
+
+def gqa_apply(params: dict, x: jax.Array, heads: int, attn_fn=None,
+              dtype=None, rope_base: float = 10000.0,
+              eps: float = 1e-5) -> jax.Array:
+    """Causal self-attention as the LFM2 / Qwen3 style decoders order it:
+    project, RMS-norm q and k per head (``q_norm`` / ``k_norm``), THEN
+    rotate (:func:`rope` at ``rope_base``), attend, project out. The norm
+    sits before the rotation: a rotation keeps a pair's norm, a gain per
+    feature after it would not commute with it. kv heads are read off the
+    ``qkv`` weight as :func:`mha_apply` does."""
+    b, s, dim = x.shape
+    hd = dim // heads
+    w_qkv, w_out = params["qkv"], params["out"]
+    kvd = (w_qkv.shape[-1] - dim) // 2
+    if dtype is not None:
+        x, w_qkv, w_out = (a.astype(dtype) for a in (x, w_qkv, w_out))
+    qkv = x @ w_qkv
+    q = qkv[..., :dim].reshape(b, s, heads, hd)
+    k = qkv[..., dim:dim + kvd].reshape(b, s, kvd // hd, hd)
+    v = qkv[..., dim + kvd:].reshape(b, s, kvd // hd, hd)
+    from .layers import rmsnorm_apply   # here over each head's features
+    q = rope(rmsnorm_apply({"scale": params["q_norm"]}, q, eps),
+             base=rope_base)
+    k = rope(rmsnorm_apply({"scale": params["k_norm"]}, k, eps),
+             base=rope_base)
+    if attn_fn is None:
+        o = dot_product_attention(q, k, v, causal=True)
+    else:
+        o = attn_fn(q, k, v)
+    return o.reshape(b, s, dim).astype(w_out.dtype) @ w_out

@@ -138,3 +138,36 @@ def lstm_apply(params: dict, xs: jax.Array, dtype=None) -> jax.Array:
 
     (_, _), hs = lax.scan(step, (h0, c0), jnp.swapaxes(xs, 0, 1))
     return jnp.swapaxes(hs, 0, 1)
+
+
+# --- RMSNorm and the gated MLP (the block of today's open decoders) ----------
+
+def rmsnorm_init(dim: int) -> dict:
+    return {"scale": jnp.ones((dim,))}
+
+
+def rmsnorm_apply(params: dict, x: jax.Array, eps: float = 1e-5) -> jax.Array:
+    """``x / sqrt(mean(x^2) + eps) * scale`` over the trailing axis, in
+    fp32 whatever the input's dtype, cast back to it."""
+    xf = x.astype(jnp.float32)
+    y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True) + eps)
+    return (y * params["scale"]).astype(x.dtype)
+
+
+def gated_mlp_init(key, dim: int, hidden: int) -> dict:
+    """``w1`` gates, ``w3`` carries, ``w2`` projects back; no bias."""
+    k1, k3, k2 = jax.random.split(key, 3)
+    s_in, s_hid = math.sqrt(1.0 / dim), math.sqrt(1.0 / hidden)
+    return {"w1": _uniform(k1, (dim, hidden), s_in),
+            "w3": _uniform(k3, (dim, hidden), s_in),
+            "w2": _uniform(k2, (hidden, dim), s_hid)}
+
+
+def gated_mlp_apply(params: dict, x: jax.Array, dtype=None) -> jax.Array:
+    """``(silu(x w1) * (x w3)) w2``: the gate is fp32 between the matmuls
+    (XLA fuses it into their epilogue), the operands ``dtype``."""
+    w1, w3, w2 = params["w1"], params["w3"], params["w2"]
+    if dtype is not None:
+        x, w1, w3, w2 = (a.astype(dtype) for a in (x, w1, w3, w2))
+    gate = jax.nn.silu((x @ w1).astype(jnp.float32))
+    return (gate * (x @ w3).astype(jnp.float32)).astype(x.dtype) @ w2

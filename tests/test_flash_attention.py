@@ -277,11 +277,37 @@ def test_flash_bf16_operands_match_dense_on_the_same_values(case):
 
 
 # -- the tile rule -------------------------------------------------------------
+@pytest.mark.parametrize("what", ["forward", "gradients"])
+def test_flash_32_query_on_8_kv_heads_across_a_tile_boundary(what):
+    """The LFM2 cell's head layout (32 query heads on 8 kv heads, head 64)
+    with the sequence cut in two tiles each way: a query tile reads its
+    group's k/v row across the boundary, and dK/dV sum the group's four
+    heads times two q tiles in one accumulator."""
+    keys = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(keys[0], (1, 64, 32, 64), jnp.float32)
+    k, v = (jax.random.normal(kk, (1, 64, 8, 64), jnp.float32)
+            for kk in keys[1:])
+    if what == "forward":
+        out = flash_attention(q, k, v, block_q=32, block_k=32)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(dot_product_attention(q, k, v)),
+            atol=1e-5, rtol=1e-5)
+        return
+    g_ref = jax.grad(lambda *a: (dot_product_attention(*a) ** 2).sum(),
+                     argnums=(0, 1, 2))(q, k, v)
+    g_out = jax.grad(lambda *a: (flash_attention(
+        *a, block_q=32, block_k=32) ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g_out, g_ref):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-4, rtol=2e-4)
+
 
 #: (sequence, head_dim, operand dtype) -> the tile the rule gives
 TILE_TABLE = [(128, 64, jnp.bfloat16, 128), (256, 64, jnp.bfloat16, 256),
               (512, 64, jnp.bfloat16, 512), (1024, 64, jnp.bfloat16, 1024),
               (1024, 128, jnp.bfloat16, 1024), (8192, 64, jnp.bfloat16, 1024),
+              (4096, 64, jnp.bfloat16, 1024),   # the LFM2 cell's sequence
               (1024, 64, jnp.float32, 1024), (96, 64, jnp.bfloat16, 96),
               (3000, 64, jnp.float32, 1000),    # no multiple of 128 divides
               (2048, 512, jnp.bfloat16, 512),   # the budget halves the target

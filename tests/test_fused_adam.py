@@ -38,6 +38,12 @@ CASES = [
     ((2100, 256), jnp.float32, (3, 1)),       # ragged last block of rows
     ((3,), jnp.float32, (1,)),                # smaller than one tile
     ((2100, 256), jnp.bfloat16, (3, 1)),      # 16 sublanes a tile
+    # rank 3, as a stack of experts' matrices: the leading axes collapse
+    # into rows (a bitcast on the chip where the last but one is whole
+    # tiles of rows), ragged last block of rows
+    ((8, 520, 256), jnp.float32, (5, 1)),
+    ((4, 16, 384), jnp.float32, (1, 1)),
+    ((8, 130, 256), jnp.bfloat16, (2, 1)),
 ]
 
 

@@ -74,7 +74,9 @@ def _kernel_count(compiled) -> int:
 FLASH_CASES = ([(*case, jnp.float32) for case in PLAN.kernel_cases]
                + [(8, 1024, 12, 12, 64, jnp.bfloat16),
                   (2, 1024, 16, 16, 64, jnp.bfloat16),
-                  (1, 128, 16, 16, 64, jnp.bfloat16)])
+                  (1, 128, 16, 16, 64, jnp.bfloat16),
+                  # the LFM2 trainer's: 4 query heads a kv head, 4 x 4 tiles
+                  (2, 4096, 32, 8, 64, jnp.bfloat16)])
 
 
 @pytest.mark.parametrize("window", [None, PLAN.kernel_window])
@@ -120,8 +122,18 @@ CELL_LEAVES = [(768, 768), (768, 2304), (768, 3072), (3072, 768),
                (4096, 1024), (50257, 1024), (1024, 50257), (1024,), (4096,)]
 
 
+#: One leaf of every shape the LFM2 trainer holds (LFM2-24B-A2B's share as
+#: `models/lfm2.py` builds it): the experts' stacks of rank 3 at 100 MB
+#: each, the fused q/k/v, the dense MLP, the taps, the router and its bias.
+LFM2_LEAVES = [(8, 2048, 1536), (8, 1536, 2048), (2048, 6144), (2048, 2048),
+               (2048, 3072), (2048, 11776), (11776, 2048), (3, 2048),
+               (2048, 64), (64,), (2048,), (8192, 2048)]
+
+
+@pytest.mark.parametrize("leaves", [CELL_LEAVES, LFM2_LEAVES],
+                         ids=["gpt2", "lfm2"])
 def test_fused_adam_moves_each_leaf_once_in_a_step_that_donates_nothing(
-        one_chip):
+        one_chip, leaves):
     """Through the optax wrapper, as every tenant runs it: one kernel a
     leaf, and around it nothing that copies a leaf. A leaf the kernel's
     view of which is not how the chip stores it shows as a ``copy`` or a
@@ -132,7 +144,7 @@ def test_fused_adam_moves_each_leaf_once_in_a_step_that_donates_nothing(
 
     import optax
 
-    params = {f"leaf{i:02d}": one_chip(s) for i, s in enumerate(CELL_LEAVES)}
+    params = {f"leaf{i:02d}": one_chip(s) for i, s in enumerate(leaves)}
     optimizer = fad.fused_adam(1e-3)
     state = jax.tree_util.tree_map(
         lambda s: one_chip(s.shape, s.dtype),
@@ -143,7 +155,7 @@ def test_fused_adam_moves_each_leaf_once_in_a_step_that_donates_nothing(
         return optax.apply_updates(params, updates), state
 
     compiled = jax.jit(step).lower(params, state, params).compile()
-    assert _kernel_names(compiled) == ["fused_adam"] * len(CELL_LEAVES)
+    assert _kernel_names(compiled) == ["fused_adam"] * len(leaves)
     text = compiled.as_text()
     moved = [(kind, dims) for dims, kind in re.findall(
         r" = \w+\[([\d,]+)\]\S* (copy|pad|slice|transpose|reshape)\(",
@@ -157,7 +169,7 @@ def test_fused_adam_moves_each_leaf_once_in_a_step_that_donates_nothing(
     assert moved == []
 
 
-@pytest.mark.parametrize("shape", CELL_LEAVES)
+@pytest.mark.parametrize("shape", CELL_LEAVES + LFM2_LEAVES)
 def test_fused_adam_block_is_large_and_inside_its_vmem_limit(shape):
     """The block the rule gives each of the cells' leaves: what the
     pipeline holds of it (seven operands, double-buffered, padded to
@@ -198,6 +210,37 @@ def test_the_four_kernels_are_told_apart_by_name_in_the_tpu_program(one_chip):
         lambda p, g, m, v: fad.adam_update(p, g, m, v, step=3)
     ).lower(x, x, x, x).compile()
     assert _kernel_names(compiled) == ["fused_adam"]
+
+
+def test_grouped_expert_products_compile_for_v5e_at_published_widths(
+        one_chip):
+    """The LFM2 expert layer's share at its published widths (8 of 64
+    experts of 2048 x 1536, top 4, 8,192 tokens: a sorted buffer of
+    32,768 rows), forward and backward: three grouped products forward,
+    three back for the rows' gradients, three transposed ones for the
+    matrices', every one a Mosaic kernel at the tiles the shapes give; the
+    rows move by gathers, never by a scatter of rows."""
+    import re
+
+    moe = importlib.import_module("kubeshare_tpu.ops.moe")
+    params = jax.tree_util.tree_map(
+        lambda s: one_chip(s.shape, s.dtype),
+        jax.eval_shape(lambda k: moe.topk_moe_init(k, 2048, 1536, 64, 8),
+                       jax.random.PRNGKey(0)))
+    x = one_chip((2, 4096, 2048), jnp.bfloat16)
+
+    def loss(p, x):
+        y = moe.topk_moe_apply(p, x, 4, 0, dtype=jnp.bfloat16)
+        return (y.astype(jnp.float32) ** 2).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile()
+    assert _kernel_names(compiled) == ["gmm"] * 6 + ["tgmm"] * 3
+    text = compiled.as_text()
+    scattered = [int(np.prod([int(d) for d in dims.split(",")]))
+                 for dims in re.findall(r" = \w+\[([\d,]+)\]\S* scatter\(",
+                                        text)]
+    assert all(n < 1024 for n in scattered), scattered
 
 
 # -- what a proxy-attached pod ships ---------------------------------------
