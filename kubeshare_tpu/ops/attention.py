@@ -59,8 +59,10 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     ``kv_heads`` may divide ``heads`` (grouped-query / multi-query
     attention — each group of heads//kv_heads query heads shares one
     k/v head); this reference expands k/v for clarity, the Pallas
-    kernel (:mod:`.flash_attention`) instead maps the group in its
-    block index arithmetic so the smaller k/v never grows in HBM.
+    kernel (:mod:`.flash_attention`) instead reads this same layout as
+    it lies (lane blocks of (batch, seq, heads·head_dim), two heads of
+    64 a block) and maps the group in its block index arithmetic, so the
+    smaller k/v never grows in HBM and nothing is transposed.
     ``window`` = sliding-window (local) attention: with ``causal``,
     query i sees keys in ``(i - window, i]`` — the Mistral-style band.
     The ring implementation is validated against this function.

@@ -104,6 +104,56 @@ def test_flash_dq_and_dkv_compile_for_v5e(one_chip, b, s, h, hk, d, dtype,
     assert _kernel_count(compiled) == 2     # the dQ pass and the dK/dV pass
 
 
+#: The cells' attention calls that must ride on lane blocks of the model's
+#: own array: the two GPT-2 trainers', the scorer's shortest bucket, and the
+#: LFM2 trainer's 32-on-8 call (which took them by measurement, PERF.md).
+LANE_CALLS = [(8, 1024, 12, 12, 64), (2, 1024, 16, 16, 64),
+              (1, 128, 16, 16, 64), (2, 4096, 32, 8, 64)]
+
+
+@pytest.mark.parametrize("b,s,h,hk,d", LANE_CALLS)
+def test_flash_moves_no_operand_around_its_three_kernels(one_chip, b, s, h,
+                                                         hk, d):
+    """Forward and backward through the public entry, bfloat16 as the cells
+    run it: the compiled program holds the three Pallas calls the plan
+    names and NOTHING that transposes or copies an array of the operands'
+    size (what ``_fold`` / ``_unfold``, the cast of dO and D's relayout
+    were), and the VMEM the plan asked for is what the rule counted, which
+    Mosaic granted (or the compile above would have refused)."""
+    import re
+
+    # as a model hands them over: the heads split off a (batch, seq,
+    # heads·head_dim) activation by a reshape, and merged back by one (a
+    # rank-4 ENTRY parameter would get a layout of XLA's own choosing)
+    q = one_chip((b, s, h * d), jnp.bfloat16)
+    kv = one_chip((b, s, hk * d), jnp.bfloat16)
+    w = one_chip((b, s, h * d))
+
+    def loss(q, k, v, w):
+        o = fa.flash_attention(q.reshape(b, s, h, d), k.reshape(b, s, hk, d),
+                               v.reshape(b, s, hk, d), causal=True)
+        return (o.reshape(b, s, h * d) * w).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv, w).compile()
+    assert _kernel_names(compiled) == ["flash_dkv", "flash_dq", "flash_fwd"]
+    text = compiled.as_text()
+    moved = [(kind, dims) for dims, kind in re.findall(
+        r" = \w+\[([\d,]+)\]\S* (copy|transpose)\(",
+        text[text.index("ENTRY"):])
+        if np.prod([int(n) for n in dims.split(",")]) >= b * s * hk * d]
+    assert moved == []
+
+    plan = fa._blocks(s, s, d, jnp.bfloat16, None, None, True, None, h, hk)
+    assert (plan.addressing, plan.heads, plan.chunk) == (
+        "lanes", 2, min(s, fa.CHUNK_TARGET))
+    need = fa._tile_vmem_bytes(
+        plan.block_q, plan.block_k,
+        plan.chunk if s == plan.block_q else plan.block_q, 128, plan.heads, 2)
+    assert need <= fa.VMEM_BUDGET
+    assert plan.vmem_limit == (None if need <= 16 * 2 ** 20 else need)
+
+
 @pytest.mark.parametrize("shape", PLAN.adam_sizes)
 def test_fused_adam_compiles_for_v5e(one_chip, shape):
     x = one_chip(tuple(np.atleast_1d(shape)))
