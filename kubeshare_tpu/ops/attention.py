@@ -182,22 +182,36 @@ def mha_apply(params: dict, x: jax.Array, heads: int, causal: bool = True,
 
 # --- grouped-query attention with per-head q/k norm ---------------------------
 
-def gqa_init(key, dim: int, heads: int, kv_heads: int) -> dict:
-    """:func:`mha_init`'s fused projection plus the q/k norm gains."""
+def gqa_init(key, dim: int, heads: int, kv_heads: int,
+             gated: bool = False) -> dict:
+    """:func:`mha_init`'s fused projection plus the q/k norm gains;
+    ``gated`` adds the (dim, dim) projection of a sigmoid output gate."""
     hd = dim // heads
-    return dict(mha_init(key, dim, heads, kv_heads=kv_heads),
-                q_norm=jnp.ones((hd,)), k_norm=jnp.ones((hd,)))
+    params = dict(mha_init(key, dim, heads, kv_heads=kv_heads),
+                  q_norm=jnp.ones((hd,)), k_norm=jnp.ones((hd,)))
+    if gated:
+        scale = math.sqrt(1.0 / dim)
+        params["gate"] = jax.random.uniform(
+            jax.random.fold_in(key, 1), (dim, dim), jnp.float32, -scale,
+            scale)
+    return params
 
 
 def gqa_apply(params: dict, x: jax.Array, heads: int, attn_fn=None,
               dtype=None, rope_base: float = 10000.0,
-              eps: float = 1e-5) -> jax.Array:
+              eps: float = 1e-5, use_rope: bool = True,
+              gated: bool = False) -> jax.Array:
     """Causal self-attention as the LFM2 / Qwen3 style decoders order it:
     project, RMS-norm q and k per head (``q_norm`` / ``k_norm``), THEN
     rotate (:func:`rope` at ``rope_base``), attend, project out. The norm
     sits before the rotation: a rotation keeps a pair's norm, a gain per
     feature after it would not commute with it. kv heads are read off the
-    ``qkv`` weight as :func:`mha_apply` does."""
+    ``qkv`` weight as :func:`mha_apply` does.
+
+    ``use_rope=False`` leaves q and k unrotated (a layer that takes its
+    positions from elsewhere in the model); ``gated`` multiplies the
+    attention's output by ``sigmoid(x @ params["gate"])`` before the
+    output projection."""
     b, s, dim = x.shape
     hd = dim // heads
     w_qkv, w_out = params["qkv"], params["out"]
@@ -209,12 +223,15 @@ def gqa_apply(params: dict, x: jax.Array, heads: int, attn_fn=None,
     k = qkv[..., dim:dim + kvd].reshape(b, s, kvd // hd, hd)
     v = qkv[..., dim + kvd:].reshape(b, s, kvd // hd, hd)
     from .layers import rmsnorm_apply   # here over each head's features
-    q = rope(rmsnorm_apply({"scale": params["q_norm"]}, q, eps),
-             base=rope_base)
-    k = rope(rmsnorm_apply({"scale": params["k_norm"]}, k, eps),
-             base=rope_base)
+    turn = (lambda a: rope(a, base=rope_base)) if use_rope else (lambda a: a)
+    q = turn(rmsnorm_apply({"scale": params["q_norm"]}, q, eps))
+    k = turn(rmsnorm_apply({"scale": params["k_norm"]}, k, eps))
     if attn_fn is None:
         o = dot_product_attention(q, k, v, causal=True)
     else:
         o = attn_fn(q, k, v)
-    return o.reshape(b, s, dim).astype(w_out.dtype) @ w_out
+    o = o.reshape(b, s, dim)
+    if gated:
+        gate = x @ params["gate"].astype(x.dtype)
+        o = o * jax.nn.sigmoid(gate.astype(jnp.float32))
+    return o.astype(w_out.dtype) @ w_out

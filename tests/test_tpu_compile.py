@@ -326,3 +326,61 @@ def test_cpu_traced_export_for_tpu_carries_the_compiled_adam_kernel():
     assert _exported_for("tpu", step, x, x, x, x).count(
         "tpu_custom_call") == 1
     assert "tpu_custom_call" not in _exported_for("cpu", step, x, x, x, x)
+
+
+# -- the long-context scorer's kernels (MiniCPM-SALA's widths, PR 34) ----------
+
+la = importlib.import_module("kubeshare_tpu.ops.linear_attention")
+spa = importlib.import_module("kubeshare_tpu.ops.sparse_attention")
+
+
+@pytest.mark.parametrize("s", [128, 1024, 32768])
+def test_lightning_scan_compiles_for_v5e(one_chip, s):
+    """32 heads of 128 lanes, bfloat16, at a chat bucket and at the longest
+    document: a head is a lane block of the model's own array (nothing is
+    copied around the one kernel), the rates ride in scalar memory."""
+    x = one_chip((1, s, 32, 128), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, k, v, lam: la.lightning_attention(q, k, v, lam)
+    ).lower(x, x, x, one_chip((32,))).compile()
+    assert _kernel_names(compiled) == ["lightning_scan"]
+    assert " transpose(" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("s", [16384, 32768])
+def test_attention_over_chosen_blocks_compiles_for_v5e(one_chip, s):
+    """The two buckets past ``dense_len``: 16 query heads a kv head share a
+    step's mask; the VMEM the call asks for is granted."""
+    q = one_chip((1, s, 32, 128), jnp.bfloat16)
+    kv = one_chip((1, s, 2, 128), jnp.bfloat16)
+    bits = one_chip((1, 2, s, s // 64 // 32), jnp.int32)
+    compiled = jax.jit(
+        lambda q, k, v, b: spa.chosen_blocks_attention(q, k, v, b, 64)
+    ).lower(q, kv, kv, bits).compile()
+    assert _kernel_names(compiled) == ["chosen_blocks_attention"]
+
+
+def test_block_selection_compiles_for_v5e_in_blocks_of_queries(one_chip):
+    """The selection at 32,768 tokens never holds the per-head scores
+    whole (32,768 x 32 x 2,047 float32 = 8.6 GB): a block of queries at a
+    time, under a gigabyte of temporaries."""
+    q = one_chip((1, 32768, 32, 128), jnp.bfloat16)
+    k = one_chip((1, 32768, 2, 128), jnp.bfloat16)
+    compiled = jax.jit(lambda q, k: spa.select_blocks(
+        q, k, kernel_size=32, stride=16, block_size=64, init_blocks=1,
+        window_blocks=32, topk=64)).lower(q, k).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+def test_flash_forward_takes_32_on_2_heads_of_128_for_v5e(one_chip):
+    """The sparse layers' dense path at its longest (8,192 tokens, 16 query
+    heads a kv head, 128 lanes a head): on lane blocks, one kernel."""
+    q = one_chip((1, 8192, 32, 128), jnp.bfloat16)
+    kv = one_chip((1, 8192, 2, 128), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, k, v: fa.flash_attention(q, k, v, causal=True)
+    ).lower(q, kv, kv).compile()
+    assert _kernel_names(compiled) == ["flash_fwd"]
+    plan = fa._blocks(8192, 8192, 128, jnp.bfloat16, None, None, True, None,
+                      32, 2)
+    assert (plan.addressing, plan.heads) == ("lanes", 1)
