@@ -178,8 +178,9 @@ class _RemoteJitFunction:
         # argument is still referenced by the caller's frame when the
         # execute goes out, so the proxy cannot take it as dead; its
         # buffer is freed when its RemoteArray is collected, and the free
-        # rides on the next execute. Forwarding it (so the runtime can
-        # reuse the buffers for the outputs) is ROADMAP S4.
+        # rides on the next execute, where the proxy writes that call's
+        # outputs into it (output recycling). Forwarding it (so a step
+        # holds one copy of its state, not two) is ROADMAP S4.
         self._cache: dict = {}
         self.__wrapped__ = fn
 

@@ -28,11 +28,13 @@ from __future__ import annotations
 
 import base64
 import os
+import re
 import socket
 import threading
 import time
 import uuid
 from collections import OrderedDict, deque
+from concurrent.futures import Future
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -107,6 +109,10 @@ def _now_ms() -> float:
     return time.monotonic() * 1000.0
 
 
+#: a parameter aliased to an output, in a compiled module's header:
+#: ``input_output_alias={ {0}: (0, {}, may-alias), ... }``
+_ALIAS_PARAM = re.compile(r"\{[\d, ]*\}: \((\d+), \{")
+
 #: per-session phase counters, cumulative milliseconds, reported by
 #: ``usage`` beside ``exec_ms_total`` (doc/observability.md names each)
 _PHASE_KEYS = ("self_ms_total", "idle_attach_ms_total", "idle_gate_ms_total",
@@ -121,7 +127,14 @@ class _Program:
     training script) export byte-identical StableHLO; compiling per
     session would pay every multi-second XLA compile N times.
     """
-    single: object = None         # the AOT-compiled program (lazy)
+    single: object = None         # the AOT-compiled plain program (lazy)
+    #: for a program with recyclable outputs (``_Executable.recycle_meta``),
+    #: a Future of ``(compiled, aliased)``: the same program with a donated,
+    #: unread first argument that holds one freed buffer per such output,
+    #: which XLA writes those outputs into instead of allocating them;
+    #: ``aliased`` counts the outputs the compiled program aliases so.
+    #: Compiled on a thread of its own beside ``single`` (_recycle_form)
+    recycle: object = None
 
 
 @dataclass
@@ -141,6 +154,10 @@ class _Executable:
     # completion-barrier pick: (index of smallest non-empty output or -1,
     # True when that output is big enough to sync via a 1-element slice)
     sync_out: tuple = (-1, False)
+    #: (shape tuple, np.dtype) of each output that some input of the
+    #: program matches in shape and dtype, in output order: what a state-
+    #: carrying step frees on its next call is exactly such buffers
+    recycle_meta: list = field(default_factory=list)
 
 
 @dataclass
@@ -168,6 +185,10 @@ class _Session:
     rpc_count: int = 0
     inline_in_total: int = 0
     inline_out_total: int = 0
+    #: outputs its programs produced, and of them those written into a
+    #: buffer the same ``execute`` freed (output recycling)
+    out_count: int = 0
+    out_recycled: int = 0
     #: where this session's executions blocked and whose idle gap each
     #: ended (_PHASE_KEYS; added to under ``lock``)
     phase_ms: dict = field(
@@ -1343,6 +1364,8 @@ class ChipProxy:
                                      "rpc_count": s.rpc_count,
                                      "inline_in_total": s.inline_in_total,
                                      "inline_out_total": s.inline_out_total,
+                                     "out_count": s.out_count,
+                                     "out_recycled": s.out_recycled,
                                      **s.phase_ms}
                             for s in self._sessions.values()}
             return {"ok": True,
@@ -1367,11 +1390,17 @@ class ChipProxy:
 
         return {"ok": False, "error": f"unknown op {op!r}"}
 
-    def _free_handles(self, sess: _Session, handles) -> None:
+    def _free_handles(self, sess: _Session, handles) -> list:
+        """Drop the handles; the arrays they held, for a caller that can
+        still put their memory to use (refunded all the same)."""
+        freed = []
         for handle in map(int, handles):
-            self._forget_buffer(sess, handle)
+            buf = self._forget_buffer(sess, handle)
+            if buf is not None:
+                freed.append(buf)
             if sess.fetch_cache and sess.fetch_cache[0] == handle:
                 sess.fetch_cache = None
+        return freed
 
     def _put_array(self, sess: _Session, arr) -> dict:
         # Pre-check with the host-side size so an over-cap upload is
@@ -1437,11 +1466,15 @@ class ChipProxy:
         least = min(nonempty, key=lambda ni: (ni[0], -ni[1]), default=None)
         sync_out = ((-1, False) if least is None
                     else (least[1], least[0] > protocol.INLINE_MAX))
+        recycle_meta = [key for key in ((tuple(shape), np.dtype(dtype))
+                                        for shape, dtype in out_meta)
+                        if key in in_meta]
         if exec_id is None:
             exec_id = sess.fresh_id()
         sess.executables[exec_id] = _Executable(
             exec_id, exported.call, in_specs, out_nbytes, out_meta,
-            prog=prog, in_meta=in_meta, sync_out=sync_out)
+            prog=prog, in_meta=in_meta, sync_out=sync_out,
+            recycle_meta=recycle_meta)
         sess.program_blobs[exec_id] = bytes(blob)
         if sess.resume_token:
             self.journal.save_program(sess.resume_token, exec_id, blob)
@@ -1470,12 +1503,95 @@ class ChipProxy:
                                        .lower(*exe.in_specs).compile())
         return exe.prog.single
 
+    def _recycle_form(self, exe: _Executable) -> Future:
+        """The program's recycling form (``_Program.recycle``), started on
+        a thread of its own the first time it is asked for: at the
+        program's first call, beside ``single``'s compile, so the two
+        compiles (or cache loads) overlap inside set-up and the call whose
+        frees first cover its outputs finds it done. Without ``_dlock``:
+        a compile drives no device, and holding it would queue ``single``
+        behind this one."""
+        prog = exe.prog
+        if prog.recycle is not None:
+            return prog.recycle
+        with self._slock:
+            fut, start = prog.recycle, prog.recycle is None
+            if start:
+                fut = prog.recycle = Future()
+        if start:
+            threading.Thread(target=self._compile_recycle, args=(exe, fut),
+                             name="ks-recycle-compile", daemon=True).start()
+        return fut
+
+    def _compile_recycle(self, exe: _Executable, fut: Future) -> None:
+        """``_recycle(scratch, *args) = call(*args)`` with ``scratch`` (one
+        buffer per recyclable output) donated: XLA aliases each to an
+        output of its shape and dtype, and the runtime allocates none of
+        them. ``keep_unused``: an unread argument is otherwise pruned, and
+        its donation with it. ``aliased`` is read from the compiled
+        module's ``input_output_alias`` (the scratch's parameters are the
+        first ``len(recycle_meta)``): what each call writes into freed
+        buffers, whatever the runtime deletes on donation."""
+        call = exe.call
+
+        def _recycle(scratch, *args):
+            return call(*args)
+
+        try:
+            jitted = real_jit()(_recycle, donate_argnums=(0,),
+                                keep_unused=True)
+            compiled = jitted.lower(
+                [self._jax.ShapeDtypeStruct(shape, dtype)
+                 for shape, dtype in exe.recycle_meta],
+                *exe.in_specs).compile()
+            header = compiled.as_text().split("\n", 1)[0]
+            aliased = sum(int(param) < len(exe.recycle_meta) for param in
+                          _ALIAS_PARAM.findall(header))
+            fut.set_result((compiled, aliased))
+        except BaseException as exc:    # the call that needs it raises it
+            fut.set_exception(exc)
+
+    @staticmethod
+    def _scratch(sess: _Session, exe: _Executable, freed: list):
+        """A freed array for each recyclable output of ``exe``, matched by
+        shape and dtype, or None where the frees do not cover them all. An
+        array that a live handle still holds is never given, nor one array
+        twice: donation deletes the array object itself."""
+        if len(freed) < len(exe.recycle_meta):
+            return None
+        # a snapshot: another connection of the session may put or free
+        taken = {id(buf) for buf in list(sess.buffers.values())}
+        pool: dict = {}
+        for buf in freed:
+            if id(buf) not in taken:
+                taken.add(id(buf))
+                pool.setdefault((buf.shape, buf.dtype), []).append(buf)
+        scratch = []
+        for key in exe.recycle_meta:
+            if not pool.get(key):
+                return None
+            scratch.append(pool[key].pop())
+        return scratch
+
+    @staticmethod
+    def _recycling(compiled, scratch: list):
+        """``compiled`` with ``scratch`` as its first argument. The freed
+        arrays are let go right after dispatch, while the program runs:
+        released after it, ~450 array destructions would hold the
+        interpreter lock just as the next tenant's program is dispatched
+        (PERF.md, PR 35)."""
+        def run(*args):
+            outs = compiled(scratch, *args)
+            scratch.clear()
+            return outs
+        return run
+
     def _execute(self, sess: _Session, req: dict, blob=None) -> dict:
         _refuse_loop_keys(req, "repeat", "chain_steps")
         # handles the tenant dropped since its last request ride in on this
         # one and go first: their memory is back before anything of this
-        # call is charged
-        self._free_handles(sess, req.get("free", ()))
+        # call is charged, and their arrays may become its outputs
+        freed = self._free_handles(sess, req.get("free", ()))
         exe = sess.executables[int(req["exec_id"])]
         # a small host input came in the frame's blob: it has no handle (a
         # null in ``args``), goes to the device under the program's own
@@ -1517,7 +1633,16 @@ class ChipProxy:
             inline_nbytes += arr.size * dtype.itemsize
             args[pos] = arr
         donate = [int(h) for h in req.get("donate", [])]
-        fn = self._single_fn(exe)
+        # output recycling: where this call's frees hold a buffer for
+        # every output that matches an input, the recycling form writes
+        # those outputs into them; any other call runs the plain program,
+        # and its frees go back to the allocator here. A session without
+        # "inline" frees by requests of its own, so it never recycles.
+        scratch = None
+        if exe.recycle_meta and "inline" in sess.features:
+            recycle = self._recycle_form(exe)
+            scratch = self._scratch(sess, exe, freed)
+        del freed
         # Cap check up front — allocation must not happen over-cap even
         # transiently (donated buffers are freed only after success). An
         # inline input is charged like a put, refused before dispatch, and
@@ -1527,14 +1652,21 @@ class ChipProxy:
         try:
             self._charge(sess, exe.out_nbytes)
             try:
+                if scratch is None:
+                    fn, aliased = self._single_fn(exe), 0
+                else:
+                    form, aliased = recycle.result()
+                    fn = self._recycling(form, scratch)
                 outs, read = self._gated(
                     sess, lambda: self._run_fn(fn, args, timing,
-                                               exe.sync_out, inline),
+                                               exe.sync_out, inline,
+                                               aliased),
                     timing)
             except Exception:
                 # A token-gate failure (scheduler closed / client removed
                 # while waiting) dispatched nothing, and the compiled
-                # program aliases no argument, so a device failure consumed
+                # program aliases no argument the tenant holds (only its
+                # scratch: buffers it freed), so a device failure consumed
                 # none either: every buffer is intact and only the output
                 # charge goes back.
                 sess.hbm_used -= exe.out_nbytes
@@ -1542,6 +1674,8 @@ class ChipProxy:
         finally:
             sess.hbm_used -= inline_nbytes
         sess.inline_in_total += len(inline)
+        sess.out_count += len(outs)
+        sess.out_recycled += aliased
         with self._slock:   # counter shared across connections
             self.total_execs += 1
         handles = []
@@ -1591,7 +1725,7 @@ class ChipProxy:
         return out
 
     def _run_fn(self, fn, args: list, timing: dict, sync_out: tuple,
-                inline=()):
+                inline=(), recycled: int = 0):
         # _dlock inside the token gate: execution is already exclusive per
         # the scheduler, but a concurrent put/get/compile from another
         # connection must not drive the device while this runs. Device
@@ -1599,7 +1733,8 @@ class ChipProxy:
         # whoever held the lock, not to this client's quota.
         # Phase stamps device_start / device_end bound ``exec_ms``; with
         # ``arrived`` and ``granted`` (left in ``timing`` by _gated) they
-        # split the idle gap this program ends.
+        # split the idle gap this program ends. ``recycled`` (outputs
+        # written into a freed buffer) is a stat of the ``ks.device`` event.
         who = timing.get("session", "")
         asked = _now_ms()
         with obs_trace.phase("dlock_wait", who):
@@ -1619,7 +1754,7 @@ class ChipProxy:
             start = _now_ms()
             timing["idle"] = self._split_idle(timing, start)
             try:
-                with obs_trace.phase("device", who):
+                with obs_trace.phase("device", who, recycled=recycled):
                     result = self._run_to_completion(fn, args, sync_out)
             finally:
                 end = self._last_device_end = _now_ms()

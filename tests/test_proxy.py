@@ -339,6 +339,10 @@ def test_program_cache_shared_across_sessions(proxy):
         (prog,) = proxy._programs.values()
         compiled = prog.single
         assert compiled is not None
+        # w + b has an input's shape and dtype: its recycling form was
+        # started beside it, once for every session
+        recycling = prog.recycle
+        assert recycling is not None
 
         with connect(proxy, "b") as cb:
             wb = cb.put(np.zeros(4, np.float32))
@@ -346,8 +350,12 @@ def test_program_cache_shared_across_sessions(proxy):
             eb = cb.compile(step, wb, bb)
             assert len(proxy._programs) == 1  # same sha → shared entry
             for _ in range(8):
+                old = wb
                 wb, auxb = eb(wb, bb)
+                cb.free_later(old)            # rides on the next call
             assert prog.single is compiled    # nothing compiled anew
+            assert prog.recycle is recycling
+            assert proxy._session("b").out_recycled == 7
             assert (proxy._session("b").executables[eb._exec_id].prog
                     is prog)
             np.testing.assert_allclose(cb.get(wb), np.full(4, 8.0))
@@ -1288,7 +1296,7 @@ def test_frees_queued_from_other_threads_are_each_applied_once(proxy):
         sess = proxy._session("c")
         freed = []
         real = proxy._free_handles
-        proxy._free_handles = lambda s, h: (freed.extend(h), real(s, h))
+        proxy._free_handles = lambda s, h: (freed.extend(h), real(s, h))[1]
         go, threads = threading.Event(), []
         for k in range(8):
             def drop(mine=dead[k::8]):
@@ -1318,3 +1326,207 @@ def test_frees_queued_from_other_threads_are_each_applied_once(proxy):
         assert len(freed) == len(set(freed))
         assert set(sess.buffers) == {x.handle}
         assert proxy.hbm_accounting()["c"]["balanced"]
+
+
+# -- output recycling: a step's outputs go into the buffers it freed ---------
+
+
+def _carry_step(w, s, x):
+    """A state-carrying step: ``w`` and ``s`` come back in their own shape
+    and dtype (recyclable), the loss matches no input."""
+    return w * 0.5 + x, s + 1, (w * x).sum()
+
+
+def _start_carry(c, x=None):
+    w = c.put(np.arange(32, dtype=np.float32).reshape(4, 8))
+    s = c.put(np.int32(0))
+    x = c.put(np.linspace(-1.0, 1.0, 8, dtype=np.float32) if x is None
+              else x)
+    return w, s, x, c.compile(_carry_step, w, s, x)
+
+
+def _pointer(proxy, name, buf):
+    return proxy._session(name).buffers[buf.handle].unsafe_buffer_pointer()
+
+
+def _the_program(proxy):
+    (prog,) = proxy._programs.values()
+    return prog
+
+
+def test_a_step_writes_its_state_into_the_buffers_it_freed(proxy):
+    """Call 3 frees call 2's state on its own ``execute``: its two
+    recyclable outputs live where those buffers lived, the counters say
+    so, and every value is bitwise what a session that never frees reads.
+    The two calls that free nothing run the plain program."""
+    with connect(proxy, "c") as c, connect(proxy, "plain") as p:
+        w, s, x, exe = _start_carry(c)
+        pw, ps, px, pexe = _start_carry(p)
+        sess = proxy._session("c")
+        w1, s1, _ = exe(w, s, x)
+        w2, s2, _ = exe(w1, s1, x)              # frees nothing
+        assert (sess.out_count, sess.out_recycled) == (6, 0)
+        prog = _the_program(proxy)
+        single = prog.single
+        assert single is not None and prog.recycle is not None
+        freed = {_pointer(proxy, "c", w1), _pointer(proxy, "c", s1)}
+        c.free_later(w1, s1)
+        w3, s3, loss3 = exe(w2, s2, x)
+        assert {_pointer(proxy, "c", w3), _pointer(proxy, "c", s3)} == freed
+        assert (sess.out_count, sess.out_recycled) == (9, 2)
+        assert prog.single is single and prog.recycle.result()[1] == 2
+        usage = c.usage()["chip"]["sessions"]
+        assert (usage["c"]["out_count"], usage["c"]["out_recycled"]) == (9, 2)
+        for _ in range(3):
+            pw, ps, ploss = pexe(pw, ps, px)
+        assert proxy._session("plain").out_recycled == 0
+        for mine, plain in ((w3, pw), (s3, ps), (loss3, ploss)):
+            assert c.get(mine).tobytes() == p.get(plain).tobytes()
+        assert _accounts(proxy, "c")["balanced"]
+
+
+def test_a_scorer_shaped_program_compiles_no_second_executable(proxy):
+    """One float out, matching no input: frees on its calls go back to the
+    allocator and nothing but the single program is compiled."""
+    def score(params, tokens):
+        return (params["w"][tokens] * params["b"]).sum()
+
+    with connect(proxy, "scorer") as c:
+        params = {"w": c.put(np.ones((16, 4), np.float32)),
+                  "b": c.put(np.ones(4, np.float32))}
+        tokens = np.arange(6, dtype=np.int32)
+        exe = c.compile(score, params, tokens)
+        for _ in range(3):
+            c.free_later(exe(params, tokens))
+        assert proxy._session("scorer").executables[exe._exec_id] \
+            .recycle_meta == []
+        prog = _the_program(proxy)
+        assert prog.single is not None and prog.recycle is None
+        sess = proxy._session("scorer")
+        assert (sess.out_count, sess.out_recycled) == (3, 0)
+
+
+def _no_recycling(proxy, monkeypatch):
+    """Fails the test if a call runs the recycling form."""
+    def refuse(compiled, scratch):
+        raise AssertionError("recycled")
+    monkeypatch.setattr(proxy, "_recycling", refuse)
+
+
+@pytest.mark.parametrize("held", [False, True])
+def test_a_partial_match_runs_the_plain_program(proxy, monkeypatch, held):
+    """Only ``w`` freed, or ``s`` freed while another live handle still
+    holds its array: the frees do not cover the outputs, so the plain
+    program runs, nothing is donated, and what the tenant still holds
+    reads as before."""
+    with connect(proxy, "c") as c:
+        w, s, x, exe = _start_carry(c)
+        w1, s1, _ = exe(w, s, x)
+        sess = proxy._session("c")
+        if held:
+            # the same array under a second handle: donating it would
+            # delete what that handle reads
+            other = sess.fresh_id()
+            sess.buffers[other] = sess.buffers[s1.handle]
+            sess.hbm_used += 4
+            c.free_later(w1, s1)
+        else:
+            c.free_later(w1)
+        _no_recycling(proxy, monkeypatch)
+        w2, s2, _ = exe(w, s, x)
+        assert (sess.out_count, sess.out_recycled) == (6, 0)
+        if held:
+            assert int(np.asarray(sess.buffers[other])) == 1
+        else:
+            assert int(c.get(s1)) == 1
+        assert int(c.get(s2)) == 1 and int(c.get(s)) == 0
+        assert _accounts(proxy, "c")["balanced"]
+
+
+def test_frees_sent_on_a_put_leave_the_next_call_plain(proxy, monkeypatch):
+    """A tenant whose input is over ``INLINE_MAX`` puts it before every
+    call, and the put carries the frees: the call then frees nothing, runs
+    the plain program, and no copy of the state is made for it."""
+    big = np.ones((protocol.INLINE_MAX // 4 + 1,), np.float32)
+
+    def step(w, s, x):
+        return w * 0.5, s + 1, x.sum()
+
+    with connect(proxy, "c") as c:
+        w = c.put(np.arange(32, dtype=np.float32).reshape(4, 8))
+        s = c.put(np.int32(0))
+        exe = c.compile(step, w, s, c.put(big))
+        w, s, _ = exe(w, s, big)
+        sess = proxy._session("c")
+        real_put, copies = proxy._jax.device_put, []
+
+        def put(x, *a, **kw):
+            copies.append(type(x).__name__)
+            return real_put(x, *a, **kw)
+
+        _no_recycling(proxy, monkeypatch)
+        monkeypatch.setattr(proxy._jax, "device_put", put)
+        for _ in range(3):
+            w, s, _ = exe(w, s, big)            # frees ride on the put
+        assert (sess.out_count, sess.out_recycled) == (12, 0)
+        assert copies == ["ndarray"] * 3        # the puts' host arrays
+        assert int(c.get(s)) == 4 and _accounts(proxy, "c")["balanced"]
+
+
+def test_over_tpu_mem_is_refused_before_dispatch_with_frees_riding(proxy):
+    """The frees are refunded first and the outputs charged in full before
+    anything runs: an execute whose outputs still pass the cap is refused,
+    the frees stay applied, and what the tenant holds reads as before."""
+    # w 128 + s 4 + x 32 = 164 B; a call's outputs 136 B
+    with connect(proxy, "capped", memory=350) as c:
+        w, s, x, exe = _start_carry(c)
+        w1, s1, loss1 = exe(w, s, x)            # 300 of 350
+        pad = c.put(np.zeros(12, np.float32))   # 348
+        execs = proxy.total_execs
+        c.free_later(w, s)                      # 216 + 136 > 350
+        with pytest.raises(RuntimeError, match="HBM cap"):
+            exe(w1, s1, x)
+        assert proxy.total_execs == execs
+        acct = _accounts(proxy, "capped")
+        assert acct["balanced"] and acct["hbm_used"] == 216
+        np.testing.assert_array_equal(
+            c.get(w1), np.arange(32, dtype=np.float32).reshape(4, 8) * 0.5
+            + np.linspace(-1.0, 1.0, 8, dtype=np.float32))
+        c.free(pad)
+        w2, s2, _ = exe(w1, s1, x)
+        assert int(c.get(s2)) == 2 and _accounts(proxy, "capped")["balanced"]
+
+
+@pytest.mark.parametrize("ran", [False, True])
+def test_a_device_failure_with_recycled_scratch_leaves_the_books_balanced(
+        proxy, monkeypatch, ran):
+    """The program fails after the freed buffers were handed over, before
+    it ran or after it consumed them: they were freed anyway, the output
+    charge goes back, what the tenant still holds is intact, and the next
+    call runs."""
+    with connect(proxy, "c") as c:
+        w, s, x, exe = _start_carry(c)
+        w1, s1, loss1 = exe(w, s, x)
+        held = c.usage()["hbm_used"] - 132     # w, s are about to go
+        if ran:
+            real, failed = proxy._run_to_completion, []
+
+            def flaky(fn, args, sync_out):
+                done = real(fn, args, sync_out)
+                if failed:
+                    return done
+                failed.append(True)
+                raise RuntimeError("injected device failure")
+
+            monkeypatch.setattr(proxy, "_run_to_completion", flaky)
+        else:
+            _fail_next_program(proxy, monkeypatch)
+        c.free_later(w, s)
+        with pytest.raises(RuntimeError, match="injected device failure"):
+            exe(w1, s1, x)
+        acct = _accounts(proxy, "c")
+        assert acct["balanced"] and acct["hbm_used"] == held
+        assert proxy._session("c").out_recycled == 0
+        assert int(c.get(s1)) == 1
+        w2, s2, _ = exe(w1, s1, x)
+        assert int(c.get(s2)) == 2 and _accounts(proxy, "c")["balanced"]
