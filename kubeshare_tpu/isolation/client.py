@@ -58,6 +58,12 @@ class ShimClock:
     send to the reply's coming in (not to the caller's asking for it: an
     async caller may do its own work in between), a difference of
     ``time.monotonic`` inside this process.
+
+    The report also carries ``turn_ms``, the wall-clock turn-around: from
+    the previous ``execute``'s reply coming in to this send, on the same
+    clock. It holds everything the tenant did between the two calls (the
+    shim, its own code, its other round trips, an open-loop tenant's
+    sleep), and is sent with ``rtt_ms`` only.
     """
 
     def __init__(self):
@@ -65,7 +71,8 @@ class ShimClock:
         self._mu = threading.Lock()
         self._shim_s = 0.0
         self._sent = 0                      # execute sends so far
-        self._rtt = (0, 0.0)                # (of send number, seconds)
+        #: (of send number, seconds, when its reply came in)
+        self._rtt = (0, 0.0, 0.0)
 
     def __enter__(self) -> "ShimClock":
         loc = self._local
@@ -87,6 +94,7 @@ class ShimClock:
         report of what was measured since the one before."""
         loc = self._local
         with self._mu:
+            now = time.monotonic()
             if getattr(loc, "depth", 0):    # the open section, so far
                 cpu = time.thread_time()
                 self._shim_s += cpu - loc.t0
@@ -94,15 +102,16 @@ class ShimClock:
             report = {"shim_ms": round(self._shim_s * 1e3, 3)}
             if self._sent and self._rtt[0] == self._sent:
                 report["rtt_ms"] = round(self._rtt[1] * 1e3, 3)
+                report["turn_ms"] = round((now - self._rtt[2]) * 1e3, 3)
             self._shim_s = 0.0
             self._sent += 1
-            return self._sent, time.monotonic(), report
+            return self._sent, now, report
 
     def replied(self, number: int, t_send: float, t_reply: float) -> None:
         """``execute`` number ``number``, sent at ``t_send``, had its
         reply come in at ``t_reply``."""
         with self._mu:
-            self._rtt = (number, t_reply - t_send)
+            self._rtt = (number, t_reply - t_send, t_reply)
 
 
 @dataclass(frozen=True)

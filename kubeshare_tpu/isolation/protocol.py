@@ -68,10 +68,12 @@ ACK_KEY = "_ack"
 #: the reserved transport keys above): what the tenant's side measured
 #: since its previous ``execute`` send, ``{"shim_ms": <CPU time of the
 #: calling thread inside shim and client code>, "rtt_ms": <the previous
-#: execute, send to reply coming in>}`` (``rtt_ms`` only where there was one).
-#: Differences taken inside the client process; the proxy adds them to
-#: the session's ``shim_ms_total`` / ``wire_ms_total``. A request without
-#: it, and a proxy that does not know it, behave as they always have.
+#: execute, send to reply coming in>, "turn_ms": <that reply coming in to
+#: this send, wall clock>}`` (``rtt_ms`` and ``turn_ms`` only where there
+#: was one). Differences taken inside the client process; the proxy adds
+#: them to the session's ``shim_ms_total`` / ``wire_ms_total`` /
+#: ``turn_ms_total``. A request without it (or without one of its keys),
+#: and a proxy that does not know it, behave as they always have.
 SHIM_KEY = "shim"
 
 #: a host array of at most this many bytes travels INSIDE an ``execute``

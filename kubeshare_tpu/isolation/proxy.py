@@ -35,6 +35,7 @@ import time
 import uuid
 from collections import OrderedDict, deque
 from concurrent.futures import Future
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -114,9 +115,11 @@ def _now_ms() -> float:
 _ALIAS_PARAM = re.compile(r"\{[\d, ]*\}: \((\d+), \{")
 
 #: per-session phase counters, cumulative milliseconds, reported by
-#: ``usage`` beside ``exec_ms_total`` (doc/observability.md names each)
+#: ``usage`` beside ``exec_ms_total`` (doc/observability.md names each);
+#: ``dispatch_ms_total`` + ``barrier_ms_total`` is ``exec_ms_total`` split
 _PHASE_KEYS = ("self_ms_total", "idle_attach_ms_total", "idle_gate_ms_total",
-               "idle_proxy_ms_total", "shim_ms_total", "wire_ms_total")
+               "idle_proxy_ms_total", "shim_ms_total", "wire_ms_total",
+               "turn_ms_total", "dispatch_ms_total", "barrier_ms_total")
 
 
 @dataclass
@@ -364,6 +367,9 @@ class ChipProxy:
         #: start of the idle gap the next program ends. None until the
         #: first program, whose gap is nobody's
         self._last_device_end: float | None = None
+        #: the ``timing`` of the call whose program is on the chip
+        #: (guarded by _dlock)
+        self._on_device: dict = {}
         # blob-sha → _Program: compiled artifacts shared
         # across sessions (guarded by _slock for lookup; compiles race-safe
         # under _dlock). LRU-capped: a client churning unique programs must
@@ -592,9 +598,23 @@ class ChipProxy:
     def _journal_buffer(self, sess: _Session, handle: int, buf) -> None:
         if not (sess.resume_token and self.journal.enabled):
             return
-        with self._dlock:
+        with self._xfer(sess.name, "journal", int(buf.nbytes)):
             host = np.asarray(buf)
         self.journal.save_buffer(sess.resume_token, handle, host)
+
+    @contextmanager
+    def _xfer(self, who: str, op: str, nbytes: int):
+        """Hold ``_dlock`` for a transfer between host and device: the
+        profiler's ``ks.dlock_wait`` while asking for it and ``ks.xfer``
+        while it is held, with the stats ``op`` and ``bytes``
+        (``scripts/ks_spans.py`` reads them; no counter does)."""
+        with obs_trace.phase("dlock_wait", who, op=op, bytes=nbytes):
+            self._dlock.acquire()
+        try:
+            with obs_trace.phase("xfer", who, op=op, bytes=nbytes):
+                yield
+        finally:
+            self._dlock.release()
 
     def _forget_buffer(self, sess: _Session, handle: int):
         """Drop one buffer (freed or donated): HBM accounting plus the
@@ -755,6 +775,7 @@ class ChipProxy:
                 if exec_end is not None:
                     exec_end()
                 elapsed = timing.get("exec_ms", end - granted)
+                dispatch_ms = timing.get("dispatch_ms", elapsed)
                 dlock_ms = timing.get("dlock_ms", 0.0)
                 idle = timing.get("idle", (0.0, 0.0, 0.0))
                 with sess.lock:
@@ -766,6 +787,8 @@ class ChipProxy:
                     ms["idle_attach_ms_total"] += idle[0]
                     ms["idle_gate_ms_total"] += idle[1]
                     ms["idle_proxy_ms_total"] += idle[2]
+                    ms["dispatch_ms_total"] += dispatch_ms
+                    ms["barrier_ms_total"] += elapsed - dispatch_ms
                     holding, used = sess.holding, sess.used_ms
                 # still busy: the idle watchdog leaves this hold alone
                 # until the boundary's pick is made
@@ -908,11 +931,13 @@ class ChipProxy:
     def _note_shim(self, sess: _Session, report) -> None:
         """What the tenant's side measured between its previous execute
         send and this one (``protocol.SHIM_KEY``): time in shim and client
-        code, and the previous execute's round trip. Each is a difference
-        taken inside that process; the round trip less this side's handler
-        time for the same call is the wire."""
+        code, the previous execute's round trip, and the turn-around from
+        that call's reply to this send. Each is a difference taken inside
+        that process; the round trip less this side's handler time for the
+        same call is the wire."""
         try:
             shim_ms = float(report.get("shim_ms", 0.0))
+            turn_ms = float(report.get("turn_ms", 0.0))
             rtt_ms = report.get("rtt_ms")
             wire_ms = (float(rtt_ms) - sess.last_handler_ms
                        if rtt_ms is not None
@@ -922,6 +947,7 @@ class ChipProxy:
         with sess.lock:
             sess.phase_ms["shim_ms_total"] += shim_ms
             sess.phase_ms["wire_ms_total"] += wire_ms
+            sess.phase_ms["turn_ms_total"] += turn_ms
 
     def _handle(self, req: dict, state: dict) -> dict:
         op = req.get("op")
@@ -1149,7 +1175,7 @@ class ChipProxy:
             handle = int(req["handle"])
             buf = sess.buffers[handle]
             if sess.fetch_cache is None or sess.fetch_cache[0] != handle:
-                with self._dlock:
+                with self._xfer(sess.name, "export_buffer", int(buf.nbytes)):
                     parts = protocol.dump_array_parts(buf)
                 sess.fetch_cache = (handle, parts,
                                     protocol.buffers_nbytes(parts))
@@ -1198,7 +1224,7 @@ class ChipProxy:
             arr = load_array(raw, writable=False)
             self._charge(sess, arr.nbytes)
             sess.hbm_used -= arr.nbytes
-            with self._dlock:
+            with self._xfer(sess.name, "import_buffer", int(arr.nbytes)):
                 buf = self._jax.device_put(arr, self.device)
             self._charge(sess, int(buf.nbytes))
             sess.buffers[handle] = buf
@@ -1229,7 +1255,7 @@ class ChipProxy:
         if op == "put":
             return self._put_array(sess,
                                    load_array(state["blob"],
-                                              writable=False))
+                                              writable=False), op)
 
         if op == "put_begin":
             # Chunked upload: stage the serialized (.npy) stream host-side
@@ -1288,7 +1314,7 @@ class ChipProxy:
             sess.hbm_used -= charged
             # load_array views the bytearray directly — bytes(raw) would
             # double peak host memory on checkpoint-sized uploads
-            return self._put_array(sess, load_array(raw, writable=False))
+            return self._put_array(sess, load_array(raw, writable=False), op)
 
         if op == "put_abort":
             sid = int(req["staging"])
@@ -1310,7 +1336,7 @@ class ChipProxy:
                 # so at most one host copy lives per session regardless of
                 # how the client paces its reads.
                 if sess.fetch_cache is None or sess.fetch_cache[0] != handle:
-                    with self._dlock:
+                    with self._xfer(sess.name, "get", int(buf.nbytes)):
                         parts = protocol.dump_array_parts(buf)
                     sess.fetch_cache = (handle, parts,
                                         protocol.buffers_nbytes(parts))
@@ -1331,7 +1357,7 @@ class ChipProxy:
                 raise ValueError(
                     f"buffer too large to transfer ({int(buf.nbytes)} bytes);"
                     " fetch it in slices (get with offset/length)")
-            with self._dlock:
+            with self._xfer(sess.name, "get", int(buf.nbytes)):
                 # parts: device→host copy (np.asarray) is the only copy;
                 # the reply payload streams straight from that buffer
                 state["reply_blob"] = protocol.dump_array_parts(buf)
@@ -1402,12 +1428,12 @@ class ChipProxy:
                 sess.fetch_cache = None
         return freed
 
-    def _put_array(self, sess: _Session, arr) -> dict:
+    def _put_array(self, sess: _Session, arr, op: str) -> dict:
         # Pre-check with the host-side size so an over-cap upload is
         # refused before touching the device at all...
         self._charge(sess, arr.nbytes)
         sess.hbm_used -= arr.nbytes
-        with self._dlock:
+        with self._xfer(sess.name, op, int(arr.nbytes)):
             buf = self._jax.device_put(arr, self.device)
         try:
             # ...then account the *device* buffer: device_put
@@ -1733,8 +1759,10 @@ class ChipProxy:
         # whoever held the lock, not to this client's quota.
         # Phase stamps device_start / device_end bound ``exec_ms``; with
         # ``arrived`` and ``granted`` (left in ``timing`` by _gated) they
-        # split the idle gap this program ends. ``recycled`` (outputs
-        # written into a freed buffer) is a stat of the ``ks.device`` event.
+        # split the idle gap this program ends. ``dispatched`` (left by
+        # _run_to_completion) splits ``exec_ms`` itself into ``dispatch_ms``
+        # and what the barrier took. ``recycled`` (outputs written into a
+        # freed buffer) is a stat of the ``ks.device`` event.
         who = timing.get("session", "")
         asked = _now_ms()
         with obs_trace.phase("dlock_wait", who):
@@ -1753,12 +1781,15 @@ class ChipProxy:
                     args[pos] = buf
             start = _now_ms()
             timing["idle"] = self._split_idle(timing, start)
+            self._on_device = timing
             try:
                 with obs_trace.phase("device", who, recycled=recycled):
                     result = self._run_to_completion(fn, args, sync_out)
             finally:
                 end = self._last_device_end = _now_ms()
                 timing["exec_ms"] = end - start
+                # a program that failed in its dispatch: all of it
+                timing["dispatch_ms"] = timing.get("dispatched", end) - start
         finally:
             self._dlock.release()
         return result
@@ -1781,34 +1812,44 @@ class ChipProxy:
 
     def _run_to_completion(self, fn, args: list, sync_out: tuple):
         """``(outputs, read)``: ``read`` is the host value of output
-        ``sync_out[0]`` where the barrier read it whole, else None."""
-        outs = fn(*args)
+        ``sync_out[0]`` where the barrier read it whole, else None.
+
+        Two phases inside ``ks.device``: ``ks.dispatch`` until the
+        executable's call returns (the stamp ``dispatched`` in the call's
+        ``timing``, ``_on_device``), then ``ks.barrier`` until the host
+        read returns."""
+        timing = self._on_device
+        who = timing.get("session", "")
+        with obs_trace.phase("dispatch", who):
+            outs = fn(*args)
+        timing["dispatched"] = _now_ms()
         if not isinstance(outs, (list, tuple)):
             outs = [outs]
-        # Completion barrier = a host read of the smallest output
-        # (kept pending S3). Quota accounting needs the program
-        # FINISHED before the clock is read, or a client could
-        # queue bursts past its token. A host read cannot complete
-        # before the program does, and every output comes from the
-        # SAME XLA program, so one read is a barrier for all of
-        # them. On the directly attached v5e block_until_ready is
-        # a barrier too (PERF.md, PR 21) and ~0.3 ms cheaper per
-        # dispatch; S3 decides whether to switch. ``sync_out`` is the
-        # pick precomputed at compile time (_Executable.sync_out) —
-        # scanning jax .nbytes properties per dispatch costs ~25 µs and
-        # this runs per op on the pipelined wire's serial stage.
-        idx, big = sync_out
-        small = outs[idx] if 0 <= idx < len(outs) else None
-        read = None
-        if small is None:     # all-empty: block_until_ready only
-            self._jax.block_until_ready(outs)
-        elif big:
-            # Don't haul a big buffer to host just to sync:
-            # a 1-element slice is a dependent dispatch that
-            # completes strictly after the program.
-            np.asarray(small.ravel()[:1])
-        else:
-            read = np.asarray(small)
+        with obs_trace.phase("barrier", who):
+            # Completion barrier = a host read of the smallest output
+            # (kept pending S3). Quota accounting needs the program
+            # FINISHED before the clock is read, or a client could
+            # queue bursts past its token. A host read cannot complete
+            # before the program does, and every output comes from the
+            # SAME XLA program, so one read is a barrier for all of
+            # them. On the directly attached v5e block_until_ready is
+            # a barrier too (PERF.md, PR 21) and ~0.3 ms cheaper per
+            # dispatch; S3 decides whether to switch. ``sync_out`` is the
+            # pick precomputed at compile time (_Executable.sync_out) —
+            # scanning jax .nbytes properties per dispatch costs ~25 µs and
+            # this runs per op on the pipelined wire's serial stage.
+            idx, big = sync_out
+            small = outs[idx] if 0 <= idx < len(outs) else None
+            read = None
+            if small is None:     # all-empty: block_until_ready only
+                self._jax.block_until_ready(outs)
+            elif big:
+                # Don't haul a big buffer to host just to sync:
+                # a 1-element slice is a dependent dispatch that
+                # completes strictly after the program.
+                np.asarray(small.ravel()[:1])
+            else:
+                read = np.asarray(small)
         return list(outs), read
 
     def _cleanup(self, state: dict) -> None:

@@ -101,6 +101,49 @@ def test_first_program_of_a_chip_counts_no_gap(rig):
     assert set(_PHASE_KEYS) <= set(c)
 
 
+def test_dispatch_and_barrier_split_the_device_time(rig, monkeypatch):
+    """``exec_ms`` in two parts taken on the same stamps: until the call
+    of the executable returns, and from there until the barrier's host
+    read returns. Per session their sums are ``exec_ms_total`` exactly."""
+    step_a, step_b = rig.tenant("a"), rig.tenant("b")
+    single = rig.proxy._single_fn
+
+    def slow_dispatch(exe):
+        fn = single(exe)
+
+        def dispatch(*args):
+            rig.t += 3.0
+            return fn(*args)
+        return dispatch
+
+    monkeypatch.setattr(rig.proxy, "_single_fn", slow_dispatch)
+    step_a()
+    step_a()
+    sess_a = rig.proxy._session("a")
+    with sess_a.lock:           # a's token idles out (the watchdog's act)
+        sess_a.holding = False
+    rig.proxy.scheduler.release("a", sess_a.used_ms)
+    step_b()
+    for name, execs in (("a", 2), ("b", 1)):
+        c = rig.counters(name)
+        assert c["exec_count"] == execs
+        assert c["dispatch_ms_total"] == 3.0 * execs
+        assert c["barrier_ms_total"] == DEVICE_MS * execs
+        assert (c["dispatch_ms_total"] + c["barrier_ms_total"]
+                == c["exec_ms_total"])
+    # a program whose dispatch fails is all dispatch, and still adds up
+    monkeypatch.setattr(rig.proxy, "_single_fn", lambda exe: _refuse)
+    with pytest.raises(RuntimeError, match="refused at dispatch"):
+        step_b()
+    c = rig.counters("b")
+    assert c["dispatch_ms_total"] + c["barrier_ms_total"] == c["exec_ms_total"]
+    assert c["barrier_ms_total"] == DEVICE_MS
+
+
+def _refuse(*args):
+    raise RuntimeError("refused at dispatch")
+
+
 def test_same_session_turn_around_is_all_attach(rig):
     step = rig.tenant("a")
     step()                      # ends at 1050
@@ -397,6 +440,7 @@ def test_execute_with_and_without_the_shims_key(proxy):
         assert mine()["exec_count"] == 1
         assert mine()["shim_ms_total"] == 0.0
         assert mine()["wire_ms_total"] == 0.0
+        assert mine()["turn_ms_total"] == 0.0
         # not the shim's report: ignored, the call is served
         reply, _ = c._conn.call(dict(bare, **{protocol.SHIM_KEY: "junk"}))
         assert reply["ok"] and mine()["shim_ms_total"] == 0.0
@@ -409,6 +453,13 @@ def test_execute_with_and_without_the_shims_key(proxy):
         assert reply["ok"]
         assert mine()["shim_ms_total"] == 1.5
         assert mine()["wire_ms_total"] == pytest.approx(0.25)
+        # a report without the turn-around adds nothing to it; one with it
+        # adds it as sent
+        assert mine()["turn_ms_total"] == 0.0
+        handler_ms = proxy._session("c").last_handler_ms
+        reply, _ = c._conn.call(dict(bare, **{protocol.SHIM_KEY: {
+            "shim_ms": 0.0, "rtt_ms": handler_ms, "turn_ms": 7.25}}))
+        assert reply["ok"] and mine()["turn_ms_total"] == 7.25
         # the client sends it by itself on every execute: the CPU time
         # its thread spent in the shim's sections since the execute before
         with c.shim_clock:
@@ -420,10 +471,13 @@ def test_execute_with_and_without_the_shims_key(proxy):
         with c.shim_clock:
             c.get(exe(x))
         after = mine()
-        assert after["exec_count"] == 5
+        assert after["exec_count"] == 6
         assert 0.0 < after["shim_ms_total"] - first["shim_ms_total"] < 50.0
         assert first["wire_ms_total"] == pytest.approx(0.25)
         assert after["wire_ms_total"] > first["wire_ms_total"]
+        # ...but the wall clock between the reply and the next send holds
+        # both, and the usage round trips in between
+        assert after["turn_ms_total"] - first["turn_ms_total"] >= 60.0
 
 
 def burn(cpu_s):
@@ -448,16 +502,25 @@ def test_shim_clock_counts_the_threads_cpu_time_and_no_wait():
     number2, t_send2, second = clock.send()
     assert (number, number2) == (1, 2)
     assert 30.0 <= first["shim_ms"] < 40.0 and "rtt_ms" not in first
+    assert "turn_ms" not in first
     assert 10.0 <= second["shim_ms"] < 20.0
     assert second["rtt_ms"] == 50.0
+    # from the reply coming in to this send, wall clock: the 40 ms of CPU
+    # after it, in the shim and out of it
+    assert second["turn_ms"] == pytest.approx(
+        (t_send2 - (t_send + 0.05)) * 1e3, abs=1e-3)
+    assert second["turn_ms"] >= 40.0
     # a reply that came in long before the caller asked for it: the
     # round trip ends where it came in
     time.sleep(0.02)
     clock.replied(number2, t_send2, t_send2 + 0.004)
-    assert 3.9 <= clock.send()[2]["rtt_ms"] <= 4.1
+    report = clock.send()[2]
+    assert 3.9 <= report["rtt_ms"] <= 4.1
+    assert report["turn_ms"] >= 20.0 - 4.0
     # only the execute before this one has a round trip to report
     clock.send()
-    assert "rtt_ms" not in clock.send()[2]
+    report = clock.send()[2]
+    assert "rtt_ms" not in report and "turn_ms" not in report
     # another thread's section is its own
     t = threading.Thread(target=lambda: (clock.__enter__(), burn(0.01),
                                          clock.__exit__(None, None, None)))
@@ -532,6 +595,8 @@ with ProxyClient("127.0.0.1", proxy.port, "ns/pod-0", 0.5, 1.0) as c:
     for _ in range(3):
         proxy._session("ns/pod-0").used_ms = 1e9    # quantum spent: renew
         exe(x)
+    y = c.put(np.ones((16, 16), np.float32))
+    c.get(y)
     jax.profiler.stop_trace()
     hi = time.monotonic_ns() // 1000
 proxy.close()
@@ -542,7 +607,8 @@ for plane in ProfileData.from_file(path).planes:
         for ev in line.events:
             if ev.name.startswith("ks."):
                 found.setdefault(ev.name, []).append(
-                    {k: v for k, v in ev.stats})
+                    {"_lo": ev.start_ns, "_hi": ev.start_ns + ev.duration_ns,
+                     **{k: v for k, v in ev.stats}})
 print(json.dumps({"lo": lo, "hi": hi, "events": found}))
 '''
 
@@ -569,3 +635,23 @@ def test_profiler_trace_holds_ks_events_with_session_and_mono_us(tmp_path):
     executes = [s for s in events["ks.rpc"] if s.get("op") == "execute"]
     assert len(executes) == 3
     assert all(s["session"] == "ns/pod-0" for s in executes)
+    # ks.device in two: the executable's call, then the barrier's read,
+    # one after the other inside it
+    for name in ("ks.dispatch", "ks.barrier"):
+        assert len(events[name]) == 3, name
+        assert all(s["session"] == "ns/pod-0" for s in events[name])
+    for dev, disp, bar in zip(*(sorted(events[n], key=lambda s: s["_lo"])
+                                for n in ("ks.device", "ks.dispatch",
+                                          "ks.barrier"))):
+        assert dev["_lo"] <= disp["_lo"] <= disp["_hi"] <= bar["_lo"]
+        assert bar["_lo"] <= bar["_hi"] <= dev["_hi"]
+    # a put and a get: the device lock asked for and held, by op and size
+    xfers = events["ks.xfer"]
+    assert [(s["op"], s["bytes"]) for s in xfers] == [("put", 1024),
+                                                      ("get", 1024)]
+    assert all(s["session"] == "ns/pod-0" for s in xfers)
+    waits = [s for s in events["ks.dlock_wait"] if "op" in s]
+    assert [(s["op"], s["bytes"]) for s in waits] == [("put", 1024),
+                                                      ("get", 1024)]
+    for wait, xfer in zip(waits, xfers):
+        assert wait["_hi"] <= xfer["_lo"]
