@@ -17,8 +17,10 @@ Enforcement lives where the reference's lives:
   (:mod:`.tokensched`, gem-schd parity): a client acquires a quota, keeps
   the token across back-to-back programs until the quota is exhausted
   (Gemini's kernel-burst amortization), an idle timer returns the token
-  early when the client stalls between steps, and where a program ends
-  while another client waits the scheduler's weighted pick says who holds;
+  early when the client stalls between steps (while another client waits,
+  once the holder's grace, learned from its own gaps, has passed), and
+  where a program ends while another client waits the scheduler's
+  weighted pick says who holds;
 - **HBM** — device bytes are accounted per client at allocation time
   (``put`` and execution outputs), mirroring the hook's ``gpu_mem`` cap at
   ``cuMemAlloc`` (annotation default rule at ``pkg/scheduler/pod.go:419-424``).
@@ -55,6 +57,37 @@ from .tokensched import TokenScheduler
 log = get_logger("proxy")
 
 IDLE_RELEASE_MS = 10.0
+
+#: A hold idle while another session asks for the chip lasts its holder's
+#: grace (:func:`_grace_ms`), learned from the holder's last
+#: ``GRACE_RING`` gaps between a program's end and its next request: the
+#: least idle past which at most one gap in ``GRACE_EARLY`` came back
+#: before ``idle_release_ms``, at least ``GRACE_FLOOR_MS``. A session
+#: with fewer than ``GRACE_SAMPLES`` gaps on record has no grace: its
+#: hold waits out ``idle_release_ms``.
+GRACE_SAMPLES = 8
+GRACE_RING = 64
+GRACE_EARLY = 32
+GRACE_FLOOR_MS = 1.0
+
+
+def _grace_ms(gaps, idle_release_ms: float) -> float | None:
+    """The grace of a hold whose holder's recent gaps (a program's end to
+    its next request) are ``gaps``: the least idle such that no more than
+    ``len(gaps) // GRACE_EARLY`` of them lie between it and
+    ``idle_release_ms`` (where ending the hold would have kept that
+    request off the chip for the waiter's program), so below
+    ``idle_release_ms``, and at least ``GRACE_FLOOR_MS``. A back-to-back
+    burst's turn-arounds and the arrivals of an open-loop client that come
+    soon after its last program both count; None under ``GRACE_SAMPLES``
+    gaps."""
+    if len(gaps) < GRACE_SAMPLES:
+        return None
+    short = sorted(g for g in gaps if g < idle_release_ms)
+    late = len(gaps) // GRACE_EARLY
+    if len(short) <= late:
+        return GRACE_FLOOR_MS
+    return max(short[-1 - late], GRACE_FLOOR_MS)
 
 #: how long a detached (resumable) session's state is kept before the
 #: watchdog reclaims it — the client's reconnect budget must fit inside
@@ -180,6 +213,21 @@ class _Session:
     quota_ms: float = 0.0
     used_ms: float = 0.0
     last_end_ms: float = 0.0      # when the last execution finished
+    #: waiting at the gate right now (in ``acquire`` or ``renew``)
+    asking: bool = False
+    #: the recent gaps from a program's end to the session's next request,
+    #: and the grace they give (``_grace_ms``)
+    gaps: deque = field(default_factory=lambda: deque(maxlen=GRACE_RING))
+    grace_ms: float | None = None
+    #: the hold goes on from a contended program boundary at which the
+    #: pick kept it; counts of such boundaries, of the holds the grace
+    #: then ended, and of those whose holder came back before
+    #: ``idle_release_ms`` (``usage``); the grace ended the last one
+    kept: bool = False
+    kept_count: int = 0
+    kept_yielded: int = 0
+    kept_early: int = 0
+    graced: bool = False
     exec_count: int = 0
     exec_ms_total: float = 0.0
     #: round trips: every request handled for the session, any op; host
@@ -380,6 +428,9 @@ class ChipProxy:
         self.total_execs = 0          # lifetime, survives session drops
         self._server: protocol.FramedServer | None = None
         self._stop = threading.Event()
+        #: wakes the idle watchdog before its next tick: a hold was kept at
+        #: a contended boundary, or a session started to wait for the token
+        self._wake = threading.Event()
         self._watchdog: threading.Thread | None = None
 
     # -- lifecycle -----------------------------------------------------------
@@ -407,6 +458,7 @@ class ChipProxy:
 
     def close(self) -> None:
         self._stop.set()
+        self._wake.set()
         if self._server is not None:
             self._server.shutdown()
             self._server.server_close()
@@ -552,6 +604,7 @@ class ChipProxy:
         journal alone."""
         self._crashed = True
         self._stop.set()
+        self._wake.set()
         srv, self._server = self._server, None
         if srv is None:
             return
@@ -715,7 +768,12 @@ class ChipProxy:
         the program in flight and never for the rest of a quantum of
         several, its program runs under the holder's turn-around, and a
         holder the pick prefers keeps its burst. With nobody waiting
-        nothing is asked and the hold goes on as above.
+        nothing is asked and the hold goes on as above. A hold that goes
+        on while another session waits lasts its holder's grace
+        (:func:`_grace_ms`, learned here from the holder's own gaps between
+        a program's end and its next request); the watchdog, woken here,
+        hands it on once that has passed with no request, also where the
+        pick kept it (:meth:`_yield_graced`).
 
         A hold marked preempted (``TokenScheduler.preempted``) yields
         here too — this gate sits exactly at a program boundary, so the
@@ -733,6 +791,13 @@ class ChipProxy:
         timing = timing if timing is not None else {}
         arrived = sess.arrived_ms
         with sess.lock:
+            if sess.last_end_ms:    # the gap this request ended
+                gap = max(arrived - sess.last_end_ms, 0.0)
+                sess.gaps.append(gap)
+                sess.grace_ms = _grace_ms(sess.gaps, self.idle_release_ms)
+                # back before the idle timer would have let go
+                sess.kept_early += sess.graced and gap < self.idle_release_ms
+                sess.graced = False
             sess.busy = True
             holding = sess.holding
             exhausted = holding and sess.used_ms >= sess.quota_ms
@@ -740,7 +805,10 @@ class ChipProxy:
         preempted = (holding and not exhausted
                      and self.slicer.should_yield(sess.name))
         try:
+            sess.asking = not holding or exhausted or preempted
             if not holding:
+                # another session's hold may be idle past its grace
+                self._wake.set()
                 quota = self.scheduler.acquire(sess.name,
                                                trace_id=sess.trace_id)
             elif exhausted or preempted:
@@ -750,6 +818,7 @@ class ChipProxy:
                                              trace_id=sess.trace_id)
             else:
                 quota = None
+            sess.asking = False
             if quota is not None:
                 with sess.lock:
                     sess.holding = True
@@ -801,13 +870,18 @@ class ChipProxy:
                         pass
                 with sess.lock:
                     sess.busy = False
+                    sess.kept = False
                     if asked and sess.holding:  # its usage is on the books
-                        sess.holding = quota is not None
+                        sess.holding = sess.kept = quota is not None
                         sess.quota_ms, sess.used_ms = quota or 0.0, 0.0
+                        sess.kept_count += sess.kept
+                if sess.kept:   # the waiter waits out this holder's grace
+                    self._wake.set()
                 if sess.call is not None:
                     sess.call[1] += gate_ms + dlock_ms + elapsed
             return result
         finally:
+            sess.asking = False
             # only reached with busy still set when the token gate itself
             # failed (scheduler closed / renew raised) before dispatch
             if sess.busy:
@@ -820,9 +894,25 @@ class ChipProxy:
 
     def _watch_idle(self) -> None:
         """Return tokens from clients that stopped executing (one watchdog
-        thread for the whole proxy — not a timer per step)."""
+        thread for the whole proxy — not a timer per step): every
+        ``idle_release_ms / 2`` a hold idle for ``idle_release_ms``, and
+        between those ticks, woken by ``_gated``, a hold idle past its
+        holder's grace while another session waits (:meth:`_yield_graced`).
+        """
         period = max(self.idle_release_ms / 2.0, 1.0) / 1000.0
-        while not self._stop.wait(period):
+        tick = time.monotonic() + period
+        while not self._stop.is_set():
+            with self._slock:
+                sessions = list(self._sessions.values())
+            due = self._yield_graced(sessions)
+            wait = tick - time.monotonic()
+            if due is not None:
+                wait = min(wait, (due - _now_ms()) / 1000.0)
+            self._wake.wait(max(wait, 0.0))
+            self._wake.clear()
+            if self._stop.is_set() or time.monotonic() < tick:
+                continue
+            tick = time.monotonic() + period
             now = _now_ms()
             with self._slock:
                 sessions = list(self._sessions.values())
@@ -855,6 +945,42 @@ class ChipProxy:
                     log.info("detached session %s expired after %.0f ms",
                              sess.name, now - sess.detached_at)
                     self._drop_session(sess.name, purge=True)
+
+    def _yield_graced(self, sessions: list) -> float | None:
+        """Hand on each hold that has sat idle past its holder's grace
+        while another session waits at the gate, as the idle timer does
+        (``scheduler.release``), and write a ``ks.gate_yield`` event with
+        the grace and the idle it ended. Returns when the nearest grace
+        still running ends (``_now_ms``), or None. A holder whose next
+        request has arrived, or that has no grace yet, keeps its hold."""
+        askers = {s.name for s in sessions if s.asking}
+        if not askers:
+            return None
+        now, nearest = _now_ms(), None
+        for sess in sessions:
+            grace = sess.grace_ms
+            if grace is None or not askers - {sess.name}:
+                continue
+            with sess.lock:
+                if (not sess.holding or sess.busy
+                        or sess.arrived_ms > sess.last_end_ms):
+                    continue
+                idle = now - sess.last_end_ms
+                if idle < grace:
+                    due = sess.last_end_ms + grace
+                    nearest = due if nearest is None else min(nearest, due)
+                    continue
+                used, kept = sess.used_ms, sess.kept
+                sess.holding, sess.graced = False, kept
+                sess.kept_yielded += kept
+            try:
+                self.scheduler.release(sess.name, used)
+            except Exception:  # raced a drop
+                pass
+            with obs_trace.phase("gate_yield", sess.name, grace_ms=grace,
+                                 idle_ms=idle, kept=int(kept)):
+                pass
+        return nearest
 
     # -- protocol ------------------------------------------------------------
 
@@ -1392,6 +1518,9 @@ class ChipProxy:
                                      "inline_out_total": s.inline_out_total,
                                      "out_count": s.out_count,
                                      "out_recycled": s.out_recycled,
+                                     "kept_count": s.kept_count,
+                                     "kept_yielded": s.kept_yielded,
+                                     "kept_early": s.kept_early,
                                      **s.phase_ms}
                             for s in self._sessions.values()}
             return {"ok": True,

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from kubeshare_tpu.isolation import protocol
+from kubeshare_tpu.isolation import proxy as proxy_mod
 from kubeshare_tpu.isolation.client import ExecutionGate, ProxyClient
 from kubeshare_tpu.isolation.podmgr import PodManager
 from kubeshare_tpu.isolation.proxy import ChipProxy
@@ -784,6 +785,10 @@ def test_idle_watchdog_races_gated_execution_stress():
                 counts[name] = n
                 u = c.usage()
                 assert u["exec_count"] == n + 0  # every dispatch accounted
+                mine = u["chip"]["sessions"][name]
+                # a kept hold is handed on by its grace at most once
+                assert mine["kept_yielded"] <= mine["kept_count"]
+                assert mine["kept_early"] <= mine["kept_yielded"]
         except Exception as e:  # pragma: no cover - failure reporting
             errors.append((name, e))
 
@@ -799,6 +804,289 @@ def test_idle_watchdog_races_gated_execution_stress():
         assert len(counts) == 4 and all(n > 10 for n in counts.values()), counts
     finally:
         p.close()
+
+
+# -- a kept hold lasts its holder's grace ---------------------------------------
+#
+# The idle timer is a whole second here, far above any grace these tests
+# learn: a hand-over well before it is the grace's, and bounds are taken
+# against it, never as a ratio of two CPU timings.
+
+IDLE_MS = 1000.0
+
+
+@pytest.fixture
+def graced():
+    p = ChipProxy(scheduler=TokenScheduler(WINDOW, BASE, MIN),
+                  idle_release_ms=IDLE_MS)
+    p.serve()
+    yield p
+    p.close()
+
+
+def _stepper(proxy, name):
+    c = connect(proxy, name)
+    x = c.put(np.ones((4, 4), np.float32))
+    exe = c.compile(lambda a: a * 2.0, x)
+    return c, lambda: exe(x)
+
+
+def _ahead(proxy, name, step, used_ms=300.0):
+    """``name`` runs one program and gives its token back, by hand, as if
+    it had used ``used_ms``: the weighted pick then keeps the other
+    tenant's hold at every boundary where ``name`` waits."""
+    step()
+    _let_go(proxy, name, used_ms)
+
+
+def _learn(step, n, gap_s=0.0):
+    """``n`` programs, ``gap_s`` apart: ``n - 1`` turn-arounds on record."""
+    for i in range(n):
+        if i and gap_s:
+            time.sleep(gap_s)
+        step()
+
+
+def _grace(proxy, name):
+    sess = proxy._session(name)
+    assert sess.grace_ms is not None, "no grace learned"
+    return sess.grace_ms
+
+
+def _let_go(proxy, name, used_ms=None):
+    """``name`` gives its idle token back, by hand (the idle timer's act),
+    with ``used_ms`` on the books where given."""
+    sess = proxy._session(name)
+    with sess.lock:
+        sess.holding = False
+    proxy.scheduler.release(name, sess.used_ms if used_ms is None
+                            else used_ms)
+
+
+def _hold_next_program(proxy, monkeypatch):
+    """The next program stays on the "device" until ``finish`` is set."""
+    started, finish = threading.Event(), threading.Event()
+    run = proxy._run_to_completion
+
+    def held(fn, args, sync_out):
+        if not started.is_set():
+            started.set()
+            assert finish.wait(10.0)
+        return run(fn, args, sync_out)
+
+    monkeypatch.setattr(proxy, "_run_to_completion", held)
+    return started, finish
+
+
+def _waiting(proxy, name):
+    deadline = time.monotonic() + 10.0
+    while name not in proxy.scheduler.waiting():
+        assert time.monotonic() < deadline, f"{name} never waited"
+        time.sleep(0.002)
+
+
+def _kept_counts(c, name):
+    sess = c.usage()["chip"]["sessions"][name]
+    return sess["kept_count"], sess["kept_yielded"], sess["kept_early"]
+
+
+def _spy_phases(monkeypatch):
+    from kubeshare_tpu.obs import trace as obs_trace
+    seen = []
+
+    class spy(obs_trace.phase):
+        def __init__(self, name, session, trace_id="", **attrs):
+            seen.append((name, session, attrs))
+            super().__init__(name, session, trace_id, **attrs)
+
+    monkeypatch.setattr(obs_trace, "phase", spy)
+    return seen
+
+
+def test_a_kept_holder_back_inside_its_turn_around_keeps_the_token(graced):
+    """A holder that learned a long turn-around (programs 150 ms apart)
+    and comes back at once keeps the token at every boundary while the
+    other tenant waits; gone quiet, it hands over no sooner than its
+    grace, and the counters say so."""
+    ca, step_a = _stepper(graced, "a")
+    cb, step_b = _stepper(graced, "b")
+    _ahead(graced, "b", step_b)
+    _learn(step_a, 10, gap_s=0.15)
+    grace = _grace(graced, "a")
+    assert grace >= 150.0
+    tb = threading.Thread(target=step_b, daemon=True)
+    tb.start()
+    _waiting(graced, "b")
+    for _ in range(3):          # back well inside 150 ms each time
+        step_a()
+    sess_a = graced._session("a")
+    assert sess_a.holding and tb.is_alive()
+    assert "b" in graced.scheduler.waiting()
+    assert _kept_counts(ca, "a") == (3, 0, 0)
+    t_end = sess_a.last_end_ms
+    tb.join(10.0)               # a is quiet now: its grace runs out
+    done = time.monotonic() * 1000.0
+    assert not tb.is_alive()
+    assert done - t_end >= grace
+    assert not sess_a.holding and graced._session("b").holding
+    assert _kept_counts(ca, "a") == (3, 1, 0)
+    assert _kept_counts(cb, "b") == (0, 0, 0)
+    ca.close()
+    cb.close()
+
+
+@pytest.mark.parametrize("kept", [True, False],
+                         ids=["kept-at-a-boundary", "idle-when-asked"])
+def test_a_quiet_holder_hands_over_within_its_grace(graced, monkeypatch,
+                                                    kept):
+    """A holder that learned a short turn-around (back-to-back programs)
+    and goes quiet while the other tenant waits hands the token over
+    within its grace, far under the idle timer: where the pick kept it
+    at the boundary of a program that ended while the other waited, and
+    where the other came to ask while the hold sat idle (the waiter wakes
+    the watchdog itself). A ``ks.gate_yield`` event gives the grace and
+    the idle it ended."""
+    seen = _spy_phases(monkeypatch)
+    ca, step_a = _stepper(graced, "a")
+    cb, step_b = _stepper(graced, "b")
+    _ahead(graced, "b", step_b)
+    _learn(step_a, 12)
+    grace = _grace(graced, "a")
+    assert grace < IDLE_MS / 4
+    tb = threading.Thread(target=step_b, daemon=True)
+    if kept:
+        started, finish = _hold_next_program(graced, monkeypatch)
+        ta = threading.Thread(target=step_a, daemon=True)
+        ta.start()
+        assert started.wait(10.0)
+        tb.start()
+        _waiting(graced, "b")
+        finish.set()
+        ta.join(10.0)
+        assert not ta.is_alive()
+    else:
+        time.sleep(grace / 1000.0 + 0.02)
+        tb.start()
+    grace = _grace(graced, "a")     # with the held program's turn-around
+    t_end = graced._session("a").last_end_ms
+    tb.join(10.0)
+    done = time.monotonic() * 1000.0
+    assert not tb.is_alive()
+    # the idle timer would have waited until t_end + IDLE_MS
+    assert done - t_end < grace + IDLE_MS / 2
+    assert not graced._session("a").holding
+    assert _kept_counts(ca, "a") == ((1, 1, 0) if kept else (0, 0, 0))
+    yields = [attrs for name, who, attrs in seen
+              if name == "gate_yield" and who == "a"]
+    assert len(yields) == 1
+    assert yields[0]["grace_ms"] == grace
+    assert yields[0]["idle_ms"] >= grace
+    assert yields[0]["kept"] == int(kept)
+    ca.close()
+    cb.close()
+
+
+@pytest.mark.parametrize("back_after_s", [0.0, IDLE_MS / 1000.0 + 0.05],
+                         ids=["before-the-timer", "after-the-timer"])
+def test_a_kept_hold_the_grace_ended_counts_early_if_its_holder_was_back_first(
+        graced, monkeypatch, back_after_s):
+    """The holder's next request after its grace ended a kept hold tells
+    the proxy whether that was too soon: back before the idle timer would
+    have let go, ``kept_early`` counts it; later, it does not."""
+    ca, step_a = _stepper(graced, "a")
+    cb, step_b = _stepper(graced, "b")
+    _ahead(graced, "b", step_b)
+    _learn(step_a, 12)
+    started, finish = _hold_next_program(graced, monkeypatch)
+    ta = threading.Thread(target=step_a, daemon=True)
+    ta.start()
+    assert started.wait(10.0)
+    tb = threading.Thread(target=step_b, daemon=True)
+    tb.start()
+    _waiting(graced, "b")
+    finish.set()
+    ta.join(10.0)
+    tb.join(10.0)               # a's kept hold goes to b by the grace
+    assert not ta.is_alive() and not tb.is_alive()
+    sess_a = graced._session("a")
+    assert sess_a.graced
+    _let_go(graced, "b")
+    time.sleep(back_after_s)
+    step_a()
+    assert not sess_a.graced
+    assert _kept_counts(ca, "a") == (1, 1, int(not back_after_s))
+    ca.close()
+    cb.close()
+
+
+@pytest.mark.parametrize("gaps, grace", [
+    ([2.0] * 7, None),
+    ([2.0] * 32, 2.0),
+    ([2.0] * 20 + [4.0, 5.0, 6.0, 7.0, 8.0] + [50.0] * 7, 7.0),
+    ([2.0] * 40 + [3.0, 4.0, 5.0, 6.0, 7.0, 8.0, 9.0] + [50.0] * 17, 7.0),
+    ([2.0] * 12 + [7.0, 9.0], 9.0),
+    ([2.0] * 31 + [9.0], 2.0),
+    ([12.0] * 31 + [3.0], proxy_mod.GRACE_FLOOR_MS),
+    ([0.2] * 32, proxy_mod.GRACE_FLOOR_MS),
+], ids=["too-few", "back-to-back", "open-loop", "open-loop-64",
+        "under-32-the-largest", "32-leave-one-out",
+        "rarely-back-before-the-timer", "floor"])
+def test_the_grace_leaves_one_gap_in_32_between_it_and_the_timer(
+        gaps, grace):
+    """The least idle past which no more than one recorded gap in
+    ``GRACE_EARLY`` came back before the idle timer (10 ms here), at
+    least ``GRACE_FLOOR_MS``; none under ``GRACE_SAMPLES`` gaps."""
+    assert proxy_mod._grace_ms(gaps, 10.0) == grace
+
+
+def test_with_nobody_waiting_a_quiet_holder_keeps_the_idle_timer(graced):
+    """A grace applies only while another tenant waits: a holder with a
+    short one learned, gone quiet with nobody asking, keeps the token
+    until ``idle_release_ms`` has passed."""
+    ca, step_a = _stepper(graced, "a")
+    _learn(step_a, 12)
+    grace = _grace(graced, "a")
+    sess_a = graced._session("a")
+    t_end = sess_a.last_end_ms
+    time.sleep(grace / 1000.0 + 0.25)
+    assert sess_a.holding
+    deadline = time.monotonic() + 10.0
+    while sess_a.holding:
+        assert time.monotonic() < deadline, "the idle timer never fired"
+        time.sleep(0.005)
+    assert time.monotonic() * 1000.0 - t_end >= IDLE_MS
+    assert _kept_counts(ca, "a") == (0, 0, 0)
+    ca.close()
+
+
+def test_a_holder_with_too_few_turn_arounds_waits_out_the_idle_timer(
+        graced, monkeypatch):
+    """Fewer than ``GRACE_SAMPLES`` turn-arounds on record: no grace, so
+    a hold the pick kept with the other tenant waiting lasts until the
+    idle timer, as it did before there was a grace."""
+    ca, step_a = _stepper(graced, "a")
+    cb, step_b = _stepper(graced, "b")
+    _ahead(graced, "b", step_b)
+    _learn(step_a, proxy_mod.GRACE_SAMPLES - 2)
+    started, finish = _hold_next_program(graced, monkeypatch)
+    ta = threading.Thread(target=step_a, daemon=True)
+    ta.start()
+    assert started.wait(10.0)
+    tb = threading.Thread(target=step_b, daemon=True)
+    tb.start()
+    _waiting(graced, "b")
+    finish.set()
+    ta.join(10.0)
+    assert not ta.is_alive()
+    sess_a = graced._session("a")
+    assert sess_a.grace_ms is None and len(sess_a.gaps) < proxy_mod.GRACE_SAMPLES
+    t_end = sess_a.last_end_ms
+    tb.join(10.0)
+    assert not tb.is_alive()
+    assert time.monotonic() * 1000.0 - t_end >= IDLE_MS
+    assert _kept_counts(ca, "a") == (1, 0, 0)
+    ca.close()
+    cb.close()
 
 
 def test_dump_array_parts_stream_equals_blob():
