@@ -35,8 +35,8 @@ ran), else ``unattributed``. The phases sum to the chip's idle time.
 
 Also reported: programs a session ran inside the traced span, how constant
 ``mono_us * 1000 - start_ns`` is over the events (the CLOCK_MONOTONIC
-offset of the trace's axis), and the device time of the four Pallas
-kernels by their names.
+offset of the trace's axis), and the device time of the Pallas kernels
+named in :data:`PALLAS`.
 
 Usage::
 
@@ -65,7 +65,7 @@ from pathlib import Path
 import numpy as np
 
 REPO = Path(__file__).resolve().parent.parent
-PALLAS = ("flash_fwd", "flash_dq", "flash_dkv", "fused_adam")
+PALLAS = ("flash_fwd", "flash_dq", "flash_dkv", "fused_adam", "moe_rows")
 PHASES = ("attach", "gate", "proxy", "dispatch", "launch", "in_program",
           "barrier", "xfer", "trace_edge", "unattributed")
 #: how far the device's axis may lie behind the host's (one traced run
@@ -270,7 +270,9 @@ def split(ks: list, ops: list) -> dict:
                   for e in ks if "mono_us" in e)
     pallas = {}
     for lo, hi, name in ops:
-        kernel = next((k for k in PALLAS if f"%{k}." in name), None)
+        # the op's own name, not an op that reads its result or calls it
+        own = name.split(" = ")[0]
+        kernel = next((k for k in PALLAS if own.startswith(f"%{k}.")), None)
         if kernel:
             seen = pallas.setdefault(kernel, {"events": 0, "seconds": 0.0,
                                               "example": name[:60]})

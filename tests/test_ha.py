@@ -334,14 +334,17 @@ def test_takeover_marks_decisions_and_flightrecorder():
     decisions = DecisionRecorder()
     sha = WarmStandby(disp, reg, "standby", ttl_s=5.0, clock=clock,
                       decisions=decisions)
-    before = len(default_recorder().state()["dumps"])
+    # the recorder is process-wide and keeps its last few dumps: count the
+    # dumps that are new, not the length (full once other tests dumped)
+    before = {(d["t"], d["seq"]) for d in default_recorder().state()["dumps"]}
     assert sha.step()                           # nobody led: acquires
     lead = [d for d in decisions.state()["recent"]
             if d["kind"] == "leadership"]
     assert lead and lead[-1]["epoch"] == 1
     assert lead[-1]["holder"] == "standby"
     dumps = default_recorder().state()["dumps"]
-    assert len(dumps) == before + 1
+    assert [d for d in dumps if (d["t"], d["seq"]) not in before] == [
+        dumps[-1]]
     assert dumps[-1]["reason"] == "leadership-transition"
 
 

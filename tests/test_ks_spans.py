@@ -189,3 +189,18 @@ def test_a_long_program_finds_an_offset_far_from_0():
     got = ks_spans().split(ks, ops)
     assert got["clock"]["offset_us"] == pytest.approx(-116_000.0 + 100.0)
     assert got["idle_by_phase"]["unattributed"]["pct"] == 0.0
+
+
+def test_a_kernel_is_timed_by_its_own_ops_not_by_those_that_read_it():
+    """An op that reads a kernel's result, or calls the computation that
+    holds it, names the kernel among its operands: it is not the kernel."""
+    ks, ops = trace()
+    ops = sorted(ops + [
+        op(20.3, 20.5, "%moe_rows.3 = bf16[32768,2048] custom-call(...)"),
+        op(20.6, 20.9, "%fusion.7 = bf16[8192,2048] fusion(%moe_rows.3)"),
+        op(21.0, 21.4, "%branch_0_fun.2 = (bf16[8]) call(), to_apply="
+                       "%moe_rows.4")])
+    got = ks_spans().split(ks, ops)["pallas"]
+    assert list(got) == ["moe_rows"]
+    assert got["moe_rows"]["events"] == 1
+    assert got["moe_rows"]["seconds"] == pytest.approx(0.2e-3)

@@ -334,6 +334,166 @@ def test_tiles_of_the_grouped_products_at_the_published_widths():
     assert moe._tiling(128, 32, 48) == (128, 32, 48)
 
 
+# -- the row moves between token order and the sorted buffer (moe_rows) -------
+#
+# The parent's moves with ``jnp.take``, kept here as the reference: each
+# kernel must give the same rows, bit for bit where a row is copied, and
+# the same sums in the same order of choices (to float32 round-off, which
+# already differs between two compiles of the reference itself).
+
+def _take_rows_of(buffer, r, j):
+    rows = jnp.take(buffer, r.position[:, j], axis=0)
+    return jnp.where(r.here[:, j, None], rows.astype(jnp.float32), 0.0)
+
+
+def _take_moves(x, ys, g, gx, r):
+    k = r.position.shape[1]
+    by_row = jnp.take(r.weights.reshape(-1), r.order)
+    return {
+        "dispatch": jnp.take(x, r.order // k, axis=0),
+        "combine": sum(r.weights[:, j, None] * _take_rows_of(ys, r, j)
+                       for j in range(k)),
+        "d_ys": (jnp.take(g, r.order // k, axis=0)
+                 * by_row[:, None]).astype(ys.dtype),
+        "d_w": jnp.stack([(_take_rows_of(ys, r, j) * g).sum(-1)
+                          for j in range(k)], 1),
+        "d_x": sum(_take_rows_of(gx, r, j)
+                   for j in range(k)).astype(gx.dtype)}
+
+
+def _kernel_moves(x, ys, g, gx, r):
+    d_ys, d_w, _ = moe._combine_bwd((ys, r.weights, r), g)
+    return {"dispatch": moe.dispatch(x, r),
+            "combine": moe.combine(ys, r.weights, r),
+            "d_ys": d_ys, "d_w": d_w, "d_x": moe._dispatch_bwd(r, gx)[0]}
+
+
+def _routing_with(here, seed=0, held=8):
+    """A routing whose held pairs are ``here`` (tokens, top_k), a token's
+    choices of distinct random held experts, laid out as ``topk_route``
+    lays them out (by expert, each expert's rows in pair order, the pairs
+    not held last)."""
+    rng = np.random.default_rng(seed)
+    tokens, top_k = here.shape
+    expert = np.argsort(rng.random((tokens, held)), 1)[:, :top_k]
+    group = np.where(here, expert, held).reshape(-1)
+    order = np.argsort(group, kind="stable")
+    weights = np.where(here, rng.uniform(0.1, 1.0, here.shape), 0.0)
+    return moe.Routing(
+        jnp.asarray(order, jnp.int32),
+        jnp.asarray(np.argsort(order).reshape(here.shape), jnp.int32),
+        jnp.asarray(here), jnp.asarray(weights, jnp.float32),
+        jnp.asarray(np.bincount(group, minlength=held + 1)[:held],
+                    jnp.int32))
+
+
+TOKENS, DIM = 320, 256      # token blocks of 160, buffer blocks of 256 rows
+
+
+def _here_of(present, seed=1):
+    """``present`` held pairs at random places, or a random mask."""
+    rng = np.random.default_rng(seed)
+    if present == "mask":
+        return rng.random((TOKENS, 4)) < 0.125
+    here = np.zeros(TOKENS * 4, bool)
+    here[rng.choice(TOKENS * 4, present, replace=False)] = True
+    return here.reshape(TOKENS, 4)
+
+
+def _move_inputs(r, seed=2):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    rows = TOKENS * 4
+    return (jax.random.normal(ks[0], (TOKENS, DIM)).astype(jnp.bfloat16),
+            jax.random.normal(ks[1], (rows, DIM)).astype(jnp.bfloat16),
+            jax.random.normal(ks[2], (TOKENS, DIM)),
+            jax.random.normal(ks[3], (rows, DIM)).astype(jnp.bfloat16))
+
+
+@pytest.mark.parametrize("present", [0, 1, 255, 256, 257, TOKENS * 4,
+                                     "mask"])
+def test_row_moves_match_the_take_reference_on_every_row_read(present):
+    """0 rows, 1, one on each side of a buffer block's edge (256), every
+    pair held, and a random eighth: ``dispatch`` and ``combine`` and both
+    their ways back against the ``jnp.take`` moves they replace. Rows of
+    the buffer past ``sum(group_sizes)`` are read by nobody and compared
+    by nobody."""
+    r = _routing_with(_here_of(present))
+    n = int(r.group_sizes.sum())
+    assert n == int(r.here.sum())
+    got = _kernel_moves(*_move_inputs(r), r)
+    want = _take_moves(*_move_inputs(r), r)
+    for name in ("dispatch", "d_ys"):           # copies: bit for bit
+        np.testing.assert_array_equal(
+            np.asarray(got[name][:n], np.float32),
+            np.asarray(want[name][:n], np.float32))
+    for name in ("combine", "d_w", "d_x"):      # sums, in the same order
+        close(got[name], want[name], 1e-6)
+    none = ~np.asarray(r.here).any(-1)          # tokens with no held pair
+    assert float(jnp.abs(got["combine"][none]).max(initial=0)) == 0.0
+    assert float(jnp.abs(got["d_x"][none]).max(initial=0)) == 0.0
+    assert float(jnp.abs(got["d_w"][~np.asarray(r.here)]).max(
+        initial=0)) == 0.0
+
+
+@pytest.mark.parametrize("present", [1, TOKENS * 4, "mask"])
+def test_row_moves_past_what_one_kernel_call_holds_match_the_take_reference(
+        present, monkeypatch):
+    """VMEM for fewer tokens' rows than there are: the buffer side's moves
+    go by XLA's row gathers, and still give every row read what the
+    reference gives."""
+    monkeypatch.setattr(moe, "RESIDENT_BYTES", 64 * DIM * 2)
+    r = _routing_with(_here_of(present, seed=3), seed=3)
+    n = int(r.group_sizes.sum())
+    got = _kernel_moves(*_move_inputs(r), r)
+    want = _take_moves(*_move_inputs(r), r)
+    for name in ("dispatch", "d_ys"):
+        np.testing.assert_array_equal(
+            np.asarray(got[name][:n], np.float32),
+            np.asarray(want[name][:n], np.float32))
+    for name in ("combine", "d_w", "d_x"):
+        close(got[name], want[name], 1e-6)
+
+
+def test_a_token_count_off_the_band_is_padded_and_adds_up_to_the_reference(
+        ref):
+    """21 tokens, not a multiple of the 16 the moves work in: the layer
+    pads them with pairs held nowhere, and its eight shares, and their
+    gradients against the input, still add up to the uncut layer's."""
+    p = _layer_params()
+    x = jax.random.normal(jax.random.PRNGKey(11), (3, 7, 32))
+    shares = [_share(p, first, 2) for first in range(0, 16, 2)]
+    total = lambda x: sum(moe.topk_moe_apply(q, x, 4, 2 * i)  # noqa: E731
+                          for i, q in enumerate(shares))
+    close(total(x), _uncut(ref, p, x, 4))
+    close(jax.grad(lambda x: (total(x) ** 2).sum())(x),
+          jax.grad(lambda x: (_uncut(ref, p, x, 4) ** 2).sum())(x))
+
+
+def test_no_row_of_a_pair_not_held_reaches_a_result():
+    """NaN in every row nothing should read: the tokens with no held
+    choice (their input and their cotangent) and the buffer's rows past
+    ``sum(group_sizes)`` (the expert results and their cotangent there).
+    Every result the held pairs make stays finite and equal to the
+    reference's over the same, NaN-free, rows."""
+    r = _routing_with(_here_of("mask", seed=5), seed=5)
+    n = int(r.group_sizes.sum())
+    x, ys, g, gx = _move_inputs(r, seed=6)
+    none = jnp.asarray(~np.asarray(r.here).any(-1))[:, None]
+    tail = (jnp.arange(TOKENS * 4) >= n)[:, None]
+    poisoned = (jnp.where(none, jnp.nan, x).astype(x.dtype),
+                jnp.where(tail, jnp.nan, ys).astype(ys.dtype),
+                jnp.where(none, jnp.nan, g),
+                jnp.where(tail, jnp.nan, gx).astype(gx.dtype))
+    got = _kernel_moves(*poisoned, r)
+    want = _take_moves(x, ys, g, gx, r)
+    held = ~np.asarray(none)[:, 0]
+    for name, rows in (("dispatch", slice(0, n)), ("d_ys", slice(0, n)),
+                       ("combine", held), ("d_x", held), ("d_w", held)):
+        part = np.asarray(got[name][rows], np.float32)
+        assert np.isfinite(part).all(), name
+        close(part, np.asarray(want[name][rows], np.float32), 1e-6)
+
+
 # -- the short convolution -----------------------------------------------------
 
 def test_convolution_is_causal():

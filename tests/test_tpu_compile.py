@@ -269,7 +269,9 @@ def test_grouped_expert_products_compile_for_v5e_at_published_widths(
     32,768 rows), forward and backward: three grouped products forward,
     three back for the rows' gradients, three transposed ones for the
     matrices', every one a Mosaic kernel at the tiles the shapes give; the
-    rows move by gathers, never by a scatter of rows."""
+    rows move between token order and the sorted buffer by four
+    ``moe_rows`` kernels (``dispatch`` and ``combine``, each way), never by
+    a scatter of rows."""
     import re
 
     moe = importlib.import_module("kubeshare_tpu.ops.moe")
@@ -285,11 +287,52 @@ def test_grouped_expert_products_compile_for_v5e_at_published_widths(
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         params, x).compile()
-    assert _kernel_names(compiled) == ["gmm"] * 6 + ["tgmm"] * 3
+    assert _kernel_names(compiled) == (["gmm"] * 6 + ["moe_rows"] * 4
+                                       + ["tgmm"] * 3)
     text = compiled.as_text()
     scattered = [int(np.prod([int(d) for d in dims.split(",")]))
                  for dims in re.findall(r" = \w+\[([\d,]+)\]\S* scatter\(",
                                         text)]
+    assert all(n < 1024 for n in scattered), scattered
+
+
+@pytest.mark.parametrize("tokens, held, train, rows_kernels", [
+    (16384, 8, True, 3),     # combine's way back: 128 MiB of float32 rows
+    (32768, 8, True, 2),     # and 128 MiB of the layer's input
+    (32768, 8, False, 1),    # one 32k-token document scored
+    (8192, 64, True, 4),     # every expert held: 64 runs a token block
+    (8184, 8, True, 4),      # no tile of 16 divides it: padded to 8,192
+])
+def test_expert_layer_compiles_for_v5e_past_the_cells_size(
+        one_chip, tokens, held, train, rows_kernels):
+    """The expert layer at its published widths where the tokens' rows
+    outgrow the VMEM a call of the buffer side keeps them in (that move
+    then goes by XLA's row gathers: fewer ``moe_rows`` kernels), where
+    every expert is held (the token side stages 64 runs a block), or where
+    the token count is padded: each compiles for a v5e, with no scatter of
+    rows."""
+    import re
+
+    moe = importlib.import_module("kubeshare_tpu.ops.moe")
+    params = jax.tree_util.tree_map(
+        lambda s: one_chip(s.shape, s.dtype),
+        jax.eval_shape(lambda k: moe.topk_moe_init(k, 2048, 1536, 64, held),
+                       jax.random.PRNGKey(0)))
+    x = one_chip((1, tokens, 2048), jnp.bfloat16)
+
+    def loss(p, x):
+        y = moe.topk_moe_apply(p, x, 4, 0, dtype=jnp.bfloat16)
+        return (y.astype(jnp.float32) ** 2).sum()
+
+    f = jax.grad(loss, argnums=(0, 1)) if train else (
+        lambda p, x: moe.topk_moe_apply(p, x, 4, 0, dtype=jnp.bfloat16))
+    compiled = jax.jit(f).lower(params, x).compile()
+    names = _kernel_names(compiled)
+    assert names.count("moe_rows") == rows_kernels, names
+    assert names.count("gmm") == (6 if train else 3), names
+    scattered = [int(np.prod([int(d) for d in dims.split(",")]))
+                 for dims in re.findall(r" = \w+\[([\d,]+)\]\S* scatter\(",
+                                        compiled.as_text())]
     assert all(n < 1024 for n in scattered), scattered
 
 
